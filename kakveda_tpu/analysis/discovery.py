@@ -2,8 +2,8 @@
 
 ONE definition of "the code tree" and "the docs corpus" — previously
 ``scripts/check_knobs.py`` had its own walker and any new checker would
-have grown another, and the two would drift (one skipping ``.probe/``,
-the other not, each with its own idea of what counts as code). Both the
+have grown another, and the two would drift (each with its own idea of
+what counts as code). Both the
 invariant linter (:mod:`kakveda_tpu.analysis.framework`) and the knob
 checker (:mod:`kakveda_tpu.analysis.knobs`) walk through here.
 
@@ -12,8 +12,8 @@ lint rules too:
 
 * ``tests/`` is NOT code: test fixtures deliberately contain rule
   violations and ``KAKVEDA_TEST_*`` levers that are not operator surface.
-* ``kakveda/`` (the retrieved reference tree), ``.probe/`` (the detached
-  probe loop's scratch) and ``__pycache__`` are never scanned.
+* ``kakveda/`` (the retrieved reference tree) and ``__pycache__`` are
+  never scanned.
 """
 
 from __future__ import annotations
@@ -23,13 +23,13 @@ from typing import Iterator
 
 # Code that can introduce operator-facing knobs or violate design
 # invariants. Tests are deliberately excluded (see module docstring).
-CODE_PATHS = ("kakveda_tpu", "scripts", "bench.py", "__graft_entry__.py")
+CODE_PATHS = ("kakveda_tpu", "scripts", "bench.py", "chip_smoke.py", "__graft_entry__.py")
 
 # The docs corpus a knob/fault-site must be discoverable from.
 DOC_PATHS = ("CLAUDE.md", "README.md", "TROUBLESHOOTING.md", "BASELINE.md", "docs")
 
 # Never descend into these directory names anywhere in the tree.
-SKIP_DIRS = frozenset({"__pycache__", ".probe", "kakveda", ".git", ".pytest_cache"})
+SKIP_DIRS = frozenset({"__pycache__", "kakveda", ".git", ".pytest_cache"})
 
 
 def _skipped(root: Path, p: Path) -> bool:

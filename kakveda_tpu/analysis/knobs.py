@@ -28,7 +28,6 @@ SITE_RE = re.compile(r"""\bsite\(\s*["']([a-z0-9_]+(?:\.[a-z0-9_]+)+)["']\s*\)""
 # Internal/cross-process plumbing set by our own launchers, not operators.
 ALLOWLIST = frozenset({
     "KAKVEDA_PROCESS_ID",  # set per-process by the multihost launcher
-    "KAKVEDA_TEST_PLATFORM",  # test-suite lever (tests/conftest.py), named here
     "KAKVEDA_CRASHSWEEP_CHILD",  # marker set per-child by the crash sweep
 })
 
@@ -37,7 +36,6 @@ ALLOWLIST = frozenset({
 # purpose) and docs-about-the-docs. Anything else documented-but-unread is
 # dead-knob drift and fails.
 DOC_ONLY_ALLOWLIST = frozenset({
-    "KAKVEDA_TEST_PLATFORM",  # tests/conftest.py: run the suite on real TPU
     # tests/test_hf_integration.py: prompt/expectation for the real-weight
     # integration test (tests/ is outside the code scan)
     "KAKVEDA_HF_PROMPT",

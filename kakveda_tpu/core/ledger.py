@@ -32,7 +32,8 @@ attribute check, nothing patches jax, nothing registers listeners. With
   tiered/mine rows assert the per-entry compile counts stay inside the
   O(log N) pow2-bucket envelope.
 
-Metrics: ``kakveda_compile_total{fn}`` and
+Metrics: ``kakveda_compile_total{fn}``,
+``kakveda_compile_cache_hits_total`` and
 ``kakveda_transfer_bytes{direction,phase}`` (``core/metrics.py``
 registry; catalog in docs/observability.md).
 
@@ -52,9 +53,12 @@ from typing import Dict, List, Optional
 
 _TRUTHY = frozenset({"1", "true", "yes", "on"})
 
-#: jax.monitoring event suffix that fires exactly once per actual XLA
-#: backend compile (NOT per trace, NOT per cache hit).
+#: jax.monitoring event suffix that fires exactly once per program handed
+#: to the backend (NOT per trace, NOT per in-memory jit-cache hit). A
+#: program found in the persistent compilation cache still fires it — and
+#: also fires the hit event below, so real compiles = compiles − hits.
 _COMPILE_EVENT_SUFFIX = "backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 def enabled() -> bool:
@@ -77,6 +81,9 @@ _COMPILES: Dict[str, int] = {}
 _POST_WARMUP: List[dict] = []
 # direction ("h2d"|"d2h") -> phase -> bytes.
 _TRANSFERS: Dict[str, Dict[str, int]] = {}
+# Programs served from the persistent compilation cache (ops/device.py
+# places it) instead of being compiled.
+_CACHE_HITS = 0
 _WARM = False
 
 _INSTALLED = False
@@ -233,6 +240,22 @@ def _on_duration_event(event: str, duration: float, **kw) -> None:
         )
 
 
+def _on_event(event: str, **kw) -> None:
+    """jax.monitoring listener: count persistent-compile-cache hits."""
+    global _CACHE_HITS
+    if not _INSTALLED or event != _CACHE_HIT_EVENT:
+        return
+    with _STATE_LOCK:
+        _CACHE_HITS += 1
+    fam = _metric(
+        "kakveda_compile_cache_hits_total",
+        "Programs loaded from the persistent compilation cache instead of "
+        "compiled (KAKVEDA_LEDGER=1)", (),
+    )
+    if fam is not None:
+        fam.inc()
+
+
 def maybe_install() -> bool:
     """Install the ledger if ``KAKVEDA_LEDGER=1`` and not yet installed.
     Idempotent; returns whether the ledger is installed after the call.
@@ -254,6 +277,7 @@ def maybe_install() -> bool:
             jax.jit = _patched_jit
         if not _LISTENER_REGISTERED:
             _monitoring.register_event_duration_secs_listener(_on_duration_event)
+            _monitoring.register_event_listener(_on_event)
             _LISTENER_REGISTERED = True
         _INSTALLED = True
     return True
@@ -321,12 +345,14 @@ def ledger_report() -> dict:
         post = [dict(e) for e in _POST_WARMUP]
         transfers = {d: dict(p) for d, p in _TRANSFERS.items()}
         warm = _WARM
+        cache_hits = _CACHE_HITS
     return {
         "enabled": enabled(),
         "installed": _INSTALLED,
         "warm": warm,
         "compiles": compiles,
         "compile_total": sum(compiles.values()),
+        "cache_hits": cache_hits,
         "post_warmup_compiles": len(post),
         "post_warmup": post,
         "transfer_bytes": {
@@ -338,11 +364,12 @@ def ledger_report() -> dict:
 
 def reset() -> None:
     """Zero the tables and the warm flag (install state is kept)."""
-    global _WARM
+    global _WARM, _CACHE_HITS
     with _STATE_LOCK:
         _COMPILES.clear()
         _POST_WARMUP.clear()
         _TRANSFERS.clear()
+        _CACHE_HITS = 0
         _WARM = False
     global _RECORDER
     _RECORDER = None

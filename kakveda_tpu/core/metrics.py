@@ -507,6 +507,9 @@ _CORE_FAMILIES = (
     ("counter", "kakveda_compile_total",
      "XLA backend compiles attributed per jit entry point "
      "(KAKVEDA_LEDGER=1)", ("fn",), None),
+    ("counter", "kakveda_compile_cache_hits_total",
+     "Programs loaded from the persistent compilation cache instead of "
+     "compiled (KAKVEDA_LEDGER=1)", (), None),
     ("counter", "kakveda_transfer_bytes",
      "Host<->device transfer bytes by direction and request phase "
      "(KAKVEDA_LEDGER=1)", ("direction", "phase"), None),

@@ -560,9 +560,7 @@ class Autoscaler:
         idx = _replica_index(rid)
         _FAULT_SPAWN.fire()
         loop = asyncio.get_running_loop()
-        # Short grace: the process is already presumed dead; the stop
-        # escalation policy (supervisor.stop) still refuses SIGKILL on a
-        # lease-marked replica.
+        # Short grace: the process is already presumed dead.
         await loop.run_in_executor(
             None, lambda: self.supervisor.stop(idx, timeout_s=5.0))
         await loop.run_in_executor(None, self.supervisor.start, idx)

@@ -901,7 +901,7 @@ def _merge_matches(answered: Dict[str, dict]) -> dict:
 
 def _hop_outcome(status: Optional[int]) -> str:
     """Span outcome for one hop's HTTP verdict — mirrors the admission
-    taxonomy: 429 is a shed, 503 a degraded verdict, other 5xx an error."""
+    classes: 429 is a shed, 503 a degraded verdict, other 5xx an error."""
     if status is None or status >= 500 and status != 503:
         return "error"
     if status == 429:

@@ -2204,6 +2204,21 @@ class GFKB:
             return
         self._tiers.insert(np.asarray(slots, np.int64), sp_idx, sp_val, route=route)
 
+    def index_info(self) -> dict:
+        """The device index as it stands, for /readyz: match path, store
+        dtype, and where each block of rows actually sits (read off the
+        array's own shards, not the mesh that was asked for)."""
+        knn, emb = self._view[0], self._view[1]
+        info = knn.info()
+        info["placement"] = [
+            {
+                "device": s.device.id,
+                "rows": [s.index[0].start or 0, s.index[0].stop or emb.shape[0]],
+            }
+            for s in emb.addressable_shards
+        ]
+        return info
+
     def tiers_info(self) -> dict:
         """Tier residency/routing view (readyz + tests)."""
         if self._tiers is None:
@@ -2316,9 +2331,8 @@ class GFKB:
             hits whenever ≥ k failures of that type exist.
 
         Concurrency design: the query embedding (host work) runs before the
-        lock and the result fetch (one wire RTT on remote-attached TPUs —
-        the dominant cost) runs after it; the lock covers only the async
-        DISPATCH of the top-k (microseconds). Dispatches must be serialized
+        lock and the result fetch runs after it; the lock covers only the
+        async DISPATCH of the top-k (microseconds). Dispatches must be serialized
         with mutators because inserts donate the index buffers and PJRT's
         buffer-hold bookkeeping is not safe against a concurrent reader
         dispatch; once dispatched, execution ordering protects the read.

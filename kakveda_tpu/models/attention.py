@@ -39,7 +39,9 @@ any real token's logits (their K/V slots are themselves masked).
 from __future__ import annotations
 
 import functools
+import logging
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +49,30 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+
+log = logging.getLogger("kakveda.attention")
+
+# Which path each traced attention call took, keyed by path + shapes. The
+# choice below is made in Python while jit traces, so this fills once per
+# compiled program (one count per layer), not per step; /readyz serves it
+# so an operator can see that a prefill really went through the kernel.
+_TRACED_PATHS: dict = {}
+_TRACED_LOCK = threading.Lock()
+
+
+def _note_path(path: str, q, k) -> None:
+    key = f"{path} q{list(q.shape)} cache{list(k.shape)}:{k.dtype.name}"
+    with _TRACED_LOCK:
+        first = key not in _TRACED_PATHS
+        _TRACED_PATHS[key] = _TRACED_PATHS.get(key, 0) + 1
+    if first:
+        log.info("attention path: %s", key)
+
+
+def traced_paths() -> dict:
+    """{"<path> q[B,S,H,D] cache[B,KV,L,D]:<dtype>": layers traced}."""
+    with _TRACED_LOCK:
+        return dict(_TRACED_PATHS)
 
 
 # ---------------------------------------------------------------------------
@@ -95,7 +121,7 @@ def _gqa_xla(q, k, v, pos0, kv_valid, window: int = 0, softcap: float = 0.0, ful
 def _flash_body(
     pos0_ref,
     q,  # [q_blk, D]
-    k,  # [l_blk, D] — already dequantized
+    k,  # [l_blk, D] compute dtype (int8 tiles: cast, scales passed apart)
     v,  # [l_blk, D]
     valid_ref,
     o_ref,
@@ -109,6 +135,8 @@ def _flash_body(
     n_l: int,
     scale: float,
     window: int,
+    k_scale=None,  # [1, l_blk] f32 per-row scales of an int8 K tile
+    v_scale=None,  # [1, l_blk] f32 per-row scales of an int8 V tile
 ):
     lb = pl.program_id(2)
     qb = pl.program_id(1)
@@ -123,6 +151,10 @@ def _flash_body(
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
+    if k_scale is not None:
+        # q·(k8·ks) == (q·k8)·ks: the per-row K scale lands on the score
+        # COLUMNS, so it broadcasts along lanes as stored.
+        s = s * k_scale
 
     # Causal + validity mask. Query rows fold (seq, group-head): row i is
     # sequence position (qb*q_blk + i) // r.
@@ -142,8 +174,11 @@ def _flash_body(
     p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)  # [q_blk, 1]
     l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
+    # p·(v8·vs) == (p·vs)·v8: same move for V, on the probability columns
+    # (the softmax denominator above keeps the unscaled p).
+    pw = p if v_scale is None else p * v_scale
     pv = jax.lax.dot_general(
-        p.astype(v.dtype),
+        pw.astype(v.dtype),
         v,
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -192,17 +227,20 @@ def _flash_kernel_kv8(
 ):
     """int8-KV variant: the cache tiles DMA from HBM as int8 (+1 f32
     scale per head_dim row) — ~½ the bandwidth of bf16 tiles on the
-    stream that binds long-context decode — and dequantize in VMEM.
-    The dequant replicates `_kv_dequant`'s EXACT op order (cast scale to
-    the compute dtype FIRST, multiply in that dtype): under bf16 a
-    multiply-in-f32-then-round differs in the last bit from
-    round-scale-then-multiply, which would make flash and XLA-fallback
-    logits diverge per element."""
+    stream that binds long-context decode. The tiles are cast to the
+    compute dtype (exact: |int8| ≤ 127) and the per-row scales are applied
+    to the score / probability COLUMNS inside `_flash_body` instead of to
+    the K/V rows: the scale blocks arrive lane-major ``[1, l_blk]``, and
+    Mosaic has no lane→sublane move to turn them into the ``[l_blk, 1]``
+    column a row-wise dequant needs (nor does v5e's VPU multiply bf16).
+    Same math as `_kv_dequant` up to rounding — the scale multiplies in
+    f32 here, in the compute dtype there — so flash and the XLA path
+    agree to compute-dtype tolerance, not bitwise."""
     dt = q_ref.dtype
-    kd = k_ref[0].astype(dt) * ks_ref[0, 0].astype(dt)[:, None]
-    vd = v_ref[0].astype(dt) * vs_ref[0, 0].astype(dt)[:, None]
     _flash_body(
-        pos0_ref, q_ref[0], kd, vd, valid_ref, o_ref, m_scr, l_scr, acc_scr, **kw,
+        pos0_ref, q_ref[0], k_ref[0].astype(dt), v_ref[0].astype(dt), valid_ref,
+        o_ref, m_scr, l_scr, acc_scr,
+        k_scale=ks_ref[0], v_scale=vs_ref[0], **kw,
     )
 
 
@@ -381,6 +419,7 @@ def gqa_cache_attention(
         # chunk) — inexpressible in the flash kernel's scalar-pos0 causal
         # mask, so these shapes take the XLA path. S ≤ k+1 keeps its
         # scratch tiny. softcap likewise always takes the XLA path.
+        _note_path("xla", q, k)
         if k_scale is not None:
             kd, vd = _dequant()
             return _gqa_xla(
@@ -408,6 +447,7 @@ def gqa_cache_attention(
     if use_flash:
         r = h // kv
         sr = s * r
+        _note_path("flash_kv8" if k_scale is not None else "flash", q, k)
         return flash_gqa_cache(
             q, k, v, pos0, kv_valid,
             k_scale=k_scale, v_scale=v_scale,
@@ -415,6 +455,7 @@ def gqa_cache_attention(
             l_blk=_pick_block(l, 512, 128),
             window=window,
         )
+    _note_path("xla", q, k)
     if k_scale is not None:
         kd, vd = _dequant()
         return _gqa_xla(q, kd, vd, pos0, kv_valid, window=window)

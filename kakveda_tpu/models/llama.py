@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from kakveda_tpu.parallel.mesh import shard_map as _shard_map
 
 Params = Dict[str, Any]
 
@@ -134,6 +133,20 @@ class LlamaConfig:
         return cls(**kw)
 
     @classmethod
+    def tinyllama_1b(cls) -> "LlamaConfig":
+        """TinyLlama-1.1B widths — the one full-width shape with a chip
+        history in this repo; fits one 16 GB chip beside a 4 GiB index."""
+        return cls(
+            vocab_size=32000,
+            d_model=2048,
+            n_layers=22,
+            n_heads=32,
+            n_kv_heads=4,
+            d_ff=5632,
+            max_seq_len=2048,
+        )
+
+    @classmethod
     def llama3_8b(cls, vocab_size: int = 128256) -> "LlamaConfig":
         return cls(
             vocab_size=vocab_size,
@@ -152,12 +165,17 @@ class LlamaConfig:
 # ---------------------------------------------------------------------------
 
 
-def init_params(rng: jax.Array, cfg: LlamaConfig) -> Params:
-    """He-ish init; params stored in f32, compute in cfg.dtype."""
+def init_params(rng: jax.Array, cfg: LlamaConfig, dtype=jnp.float32) -> Params:
+    """He-ish init; compute runs in cfg.dtype. ``dtype`` is the STORAGE
+    dtype of the matrices: f32 for training and the parity tests, bf16 for
+    a served model — what the HF loader delivers (models/hf_convert.py), so
+    a seeded random model occupies what a deployed checkpoint would. Each
+    leaf is drawn in f32 and cast on its own, so the f32 transient is one
+    leaf, never the tree. Norm gains stay f32 either way."""
     keys = jax.random.split(rng, cfg.n_layers + 2)
 
     def dense(key, fan_in, shape):
-        return (jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)).astype(jnp.float32)
+        return (jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)).astype(dtype)
 
     hd = cfg.head_dim
     layers = []
@@ -568,7 +586,7 @@ def _attention_block(
             k = _repeat_kv(k, cfg.n_heads // cfg.n_kv_heads)
             v = _repeat_kv(v, cfg.n_heads // cfg.n_kv_heads)
         spec = P("dp", cp_axis, tp, None)
-        attn = _shard_map(
+        attn = jax.shard_map(
             partial(
                 ring_attention_local,
                 axis_name=cp_axis,

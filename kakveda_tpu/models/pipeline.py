@@ -43,7 +43,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from kakveda_tpu.parallel.mesh import shard_map as _shard_map
 from kakveda_tpu.models.llama import (
     LlamaConfig,
     Params,
@@ -177,7 +176,7 @@ def pp_forward(
         return jax.lax.psum(outs, pp_axis)
 
     stage_spec = jax.tree.map(lambda a: P(pp_axis), stacked["stages"])
-    y_mb = _shard_map(
+    y_mb = jax.shard_map(
         pp_body,
         mesh=mesh,
         in_specs=(stage_spec, P(), P(), P()),

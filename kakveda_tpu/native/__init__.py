@@ -53,7 +53,11 @@ def _build() -> bool:
         )
         return _LIB_PATH.exists()
     except (subprocess.SubprocessError, OSError) as e:  # noqa: PERF203
-        log.debug("native build failed: %s", e)
+        stderr = getattr(e, "stderr", b"") or b""
+        log.warning(
+            "native build failed (%s); stderr tail: %s",
+            e, stderr.decode(errors="replace")[-2000:],
+        )
         return False
 
 
@@ -62,10 +66,12 @@ def load() -> Optional[ctypes.CDLL]:
     global _lib, _load_attempted
     if _lib is not None:
         return _lib
+    env = os.environ.get("KAKVEDA_NATIVE", "auto").lower()
     if _load_attempted:
+        if env == "require":  # every caller hears it, not just the first
+            raise RuntimeError("KAKVEDA_NATIVE=require but the native library did not load")
         return None
     _load_attempted = True
-    env = os.environ.get("KAKVEDA_NATIVE", "auto").lower()
     if env in ("0", "false", "off"):
         return None
     # Rebuild when the source is newer than the .so (a stale library would
@@ -78,13 +84,14 @@ def load() -> Optional[ctypes.CDLL]:
     if stale and not _build() and not _LIB_PATH.exists():
         if env == "require":
             raise RuntimeError("KAKVEDA_NATIVE=require but the native library cannot be built")
+        log.warning("native library unavailable; using the pure-Python host tier")
         return None
     try:
         lib = ctypes.CDLL(str(_LIB_PATH))
     except OSError as e:
         if env == "require":
             raise
-        log.debug("native load failed: %s", e)
+        log.warning("native load failed (%s); using the pure-Python host tier", e)
         return None
 
     try:

@@ -35,8 +35,8 @@ import numpy as np
 
 _DENSE_MAX = 8192
 _BLOCK = 1024
-# Query rows per device dispatch: each dispatch costs one device→host fetch
-# (a fixed wire RTT on remote-attached TPUs), so bigger blocks amortize it.
+# Query rows per device dispatch: each dispatch costs one device→host
+# fetch, so bigger blocks amortize it.
 _QBLOCK = 4096
 _EXACT_SWEEP_MAX = 1 << 17  # full-dim candidate sweep up to 131k rows
 _MINE_DIM = 256  # projection dim for the candidate sweep beyond that
@@ -154,7 +154,7 @@ def build_knn_edges(
 
     # Dispatch every query block up front (async), then drain fetches — the
     # device computes block i+1 while the host pulls block i's packed
-    # results, so the per-fetch wire RTT overlaps compute.
+    # results, so each fetch overlaps compute.
     pending = []
     for start in range(0, n, _QBLOCK):
         stop = min(start + _QBLOCK, n)

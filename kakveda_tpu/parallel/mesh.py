@@ -25,30 +25,6 @@ def local_device_count() -> int:
     return len(jax.devices())
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions — ONE compat seam for every
-    manual-collective in the tree (ops/knn.py, models/llama.py,
-    models/pipeline.py).
-
-    jax < 0.5 only ships it as ``jax.experimental.shard_map.shard_map``
-    with the replication check named ``check_rep`` (same semantics as the
-    promoted API's ``check_vma``). Without this seam the whole warn path
-    — and everything downstream of a sharded top-k — dies at dispatch
-    time on such versions with ``AttributeError: module 'jax' has no
-    attribute 'shard_map'``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
 def parse_mesh_shape(spec: str, n_devices: int | None = None) -> Dict[str, int]:
     """Parse ``"dp:2,tp:-1"`` into an ordered {axis: size} dict.
 

@@ -14,6 +14,7 @@ concurrent pre-flight checks into one device call (the <10 ms p50 path).
 
 from __future__ import annotations
 
+import logging
 import time
 from typing import List, Optional, Sequence
 
@@ -27,6 +28,8 @@ from kakveda_tpu.pipeline.classifier import HALLUCINATION_CITATION
 # The demo pattern the reference's policy knows how to attach
 # (reference: services/warning_policy/app.py:40-48).
 _CITATION_PATTERN_NAME = "Citation hallucination without sources"
+
+log = logging.getLogger("kakveda.warning")
 
 
 class WarningPolicy:
@@ -56,10 +59,12 @@ class WarningPolicy:
         # is latched DEGRADED we never even dispatch (a wedged chip hangs,
         # it doesn't error) — the GFKB's host-warm/disk-cold tiers answer
         # instead (index/tiers.py, `match_batch_fallback`), flagged
-        # `degraded=true`. A fresh backend failure here latches the mode
-        # and takes the same fallback, so the request that DISCOVERS the
-        # outage still gets a verdict. The pre-flight check is the
-        # product; it must not die with the chip.
+        # `degraded=true`. A fresh LOSS of the device here latches the
+        # mode and takes the same fallback, so the request that DISCOVERS
+        # the outage still gets a verdict. Any other device-side failure
+        # (a kernel Mosaic refuses, an out-of-memory) is raised: the chip
+        # is there, and a host answer would hide that the program on it
+        # is broken.
         from kakveda_tpu.core import admission as _admission
 
         health = _admission.get_device_health()
@@ -72,7 +77,11 @@ class WarningPolicy:
                 all_matches, tier_info = self.gfkb.match_batch_info(sigs)
             except Exception as e:  # noqa: BLE001 — classify, maybe degrade
                 if not health.note_failure(e, where="gfkb.match"):
-                    raise  # a real software bug, not a device loss
+                    log.error(
+                        "gfkb.match failed on the device and is NOT served "
+                        "from the host tiers (%s: %s)", type(e).__name__, e,
+                    )
+                    raise
                 all_matches, tier_info = self.gfkb.match_batch_fallback(sigs)
                 degraded = True
         self._m_batch.observe(time.perf_counter() - t0)

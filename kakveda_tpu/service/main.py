@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import os
 
 from aiohttp import web
 
@@ -60,19 +61,9 @@ def run_server(
     setup_logging(service_name="kakveda-tpu")
     cfg = get_runtime_config(service_name="kakveda-tpu")
 
-    # Honor JAX_PLATFORMS even on images whose sitecustomize pins the
-    # platform through jax.config (where the env var alone is ignored) —
-    # operators use it to run the service on CPU for dev/tests.
-    import os
+    from kakveda_tpu.ops.device import device_report, setup_compile_cache
 
-    plat_env = os.environ.get("JAX_PLATFORMS")
-    if plat_env:
-        import jax
-
-        try:
-            jax.config.update("jax_platforms", plat_env)
-        except Exception as e:  # noqa: BLE001 — best effort, never fatal
-            log.warning("could not apply JAX_PLATFORMS=%s: %s", plat_env, e)
+    cache_dir = setup_compile_cache()
 
     # Join the multi-host world (if configured) BEFORE the Platform builds
     # its mesh — jax.devices() must already span the pod.
@@ -88,7 +79,17 @@ def run_server(
 
     if ledger.maybe_install():
         log.info("compile-and-transfer ledger installed (KAKVEDA_LEDGER=1)")
-    plat = Platform(data_dir=data_dir or cfg.data_dir, capacity=cfg.index_capacity)
+    from kakveda_tpu.parallel.mesh import create_mesh
+
+    plat = Platform(
+        data_dir=data_dir or cfg.data_dir,
+        capacity=cfg.index_capacity,
+        mesh=create_mesh(cfg.mesh_shape),
+    )
+    log.info(
+        "running on %s; index %s; compile cache %s",
+        device_report(), plat.gfkb.index_info(), cache_dir,
+    )
 
     # Generational-GC tuning for the streaming path: ingest allocates ~2k
     # short-lived objects per 512-batch (pydantic records + dicts), which

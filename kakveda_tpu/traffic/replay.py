@@ -22,9 +22,7 @@ Every dispatch terminates in exactly one bucket:
 factor as the replay): ``faults`` re-arms `core/faults.py` (empty spec
 ends the outage window — disarm IS recovery), ``kill_replica`` /
 ``restart_replica`` drive a FleetSupervisor, ``crash_replica`` hard-kills
-one (SIGKILL — the dead-owner drill; skipped with a warning when the
-replica may hold the TPU lease, per the never-kill-the-lease-holder
-gotcha), ``fleet_pressure`` feeds
+one (SIGKILL — the dead-owner drill), ``fleet_pressure`` feeds
 ``AdmissionController.note_fleet_pressure`` exactly as a peer's gossip
 sample would, and ``scale_events`` snapshots the threaded autoscaler's
 decision counters into the chaos log (a measurement, not a mutation).
@@ -269,28 +267,22 @@ async def run_chaos(timeline: List[dict], *, speed: float = 1.0,
                     entry.update(applied=False, reason="no supervisor")
                 else:
                     i = int(act.get("replica", 0))
-                    # stop() waits out SIGTERM (never SIGKILL — TPU
-                    # lease); keep that wait off the event loop.
+                    # stop() waits out SIGTERM; keep that wait off the
+                    # event loop.
                     fn = supervisor.stop if kind == "kill_replica" else supervisor.start
                     await loop.run_in_executor(None, fn, i)
             elif kind == "crash_replica":
                 # Hard owner death (the replacement drill): SIGKILL with
-                # zero grace so the replica cannot drain — UNLESS it may
-                # hold the TPU lease (CLAUDE.md gotcha: a killed lease
-                # holder wedges every later backend init for hours).
+                # zero grace so the replica cannot drain.
                 if supervisor is None:
                     entry.update(applied=False, reason="no supervisor")
                 else:
                     i = int(act.get("replica", 0))
-                    if supervisor.may_hold_device_lease(i):
-                        entry.update(applied=False,
-                                     reason="replica may hold TPU lease")
-                    else:
-                        await loop.run_in_executor(
-                            None,
-                            lambda: supervisor.stop(
-                                i, timeout_s=0.5, sig=signal.SIGKILL),
-                        )
+                    await loop.run_in_executor(
+                        None,
+                        lambda: supervisor.stop(
+                            i, timeout_s=0.5, sig=signal.SIGKILL),
+                    )
             elif kind == "scale_events":
                 # Measurement-only: snapshot the autoscaler's decision
                 # ledger into the chaos log at this offset.

@@ -22,9 +22,7 @@ offsets while the replay runs —
     {"t": 5.0, "action": "kill_replica", "replica": 1}
     {"t": 5.5, "action": "restart_replica", "replica": 1}
     {"t": 5.2, "action": "crash_replica", "replica": 1}   ← SIGKILL (dead-owner
-                                                            drill; skipped when
-                                                            the replica may hold
-                                                            the TPU lease)
+                                                            drill)
     {"t": 4.5, "action": "fleet_pressure", "pressure": 0.95, "ttl_s": 5.0}
     {"t": 7.0, "action": "scale_events"}                  ← snapshot autoscaler
                                                             counters (measurement)
@@ -387,7 +385,7 @@ def rebalance_storm(seed: int = 0, *, duration_s: float = 10.0,
       run the range migration through the router's /fleet/rebalance);
       warn keeps flowing open-loop through the migration.
     * phase ``storm`` until ``kill``; at ``kill`` the named replica — an
-      owner — gets SIGTERM'd (supervisor.stop, never SIGKILL). Scatter-
+      owner — gets SIGTERM'd (supervisor.stop). Scatter-
       gather must keep answering from standbys; the epoch push re-fences.
     * phase ``recovery`` to the end: the ladder must be back to normal
       within ``gossip_ttl_s``.

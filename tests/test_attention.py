@@ -96,10 +96,9 @@ def test_flash_kernel_matches_oracle(b, s, h, kv, l, d, pos0, with_valid):
 
 @pytest.mark.parametrize("b,s,h,kv,l,d,pos0,with_valid", CASES)
 def test_flash_kernel_int8_cache_matches_dequant_path(b, s, h, kv, l, d, pos0, with_valid):
-    """int8-KV flash: streaming int8 tiles + per-row scales and
-    dequantizing IN the kernel must equal dequantize-then-attend (the XLA
-    fallback's math) exactly — the kernel casts back to the q dtype, so
-    the two paths see identical K/V values."""
+    """int8-KV flash: streaming int8 tiles and applying the per-row scales
+    to the score / probability columns IN the kernel must equal
+    dequantize-then-attend (the XLA fallback's math) to f32 rounding."""
     from kakveda_tpu.models.llama import _kv_dequant, _kv_quant_rows
 
     q, k, v = _mk(b, s, h, kv, l, d, seed=b * 11 + s)
@@ -132,11 +131,10 @@ def test_flash_kernel_int8_cache_matches_dequant_path(b, s, h, kv, l, d, pos0, w
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
 
 
-def test_flash_int8_bf16_bitwise_matches_dequant_path():
-    """Under bf16 compute the kernel must replicate _kv_dequant's exact
-    op order (round the scale to bf16 FIRST, multiply in bf16):
-    multiply-in-f32-then-round differs in the last bit and would make
-    flash vs XLA-fallback logits diverge per element."""
+def test_flash_int8_bf16_matches_dequant_path():
+    """Under bf16 compute the kernel scales in f32 on the score columns
+    where `_kv_dequant` scales the rows in bf16 (the row form needs a
+    lane→sublane move Mosaic refuses): the two agree to bf16 tolerance."""
     from kakveda_tpu.models.llama import _kv_dequant, _kv_quant_rows
 
     rng = np.random.default_rng(3)
@@ -157,10 +155,6 @@ def test_flash_int8_bf16_bitwise_matches_dequant_path():
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), atol=1e-2, rtol=1e-2
     )
-    # the dequantized K/V the two paths see must be IDENTICAL bits —
-    # that's the invariant the kernel's op ordering exists for
-    kd_kernel = k_i8.astype(jnp.bfloat16) * k_sc.astype(jnp.bfloat16)[..., None]
-    assert jnp.array_equal(kd_kernel, _kv_dequant(k_i8, k_sc, jnp.bfloat16))
 
 
 def test_flash_decode_shape_pads_q_rows():

@@ -420,7 +420,7 @@ def test_autoscale_flash_crowd(tmp_path, monkeypatch):
     sup = FleetSupervisor(
         tmp_path, port_base=pick_port_base(4), replicas=2,
         env={
-            "JAX_PLATFORMS": "cpu",  # SIGKILL drill: never a lease holder
+            "JAX_PLATFORMS": "cpu",  # host-plane drill: replicas take no chip
             "KAKVEDA_CONFIG_PATH": str(cfg),
             "KAKVEDA_INDEX_CAPACITY": "1024",
             "KAKVEDA_FLEET_OWNERSHIP": "1",

@@ -327,11 +327,9 @@ def test_ring_attention_key_blocking_matches_dense():
 
     from jax.sharding import PartitionSpec as P
 
-    from kakveda_tpu.parallel.mesh import shard_map
-
     def run(key_block):
         spec = P("dp", "cp", None, None)
-        return shard_map(
+        return jax.shard_map(
             partial(ring_attention_local, axis_name="cp", n_chunks=4, key_block=key_block),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
         )(q, k, v)

@@ -242,9 +242,7 @@ def test_incremental_mine_reemits_only_dirty_clusters(tmp_path):
 def test_warn_topk_reuse_skips_delta_dispatch(tmp_path):
     """The acceptance criterion: when the warn path already fetched a
     signature's neighbors, ingesting that signature attaches WITHOUT a
-    new device dispatch; a cold signature costs exactly one. (Single-device
-    mesh: the sharded match path needs jax.shard_map, unavailable in the
-    CI image — same constraint as the chaos suite.)"""
+    new device dispatch; a cold signature costs exactly one."""
     from kakveda_tpu.parallel.mesh import create_mesh
 
     g = _mk(tmp_path, mesh=create_mesh("data:1"))
