@@ -56,7 +56,6 @@ __all__ = [
     "FlightRecorder",
     "get_registry",
     "dump_recorders",
-    "device_block",
     "TIME_BUCKETS",
     "RATE_BUCKETS",
     "PROMETHEUS_CONTENT_TYPE",
@@ -445,7 +444,8 @@ _CORE_FAMILIES = (
     ("counter", "kakveda_mine_sweeps_total",
      "Pattern-mining sweeps by mode", ("mode",), None),
     ("histogram", "kakveda_warn_batch_seconds",
-     "Device kNN match wall per warn batch", (), None),
+     "Match wall per warn batch: signatures, featurize, dispatch, fetch and "
+     "match assembly (not the device scan alone)", (), None),
     ("counter", "kakveda_bus_events_published_total",
      "Events published on the in-process bus", ("topic",), None),
     ("counter", "kakveda_bus_deliveries_total",
@@ -501,9 +501,21 @@ _CORE_FAMILIES = (
      "Configured HBM weight+KV budget (0 = unbudgeted)", (), None),
     ("gauge", "kakveda_hbm_loaded_bytes",
      "Resident weight+KV bytes accounted by the model router", (), None),
-    ("histogram", "kakveda_device_block_seconds",
-     "Host wall of profiling.annotate()-labeled device blocks, keyed by "
-     "annotation name", ("name",), None),
+    ("histogram", "kakveda_microbatch_wait_seconds",
+     "Per-request wait in a micro-batcher queue: enqueue to the close of "
+     "the batch that took the request", ("batcher",), None),
+    ("histogram", "kakveda_serving_first_chunk_seconds",
+     "End of a request's admission prefill to its first delivered tokens "
+     "(chunks queued ahead, its first decode chunk, the fetch)",
+     ("engine",), None),
+    ("histogram", "kakveda_host_phase_seconds",
+     "Host wall of one named phase of the /warn batch cycle or the serving "
+     "engine loop (profiling.annotate / observe_phase; the phase label is "
+     "the TraceAnnotation's name)", ("phase",), None),
+    ("counter", "kakveda_host_stall_seconds_total",
+     "Seconds spent in loop phases that each lasted over 0.1 s (phases that "
+     "wait by design, for arrivals or for the device's chunk, are exempt)",
+     ("loop",), None),
     ("counter", "kakveda_compile_total",
      "XLA backend compiles attributed per jit entry point "
      "(KAKVEDA_LEDGER=1)", ("fn",), None),
@@ -520,26 +532,6 @@ _REGISTRY = MetricsRegistry()
 
 def get_registry() -> MetricsRegistry:
     return _REGISTRY
-
-
-_DEVICE_HIST: Optional[Histogram] = None
-
-
-def device_block(name: str, seconds: float) -> None:
-    """Observe one profiling.annotate block's host wall — the bridge that
-    keys XPlane annotation names to metric label values, so the kNN device
-    time an operator sees in a profile and the one on /metrics share a
-    vocabulary."""
-    global _DEVICE_HIST
-    h = _DEVICE_HIST
-    if h is None:
-        h = _DEVICE_HIST = _REGISTRY.histogram(
-            "kakveda_device_block_seconds",
-            "Host wall of profiling.annotate()-labeled device blocks, keyed "
-            "by annotation name",
-            ("name",),
-        )
-    h.labels(name=name).observe(seconds)
 
 
 # --- flight recorder --------------------------------------------------------

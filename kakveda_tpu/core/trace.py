@@ -58,6 +58,7 @@ import uuid
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 from kakveda_tpu.core import faults as _faults
+from kakveda_tpu.core import otel as _otel
 from kakveda_tpu.core import sanitize
 
 __all__ = [
@@ -436,8 +437,6 @@ class Tracer:
             # OTel bridge (KAKVEDA_OTEL_ENABLED): recorded spans also
             # export through the best-effort SDK tracer — one None check
             # when off, never a new hard dependency.
-            from kakveda_tpu.core import otel as _otel
-
             if _otel.get_tracer() is not None:
                 _otel.export_native_span(d)
         except Exception:  # noqa: BLE001 — a failing recorder drops the span, nothing else

@@ -2341,7 +2341,8 @@ class GFKB:
         other matches' result fetches.
         """
         # Ledger attribution: any compile or transfer below lands on the
-        # warn entry/phase (lambda jits inherit the ambient entry).
+        # warn entry/phase (jits made before the ledger's install, and
+        # lambda jits, inherit the ambient entry).
         with _ledger.entry("warn"), _ledger.phase("warn"):
             return self._match_batch_info(signature_texts, failure_type, type_filter)
 
@@ -2355,7 +2356,8 @@ class GFKB:
         # pre-flight check than dense rows; the device densifies before the
         # same top-k (identical scores). topk_async_sparse buckets ragged
         # batches internally.
-        q_idx, q_val = self.featurizer.encode_batch_sparse(list(signature_texts))
+        with profiling.annotate("gfkb.match.featurize"):
+            q_idx, q_val = self.featurizer.encode_batch_sparse(list(signature_texts))
         b = q_idx.shape[0]
 
         with self._lock:
@@ -2383,74 +2385,74 @@ class GFKB:
                 packed = knn.topk_async_sparse(emb, valid, q_idx, q_val)
         with profiling.annotate("gfkb.match.fetch"):
             scores, slots = knn.topk_result(packed)
-
-        info = {"tier": "hot", "nprobe": None}
-        hot = self._hot_cap()
-        if n > hot and self._tiers is not None:
-            # Overflow: merge the device's exact hot top-k with the host
-            # tiers' (routed) top-k over slots the device doesn't hold.
-            modes: set = set()
-            m_scores, m_slots = [], []
-            k = scores.shape[1]
-            overflow = self._tiers.match_host_batch(q_idx, q_val, k, min_slot=hot)
-            for i in range(b):
-                o_s, o_sl, mode = overflow[i]
-                modes.add(mode)
-                if tid is not None and len(o_sl):
-                    keep = np.asarray(
-                        [records[int(s)].failure_type == failure_type for s in o_sl]
-                    )
-                    o_s, o_sl = o_s[keep], o_sl[keep]
-                cs = np.concatenate([scores[i], o_s])
-                csl = np.concatenate([slots[i], o_sl])
-                order = np.argsort(-cs)[:k]
-                m_scores.append(cs[order])
-                m_slots.append(csl[order])
-            scores = np.stack(m_scores)
-            slots = np.stack(m_slots)
-            if "fault_exact" in modes:
-                info = {"tier": "tiered_fault", "nprobe": None}
-            elif modes == {"routed"}:
-                info = {"tier": "tiered", "nprobe": self._tiers.cfg.nprobe}
-            else:
-                info = {"tier": "tiered_exact", "nprobe": None}
-
-        if self._mine is not None and self._match_cache_max > 0 and failure_type is None:
-            # Remember the fetched neighbors per signature: a pre-flight
-            # warn is usually followed by the SAME signature being
-            # ingested when the trace fails, and these rows make its
-            # cluster attachment free (no extra device dispatch).
-            with self._lock:
-                gen_now = self._generation
+        with profiling.annotate("gfkb.match.assemble"):
+            info = {"tier": "hot", "nprobe": None}
+            hot = self._hot_cap()
+            if n > hot and self._tiers is not None:
+                # Overflow: merge the device's exact hot top-k with the host
+                # tiers' (routed) top-k over slots the device doesn't hold.
+                modes: set = set()
+                m_scores, m_slots = [], []
+                k = scores.shape[1]
+                overflow = self._tiers.match_host_batch(q_idx, q_val, k, min_slot=hot)
                 for i in range(b):
-                    self._match_cache[signature_texts[i]] = (
-                        scores[i], slots[i], gen_now
-                    )
-                    self._match_cache.move_to_end(signature_texts[i])
-                while len(self._match_cache) > self._match_cache_max:
-                    self._match_cache.popitem(last=False)
+                    o_s, o_sl, mode = overflow[i]
+                    modes.add(mode)
+                    if tid is not None and len(o_sl):
+                        keep = np.asarray(
+                            [records[int(s)].failure_type == failure_type for s in o_sl]
+                        )
+                        o_s, o_sl = o_s[keep], o_sl[keep]
+                    cs = np.concatenate([scores[i], o_s])
+                    csl = np.concatenate([slots[i], o_sl])
+                    order = np.argsort(-cs)[:k]
+                    m_scores.append(cs[order])
+                    m_slots.append(csl[order])
+                scores = np.stack(m_scores)
+                slots = np.stack(m_slots)
+                if "fault_exact" in modes:
+                    info = {"tier": "tiered_fault", "nprobe": None}
+                elif modes == {"routed"}:
+                    info = {"tier": "tiered", "nprobe": self._tiers.cfg.nprobe}
+                else:
+                    info = {"tier": "tiered_exact", "nprobe": None}
 
-        out: List[List[FailureMatch]] = []
-        for i in range(b):
-            row: List[FailureMatch] = []
-            for s, slot in zip(scores[i], slots[i]):
-                if s <= -1.0 or slot >= n or int(slot) in tomb:
-                    continue  # padding / invalid / tombstoned rows
-                rec = records[int(slot)]
-                if failure_type and rec.failure_type != failure_type:
-                    continue
-                row.append(
-                    FailureMatch(
-                        failure_id=rec.failure_id,
-                        version=rec.version,
-                        # f32 accumulation can nudge an exact self-match a hair
-                        # past 1.0; cosine is bounded, so clamp.
-                        score=min(1.0, max(-1.0, float(s))),
-                        failure_type=rec.failure_type,
-                        suggested_mitigation=rec.resolution,
+            if self._mine is not None and self._match_cache_max > 0 and failure_type is None:
+                # Remember the fetched neighbors per signature: a pre-flight
+                # warn is usually followed by the SAME signature being
+                # ingested when the trace fails, and these rows make its
+                # cluster attachment free (no extra device dispatch).
+                with self._lock:
+                    gen_now = self._generation
+                    for i in range(b):
+                        self._match_cache[signature_texts[i]] = (
+                            scores[i], slots[i], gen_now
+                        )
+                        self._match_cache.move_to_end(signature_texts[i])
+                    while len(self._match_cache) > self._match_cache_max:
+                        self._match_cache.popitem(last=False)
+
+            out: List[List[FailureMatch]] = []
+            for i in range(b):
+                row: List[FailureMatch] = []
+                for s, slot in zip(scores[i], slots[i]):
+                    if s <= -1.0 or slot >= n or int(slot) in tomb:
+                        continue  # padding / invalid / tombstoned rows
+                    rec = records[int(slot)]
+                    if failure_type and rec.failure_type != failure_type:
+                        continue
+                    row.append(
+                        FailureMatch(
+                            failure_id=rec.failure_id,
+                            version=rec.version,
+                            # f32 accumulation can nudge an exact self-match a hair
+                            # past 1.0; cosine is bounded, so clamp.
+                            score=min(1.0, max(-1.0, float(s))),
+                            failure_type=rec.failure_type,
+                            suggested_mitigation=rec.resolution,
+                        )
                     )
-                )
-            out.append(row)
+                out.append(row)
         return out, info
 
     # ------------------------------------------------------------------

@@ -338,6 +338,9 @@ def flash_gqa_cache(
             transcendentals=b * kv * sr * l,
         ),
         interpret=interpret,
+        # A stable name for the trace (a multi-row call is an admission's
+        # prefill; one row is a decode step over an int8 cache).
+        name="flash_prefill" if s > 1 else "flash_decode",
     )(*operands)
 
     # [B*KV, S*R(+pad), D] -> [B, S, H, D]

@@ -73,6 +73,7 @@ from kakveda_tpu.core import faults as _faults
 from kakveda_tpu.core import metrics as _metrics
 from kakveda_tpu.core.admission import DeviceUnavailableError, OverloadError
 from kakveda_tpu.core import ledger as _ledger
+from kakveda_tpu.core.profiling import annotate, observe_phase
 from kakveda_tpu.core import sanitize
 from kakveda_tpu.core import trace as _trace
 from kakveda_tpu.models.llama import (
@@ -1398,6 +1399,12 @@ class ServingEngine:
                 "kakveda_serving_prefill_seconds",
                 "Admission prefill dispatch wall per request", ("engine",),
             ).labels(**el),
+            "first_chunk": reg.histogram(
+                "kakveda_serving_first_chunk_seconds",
+                "End of a request's admission prefill to its first delivered "
+                "tokens (chunks queued ahead, its first decode chunk, the "
+                "fetch)", ("engine",),
+            ).labels(**el),
             "ttft": reg.histogram(
                 "kakveda_serving_ttft_seconds",
                 "Submit-to-first-token latency per request", ("engine",),
@@ -1506,6 +1513,7 @@ class ServingEngine:
             "request_id": rid,
             "queue_wait_ms": round((tr["admit"] - tr["submit"]) * 1000, 3),
             "prefill_ms": round(tr.get("prefill_s", 0.0) * 1000, 3),
+            "first_chunk_ms": round(tr.get("first_chunk_s", 0.0) * 1000, 3),
             "ttft_ms": (
                 round((tr["first"] - tr["submit"]) * 1000, 3)
                 if tr["first"] is not None else None
@@ -1524,7 +1532,8 @@ class ServingEngine:
             traceparent=tr.get("traceparent") or None,
             ts=time.time() - wall, dur_ms=tl["wall_ms"], outcome="ok",
             engine=self.name, queue_wait_ms=tl["queue_wait_ms"],
-            prefill_ms=tl["prefill_ms"], ttft_ms=tl["ttft_ms"] or 0.0,
+            prefill_ms=tl["prefill_ms"], first_chunk_ms=tl["first_chunk_ms"],
+            ttft_ms=tl["ttft_ms"] or 0.0,
             tokens=n_tokens,
         )
         if rec:
@@ -1946,20 +1955,30 @@ class ServingEngine:
             "deadline": deadline,
             "traceparent": getattr(fut, "traceparent", None),
         }
-        mx_ttft = self._mx["ttft"]
+        mx_ttft, mx_first = self._mx["ttft"], self._mx["first_chunk"]
 
         def _on_tokens(new, done, _orig=on_tokens, _tr=track):
             if _tr["first"] is None and new:
-                _tr["first"] = time.perf_counter()
-                mx_ttft.observe(_tr["first"] - _tr["submit"])
+                _tr["first"] = first = time.perf_counter()
+                mx_ttft.observe(first - _tr["submit"])
+                # ttft = queue_wait + prefill + first_chunk. "prefill_s" is
+                # absent while cb.admit itself is still running: tokens it
+                # delivered waited for no chunk.
+                prefill_s = _tr.get("prefill_s")
+                _tr["first_chunk_s"] = (
+                    0.0 if prefill_s is None
+                    else max(0.0, first - _tr["admit"] - prefill_s)
+                )
+                mx_first.observe(_tr["first_chunk_s"])
             _tr["tokens"] += len(new)
             if _orig is not None:
                 _orig(new, done)
 
         try:
-            rid = self.cb.admit(
-                ids, max_new_tokens=max_new, temperature=temp, on_tokens=_on_tokens
-            )
+            with annotate("serve.admit"):
+                rid = self.cb.admit(
+                    ids, max_new_tokens=max_new, temperature=temp, on_tokens=_on_tokens
+                )
         except Exception as e:  # noqa: BLE001 — admission errors belong to the caller
             self._m_requests.labels(engine=self.name, outcome="rejected").inc()
             self._fail(fut, e)
@@ -2093,17 +2112,22 @@ class ServingEngine:
             # its thread); admission itself runs unlocked — it can hide a
             # prefill compile and must not block submitters that long.
             nonlocal pending_spec
-            try:
-                while True:
-                    item = self._q.get(timeout=0.1) if block else self._q.get_nowait()
-                    block = False
-                    if item[0] in ("cancel", "prefix"):
-                        self._admit_one(item)
-                    else:
-                        with self._submit_lock:
-                            self._waiting.append(item)
-            except queue.Empty:
-                pass
+            # An empty pool blocks here for the next arrival: that wait is
+            # "serve.wait", which never counts as a stall; the non-blocking
+            # drain of the queue is "serve.pump". Admissions below are
+            # phases of their own ("serve.admit").
+            with annotate("serve.wait" if block else "serve.pump"):
+                try:
+                    while True:
+                        item = self._q.get(timeout=0.1) if block else self._q.get_nowait()
+                        block = False
+                        if item[0] in ("cancel", "prefix"):
+                            self._admit_one(item)
+                        else:
+                            with self._submit_lock:
+                                self._waiting.append(item)
+                except queue.Empty:
+                    pass
             while self.cb.has_capacity:
                 with self._submit_lock:
                     if not self._waiting:
@@ -2113,14 +2137,35 @@ class ServingEngine:
                     drain_spec()
                 self._admit_one(item)
 
+        def dispatch(step):
+            with annotate("serve.chunk.dispatch"):
+                return step()
+
+        def process(handle, process_chunk) -> None:
+            # The wait for the device is a phase of its own: a chunk lasts
+            # chunk_steps x the model's step whatever the host does, so it
+            # never counts as a host stall. What follows is host work:
+            # the copy, accept, callbacks, the finished requests' futures.
+            if handle is not None:
+                with annotate("serve.chunk.fetch"):
+                    handle[0].block_until_ready()
+                with annotate("serve.chunk.process"):
+                    self._finish_rids(process_chunk(handle))
+
+        def process_plain(handle) -> None:
+            process(handle, self.cb.process_chunk)
+
         def drain_spec() -> None:
             nonlocal pending_spec
-            finish(self.cb.process_spec_chunk(pending_spec))
+            process(pending_spec, self.cb.process_spec_chunk)
             pending_spec = None
 
-        finish = self._finish_rids
-
+        t_cycle = None
         while not self._closed.is_set():
+            now = time.perf_counter()
+            if t_cycle is not None:
+                observe_phase("serve.cycle", now - t_cycle)
+            t_cycle = now
             # Idle: block briefly for the next arrival (bounded so
             # close() is prompt) instead of spinning on an empty pool.
             pump_queue(
@@ -2132,11 +2177,12 @@ class ServingEngine:
             # Deadline sweep between chunks: expired requests retire via
             # the cancel_request done-flag path (safe while a pipelined
             # plain or verify handle is still in flight).
-            self._expire_deadlines()
+            with annotate("serve.expire"):
+                self._expire_deadlines()
             if self.cb.spec_ready():
                 # Flavor switch plain→spec: drain the plain handle so
                 # the verify dispatch sees authoritative positions.
-                finish(self.cb.process_chunk(pending_handle))
+                process_plain(pending_handle)
                 pending_handle = None
                 if self.cb.slots:
                     self._note_active()
@@ -2149,7 +2195,7 @@ class ServingEngine:
                         # i+1 (cursor drafts), THEN fetch chunk i —
                         # the draft/accept host work and the fetch
                         # RTT ride under the device's verify time.
-                        nxt = self.cb.step_spec_async()
+                        nxt = dispatch(self.cb.step_spec_async)
                         drain_spec()
                         pending_spec = nxt
                         self._bump("chunks")
@@ -2159,11 +2205,9 @@ class ServingEngine:
                         if pending_spec is not None:
                             drain_spec()
                         if self.cb.slots and self.cb.spec_ready():
-                            h = self.cb.step_spec_async()
-                            if pipelined:
-                                pending_spec = h
-                            else:
-                                finish(self.cb.process_spec_chunk(h))
+                            pending_spec = dispatch(self.cb.step_spec_async)
+                            if not pipelined:
+                                drain_spec()
                             self._bump("chunks")
                 elif pending_spec is not None:
                     drain_spec()
@@ -2175,15 +2219,15 @@ class ServingEngine:
                 if not self.cb.slots:
                     continue  # the drain retired the whole pool
                 self._note_active()
-                handle = self.cb.step_async()
+                handle = dispatch(self.cb.step_async)
                 self._bump("chunks")
                 if not pipelined:
-                    finish(self.cb.process_chunk(handle))
+                    process_plain(handle)
                 else:
-                    finish(self.cb.process_chunk(pending_handle))
+                    process_plain(pending_handle)
                     pending_handle = handle
             else:
-                finish(self.cb.process_chunk(pending_handle))
+                process_plain(pending_handle)
                 pending_handle = None
                 if pending_spec is not None:
                     drain_spec()

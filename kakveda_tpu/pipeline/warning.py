@@ -19,6 +19,7 @@ import time
 from typing import List, Optional, Sequence
 
 from kakveda_tpu.core import metrics as _metrics
+from kakveda_tpu.core import profiling
 from kakveda_tpu.core.config import ConfigStore
 from kakveda_tpu.core.fingerprint import signature_text
 from kakveda_tpu.core.schemas import WarningRequest, WarningResponse
@@ -39,7 +40,8 @@ class WarningPolicy:
         reg = _metrics.get_registry()
         self._m_batch = reg.histogram(
             "kakveda_warn_batch_seconds",
-            "Device kNN match wall per warn batch",
+            "Match wall per warn batch: signatures, featurize, dispatch, "
+            "fetch and match assembly (not the device scan alone)",
         )
         self._m_verdicts = reg.counter(
             "kakveda_warn_requests_total",
@@ -51,10 +53,8 @@ class WarningPolicy:
 
     def warn_batch(self, reqs: Sequence[WarningRequest]) -> List[WarningResponse]:
         t0 = time.perf_counter()
-        threshold = self.config.similarity_threshold()
-        default_action = self.config.default_action()
-
-        sigs = [signature_text(r.prompt, r.tools, r.env) for r in reqs]
+        with profiling.annotate("warn.signature"):
+            sigs = [signature_text(r.prompt, r.tools, r.env) for r in reqs]
         # Device-loss degraded mode (core/admission.py): while the backend
         # is latched DEGRADED we never even dispatch (a wedged chip hangs,
         # it doesn't error) — the GFKB's host-warm/disk-cold tiers answer
@@ -85,50 +85,62 @@ class WarningPolicy:
                 all_matches, tier_info = self.gfkb.match_batch_fallback(sigs)
                 degraded = True
         self._m_batch.observe(time.perf_counter() - t0)
-        patterns = self.gfkb.list_patterns()
+        with profiling.annotate("warn.patterns"):
+            # list_patterns() copies every pattern's failure ids (262,144 of
+            # them in the benchmark's deployment): the copy and its release
+            # both belong to this phase, so keep only the id a verdict can
+            # carry and let the copy go here.
+            citation_pattern_id = next(
+                (p.pattern_id for p in self.gfkb.list_patterns()
+                 if p.name == _CITATION_PATTERN_NAME),
+                None,
+            )
+        with profiling.annotate("warn.policy"):
+            # The config is stat-ed for hot reload on every read: syscalls,
+            # so they belong under the phase that uses what they return.
+            threshold = self.config.similarity_threshold()
+            default_action = self.config.default_action()
+            out: List[WarningResponse] = []
+            for matches in all_matches:
+                best = matches[0] if matches else None
+                score = best.score if best else 0.0
 
-        out: List[WarningResponse] = []
-        for matches in all_matches:
-            best = matches[0] if matches else None
-            score = best.score if best else 0.0
-
-            pattern_id = None
-            if best and best.failure_type == HALLUCINATION_CITATION:
-                for p in patterns:
-                    if p.name == _CITATION_PATTERN_NAME:
-                        pattern_id = p.pattern_id
-                        break
-
-            if best and score >= threshold:
-                out.append(
-                    WarningResponse(
-                        action=default_action,
-                        confidence=score,
-                        pattern_id=pattern_id,
-                        references=[best],
-                        message=(
-                            f"This execution matches past failure type {best.failure_type} "
-                            f"(failure_id={best.failure_id}, similarity={score:.2f}). "
-                            f"Suggested mitigation: {best.suggested_mitigation or 'n/a'}"
-                        ),
-                        degraded=degraded,
-                        tier=tier_info.get("tier"),
-                        nprobe=tier_info.get("nprobe"),
-                    )
+                pattern_id = (
+                    citation_pattern_id
+                    if best and best.failure_type == HALLUCINATION_CITATION
+                    else None
                 )
-            else:
-                out.append(
-                    WarningResponse(
-                        action="silent" if default_action == "silent" else "warn",
-                        confidence=score,
-                        pattern_id=pattern_id,
-                        references=[],
-                        message="No high-similarity match found in GFKB.",
-                        degraded=degraded,
-                        tier=tier_info.get("tier"),
-                        nprobe=tier_info.get("nprobe"),
+
+                if best and score >= threshold:
+                    out.append(
+                        WarningResponse(
+                            action=default_action,
+                            confidence=score,
+                            pattern_id=pattern_id,
+                            references=[best],
+                            message=(
+                                f"This execution matches past failure type {best.failure_type} "
+                                f"(failure_id={best.failure_id}, similarity={score:.2f}). "
+                                f"Suggested mitigation: {best.suggested_mitigation or 'n/a'}"
+                            ),
+                            degraded=degraded,
+                            tier=tier_info.get("tier"),
+                            nprobe=tier_info.get("nprobe"),
+                        )
                     )
-                )
-        for r in out:
-            self._m_verdicts.labels(action=r.action).inc()
+                else:
+                    out.append(
+                        WarningResponse(
+                            action="silent" if default_action == "silent" else "warn",
+                            confidence=score,
+                            pattern_id=pattern_id,
+                            references=[],
+                            message="No high-similarity match found in GFKB.",
+                            degraded=degraded,
+                            tier=tier_info.get("tier"),
+                            nprobe=tier_info.get("nprobe"),
+                        )
+                    )
+            for r in out:
+                self._m_verdicts.labels(action=r.action).inc()
         return out

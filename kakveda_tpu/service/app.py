@@ -35,6 +35,7 @@ from pydantic import ValidationError
 from kakveda_tpu.core import admission as _admission
 from kakveda_tpu.core import faults as _faults
 from kakveda_tpu.core.admission import DeviceUnavailableError, OverloadError
+from kakveda_tpu.core.profiling import observe_phase
 from kakveda_tpu.core import sanitize
 from kakveda_tpu.core import trace as _trace
 from kakveda_tpu.core.runtime import ensure_request_id, get_runtime_config
@@ -797,6 +798,7 @@ def make_app(
     # --- warn (micro-batched) -------------------------------------------
 
     async def warn(request):
+        t_in = time.perf_counter()
         try:
             req = WarningRequest.model_validate(await request.json())
         except (ValidationError, ValueError) as e:
@@ -810,7 +812,9 @@ def make_app(
         with _trace.get_tracer().start_span(
             "gfkb.warn", app_id=req.app_id
         ) as gspan:
+            t_sub = time.perf_counter()
             res = await warn_batcher.submit(req)
+            t_out = time.perf_counter()
             gspan.set(
                 tier=res.tier, nprobe=res.nprobe, degraded=res.degraded,
                 native=_native_avail, action=res.action,
@@ -823,7 +827,12 @@ def make_app(
         _h_warn.observe(
             time.perf_counter() - t0, exemplar=gspan.trace_id or None
         )
-        return web.json_response(res.model_dump())
+        resp = web.json_response(res.model_dump())
+        # The handler's own work on either side of the batcher: read +
+        # validate, then verdict span + dump + JSON (the wait between the
+        # two is the batcher's, under its own series).
+        observe_phase("warn.http", (t_sub - t_in) + (time.perf_counter() - t_out))
+        return resp
 
     # --- GFKB -----------------------------------------------------------
 

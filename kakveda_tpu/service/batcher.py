@@ -44,6 +44,7 @@ from typing import (
 )
 
 from kakveda_tpu.core import metrics as _metrics
+from kakveda_tpu.core.profiling import observe_phase
 from kakveda_tpu.core.admission import (
     AdmissionController,
     _env_float,
@@ -114,6 +115,19 @@ class MicroBatcher(Generic[TReq, TRes]):
             "Coalesced batch size per micro-batcher drain", ("batcher",),
             buckets=(1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0),
         ).labels(batcher=name)
+        self._m_wait = reg.histogram(
+            "kakveda_microbatch_wait_seconds",
+            "Per-request wait in a micro-batcher queue: enqueue to the close "
+            "of the batch that took the request", ("batcher",),
+        ).labels(batcher=name)
+        # Phase names of the drain loop (docs/observability.md § Phases),
+        # resolved once: "<name>.cycle" is one whole iteration, the rest
+        # are its parts on this thread.
+        self._ph_cycle = f"{name}.cycle"
+        self._ph_collect = f"{name}.batcher.collect"
+        self._ph_handoff = f"{name}.batcher.handoff"
+        self._ph_resolve = f"{name}.batcher.resolve"
+        self._ph_wake = f"{name}.batcher.wake"  # per request, not a part of the cycle
 
     def start(self) -> None:
         if self._task is None or self._task.done():
@@ -169,7 +183,13 @@ class MicroBatcher(Generic[TReq, TRes]):
         if self._fair and tenant:
             self._queued[tenant] = self._queued.get(tenant, 0) + 1
         await self._queue.put((req, fut, time.monotonic(), tenant))
-        return await fut
+        res, t_done = await fut
+        # From the batch's end on the executor thread to this waiter
+        # running again: the hop back to the loop, then the loop wakes the
+        # batch's waiters one after another, each running its handler's
+        # tail before the next.
+        observe_phase(self._ph_wake, time.perf_counter() - t_done)
+        return res
 
     def _shed(self, reason: str, detail: str, tenant: str = "") -> None:
         # Shed while it's still cheap: the typed error carries the
@@ -278,27 +298,48 @@ class MicroBatcher(Generic[TReq, TRes]):
             del self._served[heaviest]
         self._served[tenant] = self._served.get(tenant, 0) + n
 
+    def _run_handed_off(self, t_closed: float, reqs: List[TReq]) -> Tuple[List[TRes], float]:
+        """The batch on the executor thread, stamped at both ends: the hop
+        there (thread pool and GIL latency) and the hop back to the loop
+        are phases of the cycle like the work between them."""
+        observe_phase(self._ph_handoff, time.perf_counter() - t_closed)
+        results = self._run_batch(reqs)
+        return results, time.perf_counter()
+
     async def _drain(self) -> None:
         loop = asyncio.get_running_loop()
+        t_cycle = time.perf_counter()
         while True:
             batch = await self._collect()
+            t_closed = time.perf_counter()
+            observe_phase(self._ph_collect, t_closed - t_cycle)
             self._m_size.observe(len(batch))
             self._m_depth.set(self._depth())
+            now = time.monotonic()
+            for item in batch:
+                self._m_wait.observe(now - item[2])
             if self._admission is not None:
                 # Oldest item's wait = the batch's worst queue delay; one
                 # sample per drain keeps the wait history cheap and honest.
-                self._admission.note_wait(
-                    self._klass, time.monotonic() - batch[0][2]
-                )
+                self._admission.note_wait(self._klass, now - batch[0][2])
             reqs = [b[0] for b in batch]
             try:
                 # The device call is sync; run it off-loop so new requests
                 # keep enqueueing while the match executes.
-                results = await loop.run_in_executor(None, self._run_batch, reqs)
+                results, t_done = await loop.run_in_executor(
+                    None, self._run_handed_off, t_closed, reqs
+                )
                 for (_, fut, _, _), res in zip(batch, results):
                     if not fut.done():
-                        fut.set_result(res)
+                        fut.set_result((res, t_done))
             except Exception as e:  # noqa: BLE001 — propagate to all waiters
+                t_done = time.perf_counter()
                 for _, fut, _, _ in batch:
                     if not fut.done():
                         fut.set_exception(e)
+            t_end = time.perf_counter()
+            # From the batch's end on the executor thread: the hop back to
+            # this loop, then every waiter resolved.
+            observe_phase(self._ph_resolve, t_end - t_done)
+            observe_phase(self._ph_cycle, t_end - t_cycle)
+            t_cycle = t_end

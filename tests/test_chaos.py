@@ -134,7 +134,9 @@ def test_deadline_expired_request_retires_cleanly(monkeypatch):
     try:
         # Warm the compiled paths so the deadline races decode, not compile.
         eng.submit([9, 8, 7], max_new_tokens=4).result(timeout=120)
-        fut = eng.submit([5, 6, 7], max_new_tokens=90, deadline_s=0.02)
+        # 90 tokens take 20-25 ms here on an idle CPU: a 20 ms deadline let
+        # one request in ten finish first. Half of that cannot be met.
+        fut = eng.submit([5, 6, 7], max_new_tokens=90, deadline_s=0.01)
         with pytest.raises(DeadlineExceededError) as ei:
             fut.result(timeout=120)
         assert isinstance(ei.value.tokens, list) and len(ei.value.tokens) < 90
