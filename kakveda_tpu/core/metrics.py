@@ -504,6 +504,9 @@ _CORE_FAMILIES = (
     ("histogram", "kakveda_microbatch_wait_seconds",
      "Per-request wait in a micro-batcher queue: enqueue to the close of "
      "the batch that took the request", ("batcher",), None),
+    ("counter", "kakveda_microbatch_overlapped_total",
+     "Batches handed off while another batch was in flight",
+     ("batcher",), None),
     ("histogram", "kakveda_serving_first_chunk_seconds",
      "End of a request's admission prefill to its first delivered tokens "
      "(chunks queued ahead, its first decode chunk, the fetch)",

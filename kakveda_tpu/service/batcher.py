@@ -10,6 +10,22 @@ every waiter. Under load the batch fills instantly and per-request cost is
 batch_time/B (see bench.py); when idle a lone request pays only the
 deadline (default 2 ms) on top of its own match.
 
+Two in flight: once batch N is handed to the executor the drain loop goes
+straight on to collect batch N+1 and hands it off while N still scans, with
+at most ``MAX_IN_FLIGHT`` = 2 batches between hand-off and resolve. Why two:
+the scan is under a third of a batch's wall, the rest is host work
+(signatures, featurize, dispatch, fetch, assembly, policy) that one batch in
+flight left the device idle for; a second batch prepares and dispatches
+while the first sits in its result fetch with the interpreter lock
+released. The device is serial and HBM-bound, so a third would only queue
+behind the two and add its wait to every request. A batch closes when it is
+full or past its deadline AND a place is free; while both are taken the
+arrivals stay queued (counted by the submit-side bounds as ever) and the
+batch takes all of them, up to ``max_batch``, the moment a place frees. Each
+batch resolves its own waiters when it returns, whichever returns first; an
+exception fails that batch's waiters alone. With nothing in flight the
+behaviour is one batch's: first request, deadline, hand-off.
+
 Overload protection (core/admission.py): the queue is BOUNDED. Past
 ``max_queue`` waiting requests, ``submit`` sheds immediately with a typed
 ``OverloadError`` (HTTP tier: 429 + Retry-After) instead of queueing into
@@ -40,7 +56,7 @@ import asyncio
 import time
 from collections import OrderedDict, deque
 from typing import (
-    Awaitable, Callable, Generic, List, Optional, Sequence, Tuple, TypeVar,
+    Awaitable, Callable, Dict, Generic, List, Optional, Sequence, Tuple, TypeVar,
 )
 
 from kakveda_tpu.core import metrics as _metrics
@@ -63,6 +79,10 @@ _Item = Tuple[TReq, asyncio.Future, float, str]
 # heavy an hour ago isn't deprioritized forever — and zeros drop, which
 # (with the eviction in _bump_served) bounds the table under key churn.
 _SERVED_DECAY_EVERY = 256
+
+# Batches between hand-off and resolve: one on the device, one being
+# prepared or queued behind it (module docstring: why two).
+MAX_IN_FLIGHT = 2
 
 
 class MicroBatcher(Generic[TReq, TRes]):
@@ -105,6 +125,11 @@ class MicroBatcher(Generic[TReq, TRes]):
         self._drains = 0
         self._queue: asyncio.Queue[_Item] = asyncio.Queue()
         self._task: asyncio.Task | None = None
+        # One place per batch in flight: taken at the batch's close,
+        # released when it lands. _flights: executor call -> (its batch,
+        # the start of its collect).
+        self._places = asyncio.Semaphore(MAX_IN_FLIGHT)
+        self._flights: Dict[asyncio.Future, Tuple[List[_Item], float]] = {}
         reg = _metrics.get_registry()
         self._m_depth = reg.gauge(
             "kakveda_microbatch_queue_depth",
@@ -120,9 +145,15 @@ class MicroBatcher(Generic[TReq, TRes]):
             "Per-request wait in a micro-batcher queue: enqueue to the close "
             "of the batch that took the request", ("batcher",),
         ).labels(batcher=name)
-        # Phase names of the drain loop (docs/observability.md § Phases),
-        # resolved once: "<name>.cycle" is one whole iteration, the rest
-        # are its parts on this thread.
+        self._m_overlapped = reg.counter(
+            "kakveda_microbatch_overlapped_total",
+            "Batches handed off while another batch was in flight",
+            ("batcher",),
+        ).labels(batcher=name)
+        # Phase names of a batch (docs/observability.md § Phases), resolved
+        # once: "<name>.cycle" is one batch's life, from the start of its
+        # collect to its waiters resolved; the rest are its parts on the
+        # loop. Batches overlap, so cycles do.
         self._ph_cycle = f"{name}.cycle"
         self._ph_collect = f"{name}.batcher.collect"
         self._ph_handoff = f"{name}.batcher.handoff"
@@ -141,12 +172,20 @@ class MicroBatcher(Generic[TReq, TRes]):
             except asyncio.CancelledError:
                 pass
             self._task = None
-        # Carried items would otherwise dangle with no drain loop; queued
-        # items keep seed behavior (they die with the queue on shutdown).
-        for item in self._carry:
+        # Batches in flight finish on their executor threads with nobody
+        # listening; their waiters, like the carried ones (and those of a
+        # batch the drain loop was still collecting), would otherwise dangle
+        # with no drain loop. Queued items keep seed behavior (they die with
+        # the queue on shutdown).
+        dangling, self._carry = self._carry, []
+        for call, (batch, _) in self._flights.items():
+            call.cancel()
+            dangling += batch
+        self._flights = {}
+        self._places = asyncio.Semaphore(MAX_IN_FLIGHT)
+        for item in dangling:
             if not item[1].done():
                 item[1].cancel()
-        self._carry.clear()
 
     def _depth(self) -> int:
         return self._queue.qsize() + len(self._carry)
@@ -208,19 +247,18 @@ class MicroBatcher(Generic[TReq, TRes]):
     # -- batch collection -------------------------------------------------
 
     async def _collect(self) -> List[_Item]:
+        """The next batch, closed: full or past its deadline, and holding
+        the in-flight place it waited for."""
         if not self._fair:
             return await self._collect_fifo(self.max_batch)
         if self._carry:
             # Deferred items go first; top up with whatever is already
             # waiting (no deadline wait — the carry proves oversubscription
             # and the queue is being fed faster than it drains).
+            await self._places.acquire()
             cands = self._carry
             self._carry = []
-            while len(cands) < 2 * self.max_batch:
-                try:
-                    cands.append(self._queue.get_nowait())
-                except asyncio.QueueEmpty:
-                    break
+            self._top_up(cands, 2 * self.max_batch)
         else:
             # Pull up to 2x max_batch so composition sees the cross-tenant
             # mix the cap is supposed to act on; the overflow carries.
@@ -228,19 +266,33 @@ class MicroBatcher(Generic[TReq, TRes]):
         return self._compose(cands)
 
     async def _collect_fifo(self, limit: int) -> List[_Item]:
-        first = await self._queue.get()
-        batch = [first]
-        loop = asyncio.get_running_loop()
-        deadline = loop.time() + self.deadline_s
-        while len(batch) < limit:
-            timeout = deadline - loop.time()
-            if timeout <= 0:
-                break
-            try:
-                batch.append(await asyncio.wait_for(self._queue.get(), timeout))
-            except asyncio.TimeoutError:
-                break
+        batch = [await self._queue.get()]
+        try:
+            loop = asyncio.get_running_loop()
+            deadline = loop.time() + self.deadline_s
+            while len(batch) < limit:
+                timeout = deadline - loop.time()
+                if timeout <= 0:
+                    break
+                try:
+                    batch.append(await asyncio.wait_for(self._queue.get(), timeout))
+                except asyncio.TimeoutError:
+                    break
+            # Both places taken: arrivals stay queued, where the submit-side
+            # bounds count them, and join the batch the moment one frees.
+            await self._places.acquire()
+        except asyncio.CancelledError:
+            self._carry = batch + self._carry  # stop() cancels these waiters
+            raise
+        self._top_up(batch, limit)
         return batch
+
+    def _top_up(self, batch: List[_Item], limit: int) -> None:
+        while len(batch) < limit:
+            try:
+                batch.append(self._queue.get_nowait())
+            except asyncio.QueueEmpty:
+                break
 
     def _compose(self, cands: List[_Item]) -> List[_Item]:
         """Deficit round-robin batch composition over per-tenant subqueues.
@@ -308,11 +360,21 @@ class MicroBatcher(Generic[TReq, TRes]):
 
     async def _drain(self) -> None:
         loop = asyncio.get_running_loop()
-        t_cycle = time.perf_counter()
         while True:
+            t_open = time.perf_counter()
             batch = await self._collect()
             t_closed = time.perf_counter()
-            observe_phase(self._ph_collect, t_closed - t_cycle)
+            # The device call is sync; run it off-loop so new requests keep
+            # enqueueing, and the next batch keeps forming, while the match
+            # executes.
+            call = loop.run_in_executor(
+                None, self._run_handed_off, t_closed, [b[0] for b in batch]
+            )
+            if self._flights:
+                self._m_overlapped.inc()
+            self._flights[call] = (batch, t_open)
+            call.add_done_callback(self._land)
+            observe_phase(self._ph_collect, t_closed - t_open)
             self._m_size.observe(len(batch))
             self._m_depth.set(self._depth())
             now = time.monotonic()
@@ -322,24 +384,30 @@ class MicroBatcher(Generic[TReq, TRes]):
                 # Oldest item's wait = the batch's worst queue delay; one
                 # sample per drain keeps the wait history cheap and honest.
                 self._admission.note_wait(self._klass, now - batch[0][2])
-            reqs = [b[0] for b in batch]
-            try:
-                # The device call is sync; run it off-loop so new requests
-                # keep enqueueing while the match executes.
-                results, t_done = await loop.run_in_executor(
-                    None, self._run_handed_off, t_closed, reqs
-                )
-                for (_, fut, _, _), res in zip(batch, results):
-                    if not fut.done():
-                        fut.set_result((res, t_done))
-            except Exception as e:  # noqa: BLE001 — propagate to all waiters
-                t_done = time.perf_counter()
-                for _, fut, _, _ in batch:
-                    if not fut.done():
-                        fut.set_exception(e)
-            t_end = time.perf_counter()
-            # From the batch's end on the executor thread: the hop back to
-            # this loop, then every waiter resolved.
-            observe_phase(self._ph_resolve, t_end - t_done)
-            observe_phase(self._ph_cycle, t_end - t_cycle)
-            t_cycle = t_end
+
+    def _land(self, call: asyncio.Future) -> None:
+        """A batch back from the executor, on the loop: it resolves its own
+        waiters, whichever batch returns first."""
+        flight = self._flights.pop(call, None)
+        if flight is None:
+            return  # stop() took the batch and made the places anew
+        batch, t_open = flight
+        # The place first: the drain loop's wake-up then runs ahead of the
+        # handlers this batch is about to wake, so the next batch closes
+        # before they take the loop.
+        self._places.release()
+        try:
+            results, t_done = call.result()
+            for (_, fut, _, _), res in zip(batch, results):
+                if not fut.done():
+                    fut.set_result((res, t_done))
+        except Exception as e:  # noqa: BLE001 — propagate to this batch's waiters
+            t_done = time.perf_counter()
+            for _, fut, _, _ in batch:
+                if not fut.done():
+                    fut.set_exception(e)
+        t_end = time.perf_counter()
+        # From the batch's end on the executor thread: the hop back to
+        # this loop, then every waiter resolved.
+        observe_phase(self._ph_resolve, t_end - t_done)
+        observe_phase(self._ph_cycle, t_end - t_open)
