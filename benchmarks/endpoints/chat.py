@@ -16,10 +16,11 @@ import time
 
 import aiohttp
 
-from harness import textgen
+from harness import manifest, textgen
 from harness.stats import percentile
 
 NEEDS_LOGIN = True
+SEGMENTS = 5  # parts of the window in the candidate reading "median of the parts' p95s" (PERF.md section 2: measured, not taken)
 
 
 async def stream_call(target, prompt: str) -> dict:
@@ -94,6 +95,10 @@ def compare(st, run) -> dict:
     logit_gap       the widest such gap (reported, not compared: it swings threefold from seed to
                     seed and comes within 2.4 times of its control's, PERF.md section 2)
     unserved        requests the engine never answered, or answered with an error
+
+    Reported beside them, from the same records: every candidate reading of the first-token and
+    per-token tails (the window's own percentile, the median of its SEGMENTS parts' percentiles,
+    the window's median), so that a run under ``--freeze`` shows which of them one freeze moves.
     """
     ctx = run.ctx
     recs = run.srv.ctl("/chat/records")["records"]
@@ -108,6 +113,13 @@ def compare(st, run) -> dict:
     unserved = sum(1 for p in sent if p not in by_prompt or by_prompt[p]["out"] is None)
     done = [by_prompt[p] for p in sent if p in by_prompt and by_prompt[p]["out"]]
     out = {"unserved": unserved, "requests": 0}
+    lat, tpot = manifest.load_module("readers", "latency_pct"), manifest.load_module("readers", "chat_tpot")
+    first = {"endpoint": st.endpoint, "field": "first"}
+    out.update(ttft_window_p95_ms=lat.read(ctx, {**first, "q": 95}),
+               ttft_segments_p95_ms=lat.read(ctx, {**first, "q": 95, "segments": SEGMENTS}),
+               ttft_window_p50_ms=lat.read(ctx, {**first, "q": 50}),
+               tpot_window_p95_ms=tpot.read(ctx, {"q": 95}),
+               tpot_segments_p95_ms=tpot.read(ctx, {"q": 95, "segments": SEGMENTS}))
     if not done:
         return out
     k = int(st.spec.get("check_sample", 8))

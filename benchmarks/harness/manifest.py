@@ -15,8 +15,15 @@ and what a stream or a metric needs in code is a module found by name as well:
     benchmarks/arrivals/<arrivals>.py    (the due times)
     benchmarks/readers/<reader>.py       (a metric from records, /metrics or the trace)
 
-so a later PR adds a cell, a configuration, a mix, an endpoint, an arrival
-kind or a metric by adding files and one entry, and edits nothing that is here.
+A configuration that has a model names its family (``"family": "<name>"``):
+
+    benchmarks/families/<family>.py      (check, build, reference_logits, work: the only file
+                                          that knows an architecture's keys or the program's
+                                          constructors for it)
+
+so a later PR adds a cell, a configuration, a model family, a mix, an endpoint,
+an arrival kind or a metric by adding files and one entry, and edits nothing
+that is here.
 """
 
 from __future__ import annotations
@@ -47,7 +54,7 @@ def _load_json(path: Path, what: str) -> dict:
 
 
 def load_module(kind: str, name: str, bench_dir: Path = BENCH_DIR):
-    """The module ``benchmarks/<kind>/<name>.py`` (endpoints, loops, arrivals, readers)."""
+    """The module ``benchmarks/<kind>/<name>.py`` (endpoints, loops, arrivals, readers, families)."""
     path = bench_dir / kind / f"{name}.py"
     if not path.is_file():
         raise ManifestError(f"{kind}: no file {path}")
@@ -55,6 +62,11 @@ def load_module(kind: str, name: str, bench_dir: Path = BENCH_DIR):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_family(config: dict, bench_dir: Path = BENCH_DIR):
+    """The family module of a configuration that has a model (``"family"``)."""
+    return load_module("families", config["family"], bench_dir)
 
 
 @dataclass(frozen=True)
@@ -91,6 +103,11 @@ def load_cell(name: str, root: Path = ROOT, bench_dir: Path | None = None) -> Ce
     if w["config"] not in configs:
         raise ManifestError(f"workload {name!r} names unknown config {w['config']!r}")
     config = _load_json(root / configs[w["config"]]["file"], f"config {w['config']!r}")
+    if config.get("family"):  # a configuration that has a model: its family refuses what it cannot build
+        try:
+            load_family(config, bench_dir).check(config)
+        except ValueError as e:
+            raise ManifestError(f"config {w['config']!r}: {e}") from e
     traffic = _load_json(bench_dir / "traffic" / f"{w['traffic']}.json", f"traffic {w['traffic']!r}")
     if not traffic.get("streams"):
         raise ManifestError(f"traffic {w['traffic']!r} has no streams")

@@ -1,4 +1,6 @@
-"""Published peaks of the chips, and the work each program needs from its shapes.
+"""Published peaks of the chips, the work an exact index scan needs from its
+shapes, and the arithmetic of a share. (The work of a model's forward pass is
+its family's: ``families/<family>.py:work``.)
 
 The peaks are the table of the repo's ``bench.py`` (``DEVICE_PEAKS``), copied
 here so that a later PR cannot move the yardstick. Source: Google Cloud TPU
@@ -28,34 +30,6 @@ def knn_scan_bytes(capacity: int, dim: int, row_bytes: int, batch: int, top_k: i
 
 def knn_scan_flops(capacity: int, dim: int, batch: int) -> int:
     return 2 * capacity * dim * batch
-
-
-def lm_param_counts(cfg: dict) -> dict:
-    """Parameters of a Llama/Mistral-shaped decoder from its published keys."""
-    d, ff, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
-    h, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd = cfg.get("head_dim") or d // h
-    attn = d * h * hd + 2 * d * kv * hd + h * hd * d
-    mlp = 3 * d * ff
-    layers = cfg["num_hidden_layers"]
-    return {"per_layer": attn + mlp, "layers": layers * (attn + mlp), "embed": v * d, "lm_head": d * v,
-            "total": layers * (attn + mlp) + 2 * v * d}
-
-
-def lm_forward_flops(cfg: dict, tokens: int, attended: int, head_rows: int) -> int:
-    """Operations a forward pass needs: 2 per parameter of the layers per token,
-    attention's QK^T and PV over ``attended`` (query, key) pairs, and the output
-    head for ``head_rows`` rows (prefill reads one row's logits, decode all)."""
-    pc = lm_param_counts(cfg)
-    h = cfg["num_attention_heads"]
-    hd = cfg.get("head_dim") or cfg["hidden_size"] // h
-    attn = 4 * attended * h * hd * cfg["num_hidden_layers"]
-    return 2 * pc["layers"] * tokens + attn + 2 * pc["lm_head"] * head_rows
-
-
-def lm_weight_bytes(cfg: dict, bytes_per_param: int = 2) -> int:
-    pc = lm_param_counts(cfg)
-    return (pc["layers"] + pc["lm_head"]) * bytes_per_param  # a step reads no embedding table, only rows
 
 
 def roofline_seconds(flops: float, nbytes: float, kind: str) -> tuple:

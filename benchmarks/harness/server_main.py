@@ -1,7 +1,7 @@
 """The launcher: the one child that holds the chip.
 
-It builds what the configuration file says (seeded weights made by the
-benchmark, put into the program's runtime table), calls the program's own
+It builds what the configuration file says (its family's seeded weights,
+``families/<family>.py``, put into the program's runtime table), calls the program's own
 ``run_server`` — the same server, ``ServingEngine`` and routes as ``cli up`` —
 and, beside it, answers a small control port for what only the process that
 holds the chip can do: report the device and its memory, start and stop the
@@ -34,6 +34,7 @@ class State:
         self.records = []          # (prompt ids, future) for every engine submit
         self.runtime = None
         self.model_cfg = None
+        self.family = None         # the module families/<family>.py of the configuration
         self.seed = 0
         self.trace_dir = None
         self.trace_t0 = None
@@ -55,20 +56,15 @@ def _count_compiles():
 
 
 def _build_model(config: dict, seed: int):
-    """Seam 1 of ISSUE 23: a ``LlamaRuntime`` built from the configuration file
-    goes under "tpu" in the program's runtime table; ``run_server`` then serves
-    it as it would a preset."""
-    import jax.numpy as jnp
-
-    from harness import weights
+    """The configuration's family (``families/<family>.py``) builds the
+    program's runtime object round seeded weights; it goes under "tpu" in the
+    program's runtime table, and ``run_server`` then serves it as it would a
+    preset. What follows is the same for every family."""
+    from harness import manifest
     from kakveda_tpu.models import runtime as rt_mod
-    from kakveda_tpu.models.generate import LlamaRuntime
-    from kakveda_tpu.models.hf_convert import hf_config_to_llama
 
-    model = config  # the published config.json keys sit at the top level of the file
-    lcfg = hf_config_to_llama(model, dtype=jnp.bfloat16)
-    params = weights.make_params(seed, model)
-    rt = LlamaRuntime(cfg=lcfg, params=params, model_label=config["name"])
+    family = manifest.load_family(config)
+    rt = family.build(config, seed)
     eng = rt.engine()  # the KV pool is part of set-up, not of the first request
     if eng is None:
         raise RuntimeError("the configuration asks for the ServingEngine and the runtime built none")
@@ -82,7 +78,7 @@ def _build_model(config: dict, seed: int):
 
     eng.submit = recording_submit
     rt_mod._RUNTIMES["tpu"] = rt
-    STATE.runtime, STATE.model_cfg = rt, model
+    STATE.runtime, STATE.model_cfg, STATE.family = rt, config, family
 
 
 def _device_info() -> dict:
@@ -165,10 +161,10 @@ def _free_model() -> None:
 def _chat_reference(body: dict) -> dict:
     """Free the model, then run the plain reference over the sampled requests:
     each prompt with the tokens it was served. Returns every served token's
-    gap; with ``control`` the int8 pass's reading as well."""
+    gap; with ``control`` the lower-precision control's reading as well."""
     import numpy as np
 
-    from harness import reference_lm as ref
+    from harness import correct
 
     _free_model()
     sample = body["sample"]  # [{"ids": [...], "out": [...]}]
@@ -181,11 +177,12 @@ def _chat_reference(body: dict) -> dict:
         toks[r, :len(seq)] = seq
     plen, served = [len(s["ids"]) for s in sample], [s["out"] for s in sample]
     t0 = time.perf_counter()
-    lg = ref.logits(STATE.seed, STATE.model_cfg, toks, live)
-    out = {"gaps": ref.served_gaps(lg, plen, served), "reference_s": time.perf_counter() - t0}
+    family = STATE.family
+    lg = family.reference_logits(STATE.seed, STATE.model_cfg, toks, live)
+    out = {"gaps": correct.served_gaps(lg, plen, served), "reference_s": time.perf_counter() - t0}
     if body.get("control"):
-        ctl = ref.logits(STATE.seed, STATE.model_cfg, toks, live, int8=True)
-        out["control_gaps"] = ref.argmax_gaps(lg, ctl, plen, served)
+        ctl = family.reference_logits(STATE.seed, STATE.model_cfg, toks, live, control=True)
+        out["control_gaps"] = correct.argmax_gaps(lg, ctl, plen, served)
     return out
 
 
@@ -242,7 +239,7 @@ def main() -> int:
         from harness import faults
 
         faults.plant(args.fault)
-    if config.get("model_type"):
+    if config.get("family"):
         _build_model(config, args.seed)
 
     ctl = ThreadingHTTPServer(("127.0.0.1", args.ctl_port), Control)

@@ -4,9 +4,10 @@ delta). The server streams a delta per decode chunk, not per token, so the
 first delta already carries several tokens: under the byte tokenizer that the
 configuration assumes, as many as it has characters. Tokens are the ones the
 engine really produced for that prompt, taken from the launcher's record. A
-failed request has no time and counts as slower than any."""
+failed request has no time and counts as slower than any. ``segments`` as in
+``readers/latency_pct.py`` (default 1: the window's own percentile)."""
 
-from harness.stats import percentile
+from harness.stats import segment_median
 
 
 def read(ctx, params):
@@ -14,14 +15,16 @@ def read(ctx, params):
     tokens = ctx.get("chat_tokens") or {}
     if not recs:
         return None
-    xs = []
+    xs, dues = [], []
     for r in recs:
         n = tokens.get(r["prompt"])
         if r.get("first") is None or r.get("error") or not r.get("done") or not n:
             xs.append(float("inf"))  # a failed request is slower than any
+            dues.append(r["due"])
             continue
         later = n - r["first_chars"]
         if later >= 1:
             xs.append((r["last"] - r["first"]) * 1e3 / later)
-    v = percentile(xs, params["q"])
+            dues.append(r["due"])
+    v = segment_median(xs, dues, params["q"], int(params.get("segments", 1)), ctx["t_start"], ctx["seconds"])
     return None if v == float("inf") else v

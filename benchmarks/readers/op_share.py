@@ -1,11 +1,12 @@
 """A kernel's own share of its roofline, by the kernel's name among the
 device operations of the trace. Reported only where the trace has that kernel.
-``work``: flash_prefill — causal attention among one admitted prompt's tokens,
-per layer: 4 x (p^2 / 2) x heads x head_dim operations; bytes: q, k, v, out."""
+``work`` names what one run of the kernel does, and the configuration's family
+counts it (``families/<family>.py:work``; ``flash_prefill``: causal attention
+among the ``rows`` tokens of the admit bucket that takes the kernel)."""
 
 import re
 
-from harness import peaks
+from harness import manifest, peaks
 
 
 def read(ctx, params):
@@ -19,10 +20,8 @@ def read(ctx, params):
     if runs == 0 or secs <= 0:
         return None
     cfg = ctx["cell"].config
-    h, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd = cfg.get("head_dim") or cfg["hidden_size"] // h
-    p = params["rows"]  # the admit bucket that takes the kernel
-    flops = runs * 4 * (p * p / 2) * h * hd
-    nbytes = runs * 2 * (2 * p * h * hd + 2 * p * kv * hd)
+    shape = {k: v for k, v in params.items() if k not in ("op", "work")}
+    need = manifest.load_family(cfg).work(cfg, params["work"], **shape)
+    flops, nbytes = runs * need["flops"], runs * need["bytes"]
     least, _ = peaks.roofline_seconds(flops, nbytes, ctx["device"]["kind"])
     return peaks.share_pct(least, secs, params["op"])

@@ -1,6 +1,7 @@
 """A jitted program's share of its roofline, or of the chip's compute peak,
-from the device trace: the work the algorithm needs from the cell's shapes (a
-function of ``harness/peaks.py``), over the device time of the WHOLE program
+from the device trace: the work the algorithm needs from the cell's shapes
+(the index scan's from ``harness/peaks.py``, a model's forward pass from its
+family's ``work``, ``families/<family>.py``), over the device time of the WHOLE program
 that does it, found by the program's name — so the share reads the same work
 whether a Pallas kernel, XLA or something later implements it. ``reads``
 narrows a name that says little (the match program is ``jit__lambda``) to the
@@ -16,7 +17,7 @@ program's time.
 
 import re
 
-from harness import peaks, prom
+from harness import manifest, peaks, prom
 
 
 def read(ctx, params):
@@ -45,11 +46,11 @@ def read(ctx, params):
         if not recs:
             return None
         p_mean = sum(len(r["ids"]) for r in recs) / len(recs)
+        family = manifest.load_family(cfg)
         if work == "prefill":
             # one run admits one prompt: its tokens through the layers, causal
             # attention among them, one row of logits
-            flops = runs * peaks.lm_forward_flops(cfg, p_mean, p_mean * p_mean / 2, 1)
-            nbytes = runs * peaks.lm_weight_bytes(cfg)
+            need = family.work(cfg, "prefill", tokens=p_mean, attended=p_mean * p_mean / 2, head_rows=1)
         else:
             chunks = prom.delta(before, after, "kakveda_serving_chunk_seconds_count")
             tokens = sum(len(r["out"]) for r in recs)
@@ -57,8 +58,9 @@ def read(ctx, params):
                 return None
             per_run = tokens / chunks  # tokens decoded per chunk program, over the window
             ctx_len = p_mean + sum(len(r["out"]) for r in recs) / len(recs) / 2
-            flops = runs * peaks.lm_forward_flops(cfg, per_run, per_run * ctx_len, per_run)
-            nbytes = runs * sizes["serve_chunk"] * peaks.lm_weight_bytes(cfg)
+            need = family.work(cfg, "decode", tokens=per_run, attended=per_run * ctx_len, head_rows=per_run,
+                               steps=sizes["serve_chunk"])
+        flops, nbytes = runs * need["flops"], runs * need["bytes"]
     else:
         raise KeyError(f"unknown work {work!r}")
     pk = peaks.device_peaks(kind)

@@ -14,8 +14,16 @@ cell's traffic uses, drives the traffic from here for ``--seconds``, reads
 references, stops the child, and prints one JSON line. Everything a cell is
 made of is found by name: ``BENCHMARK.json`` names files under
 ``benchmarks/{configs,traffic,limits,metrics}``, and those name modules under
-``benchmarks/{endpoints,loops,arrivals,readers}``; nothing here knows a
-cell's, an endpoint's or a metric's name.
+``benchmarks/{endpoints,loops,arrivals,readers,families}`` (a configuration
+that has a model names its family, ``families/<family>.py``); nothing here
+knows a cell's, an endpoint's, a metric's or an architecture's name.
+
+Beside the server runs a canary (``harness/machine.py``), a child that only
+sleeps and writes down every gap between two wake-ups of over 50 ms: what it
+saw inside the window is in every result (``numbers.machine_freeze_ms``) and
+decides nothing. ``--freeze AT:SECONDS`` holds server, load generator and
+canary for SECONDS, AT seconds into the window, as a freeze of the machine
+would; like ``--rates`` it is calibration and prints no result.
 
 Without a chip it exits 2 and prints no result. ``--rehearse-on-cpu`` drives
 the same steps at the configuration's ``rehearsal`` sizes on the CPU, labels
@@ -48,7 +56,7 @@ sys.path.insert(0, str(BENCH))
 
 from types import SimpleNamespace  # noqa: E402
 
-from harness import correct, manifest, prom, store, streams, textgen  # noqa: E402
+from harness import correct, machine, manifest, prom, store, streams, textgen  # noqa: E402
 
 _label = ""
 
@@ -219,7 +227,7 @@ async def probes(target: streams.Target, cell_streams: list) -> dict:
 # --- the run ----------------------------------------------------------------------
 
 
-async def run_cell(args, cell: manifest.Cell, srv: Server, sizes: dict) -> dict:
+async def run_cell(args, cell: manifest.Cell, srv: Server, sizes: dict, canary) -> dict:
     loop = asyncio.get_running_loop()
     corpus = textgen.Corpus(args.seed)
     traffic = cell.traffic
@@ -256,6 +264,11 @@ async def run_cell(args, cell: manifest.Cell, srv: Server, sizes: dict) -> dict:
                 st.prepare(rate)
             t_start = time.perf_counter() + 0.05
             setup_s = t_start - T_PROCESS_START
+            t_start_mono = t_start + (time.monotonic() - time.perf_counter())  # the canary's clock
+            freezer = None
+            if args.freeze:
+                at, secs = args.freeze
+                freezer = machine.start("freeze", t_start_mono + at, secs, srv.proc.pid, f"{os.getpid()},{canary.pid}")
             trace_task = None
             if args.trace:
                 trace_task = asyncio.ensure_future(traced_window(srv, loop, t_start, float(args.seconds), traffic))
@@ -269,6 +282,7 @@ async def run_cell(args, cell: manifest.Cell, srv: Server, sizes: dict) -> dict:
                 await asyncio.gather(*[st.run(target, t_start) for st in cell_streams])
             finally:
                 gc.enable()
+                machine.stop(freezer)
             t_end = t_start + float(args.seconds)
             if args.rates:
                 sweep.append({"rate": rate, **{k: v for st in cell_streams for k, v in st.kind.sweep_row(st, t_end).items()}})
@@ -287,12 +301,15 @@ async def run_cell(args, cell: manifest.Cell, srv: Server, sizes: dict) -> dict:
             "setup_s": setup_s, "device": info1, "prom_before": prom0, "prom_after": prom1,
             "streams": {st.endpoint: st.records for st in cell_streams}, "trace": trace, "stored": stored,
             "probe": await probes(target, cell_streams) if args.trace else {},
+            "machine": machine.seen(srv.run_dir, t_start_mono, t_start_mono + float(args.seconds)),
         }
+        say("machine: " + json.dumps(ctx["machine"]))
         run = SimpleNamespace(args=args, srv=srv, ctx=ctx, sizes=sizes, target=target, loop=loop, say=say)
         numbers = {}
         for st in cell_streams:
             numbers.update(await st.kind.check(st, run))
         numbers["compiles_in_window"] = compiles_in_window
+        numbers.update(ctx["machine"])
         ok, compared = correct.judge(numbers, {**cell.limits, "compiles_in_window": 0})
         out.update(ctx=ctx, numbers=numbers, correct=ok, compared=compared,
                    attempted=sum(len(st.records) for st in cell_streams),
@@ -323,12 +340,19 @@ def main() -> int:
     ap.add_argument("--rehearse-on-cpu", action="store_true")
     ap.add_argument("--control", type=int, default=0, help="also read the lower-precision control (calibration only)")
     ap.add_argument("--rates", default="", help="comma list: one window per rate, no result (the sweep)")
+    ap.add_argument("--freeze", default="", help="AT:SECONDS: hold server, load generator and canary, no result")
     ap.add_argument("--fault", default="", help="benchmarks/tests only, with --rehearse-on-cpu: harness/faults.py")
     args = ap.parse_args()
     if args.fault and not args.rehearse_on_cpu:
         ap.error("--fault breaks the timed path for the tests; it runs only with --rehearse-on-cpu")
     if args.rehearse_on_cpu:
         _label = "[rehearsal on CPU — not a result] "
+    elif args.freeze:
+        _label = "[--freeze: calibration — not a result] "
+    if args.freeze:
+        args.freeze = tuple(float(x) for x in args.freeze.split(":"))
+        if len(args.freeze) != 2 or not 0 <= args.freeze[0] < sum(args.freeze) < args.seconds:
+            ap.error("--freeze AT:SECONDS has to end inside the window")
     try:
         cell = manifest.load_cell(args.workload)
     except manifest.ManifestError as e:
@@ -350,6 +374,7 @@ def main() -> int:
     config_file = run_dir / "config.json"
     config_file.write_text(json.dumps(config))
     srv = Server(cell, args.seed, run_dir, args.rehearse_on_cpu, args.fault)
+    canary = machine.start("canary", run_dir / machine.GAPS_FILE)
     rc, result = 1, None
     try:
         stored = int(sizes.get("gfkb_fill", 0))
@@ -367,7 +392,7 @@ def main() -> int:
                 return 2
         say(f"server ready {time.perf_counter() - T_PROCESS_START:.1f}s after start on {info['platform']} "
             f"{info['kind']} x{info['count']}")
-        out = asyncio.run(run_cell(args, cell, srv, sizes))
+        out = asyncio.run(run_cell(args, cell, srv, sizes, canary))
         if "sweep" in out:  # exploration: rows on standard error, no result
             return 0
         ctx = out["ctx"]
@@ -401,8 +426,9 @@ def main() -> int:
         rc = 1
     finally:
         srv.stop()
+        machine.stop(canary)
         shutil.rmtree(run_dir, ignore_errors=True)
-    if result is None or args.rehearse_on_cpu:
+    if result is None or args.rehearse_on_cpu or args.freeze:
         if result is not None:
             say("would have printed: " + json.dumps(result))
         return rc if result is None else 0

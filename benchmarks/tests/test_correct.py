@@ -69,7 +69,7 @@ def test_warn_control_int8_rows_is_not_correct(seed):
 
 @pytest.mark.parametrize("seed", [11, 2_500_000_011, 3_000_000_123])
 def test_chat_control_int8_matmuls_is_not_correct(seed):
-    from harness import reference_lm as lm
+    lm = manifest.load_module("families", "mistral")
 
     cfg = json.loads((BENCH / "configs" / "judge-mistral-7b.json").read_text())
     cfg = {**cfg, **cfg["rehearsal"]["model"], "hidden_size": 512, "intermediate_size": 1792,
@@ -78,13 +78,13 @@ def test_chat_control_int8_matmuls_is_not_correct(seed):
     rng = np.random.default_rng(seed % (1 << 32))
     toks = rng.integers(3, live, (4, 128))
     plen = [64] * 4
-    lg = np.asarray(lm.logits(seed, cfg, toks, live))
+    lg = np.asarray(lm.reference_logits(seed, cfg, toks, live))
     # greedy tokens of the reference itself stand for a sound server: gap 0
     served = [[int(lg[r, plen[r] - 1 + k].argmax()) for k in range(1)] for r in range(4)]
-    assert max(lm.served_gaps(lg, plen, served)) == 0.0
-    ctl = lm.logits(seed, cfg, toks, live, int8=True)
+    assert max(correct.served_gaps(lg, plen, served)) == 0.0
+    ctl = lm.reference_logits(seed, cfg, toks, live, control=True)
     every = [list(map(int, toks[r, plen[r]:])) for r in range(4)]
-    gaps = lm.argmax_gaps(lg, ctl, plen, every)
+    gaps = correct.argmax_gaps(lg, ctl, plen, every)
     limits = _limits("chat-short")
     ok, compared = correct.judge({"logit_gap_mean": sum(gaps) / len(gaps), "unserved": 0}, limits)
     assert not ok, compared

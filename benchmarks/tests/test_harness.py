@@ -66,10 +66,11 @@ def test_prom_mean_delta():
 
 
 MISTRAL = json.loads((BENCH / "configs" / "judge-mistral-7b.json").read_text())
+mistral = manifest.load_module("families", MISTRAL["family"])
 
 
 def test_mistral_parameter_counts_by_hand():
-    pc = peaks.lm_param_counts(MISTRAL)
+    pc = mistral.param_counts(MISTRAL)
     # attention: 4096*4096 (q) + 2 * 4096*1024 (k, v) + 4096*4096 (o) = 41,943,040
     # mlp: 3 * 4096 * 14336 = 176,160,768  -> 218,103,808 a layer
     assert pc["per_layer"] == 218_103_808
@@ -85,8 +86,12 @@ def test_knn_scan_bytes_by_hand():
 
 def test_lm_forward_flops_by_hand():
     # one decode token attending 100 cached positions
-    f = peaks.lm_forward_flops(MISTRAL, tokens=1, attended=100, head_rows=1)
-    assert f == 2 * 12 * 218_103_808 + 4 * 100 * 32 * 128 * 12 + 2 * 131_072_000
+    f = 2 * 12 * 218_103_808 + 4 * 100 * 32 * 128 * 12 + 2 * 131_072_000
+    assert mistral.work(MISTRAL, "prefill", tokens=1, attended=100, head_rows=1) == {
+        "flops": f, "bytes": 2 * (12 * 218_103_808 + 131_072_000)}
+    # a chunk program of 8 steps reads the weights once a step
+    assert mistral.work(MISTRAL, "decode", tokens=1, attended=100, head_rows=1, steps=8) == {
+        "flops": f, "bytes": 8 * 2 * (12 * 218_103_808 + 131_072_000)}
 
 
 def test_roofline_and_share():
