@@ -74,6 +74,10 @@ RATE_BUCKETS: Tuple[float, ...] = (
     1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0,
     1000.0, 2500.0, 5000.0, 10000.0, 25000.0, 50000.0, 100000.0,
 )
+# Expert-layer load (models/serving.py): experts touched in a step (counts up
+# to a few hundred experts) and the fullest expert over the mean (1 = even).
+MOE_TOUCHED_BUCKETS: Tuple[float, ...] = (1, 2, 4, 8, 12, 16, 24, 32, 48, 64, 96, 128, 256, 512)
+MOE_SKEW_BUCKETS: Tuple[float, ...] = (1.0, 1.1, 1.25, 1.5, 2.0, 3.0, 4.0, 6.0, 8.0, 16.0, 64.0)
 
 
 def _fmt(v: float) -> str:
@@ -519,6 +523,16 @@ _CORE_FAMILIES = (
      "Seconds spent in loop phases that each lasted over 0.1 s (phases that "
      "wait by design, for arrivals or for the device's chunk, are exempt)",
      ("loop",), None),
+    ("histogram", "kakveda_moe_experts_touched",
+     "Distinct experts that got at least one token, per expert layer per "
+     "decode step", ("engine",), MOE_TOUCHED_BUCKETS),
+    ("histogram", "kakveda_moe_load_max_over_mean",
+     "Fullest expert's load over the mean load, worst expert layer, over "
+     "the recent chunks' decoded tokens; one observation a chunk",
+     ("engine",), MOE_SKEW_BUCKETS),
+    ("gauge", "kakveda_serving_cache_bytes",
+     "Bytes of the slot pool by kind: kv (the attention layers' K/V slabs "
+     "and their scales), conv (the conv layers' states)", ("engine", "kind"), None),
     ("counter", "kakveda_compile_total",
      "XLA backend compiles attributed per jit entry point "
      "(KAKVEDA_LEDGER=1)", ("fn",), None),

@@ -1,4 +1,5 @@
-"""HF checkpoint → kakveda param pytree (eight model families).
+"""HF checkpoint → kakveda param pytree (eight model families; a ninth,
+``lfm2_moe``, by its config keys alone).
 
 The reference delegates all real-model inference to an external Ollama
 daemon (reference: services/dashboard/app.py:1182-1258) — which is also how
@@ -51,7 +52,12 @@ _VOCAB_MULTIPLE = 8
 
 _SUPPORTED_FAMILIES = (
     "llama", "mistral", "qwen2", "qwen3", "mixtral", "gemma", "gemma2", "phi3",
+    "lfm2_moe",
 )
+# Families whose config maps but whose checkpoint tensor names are not
+# learnt yet (no such files are in the repository): ``load_hf_checkpoint``
+# refuses them; seeded weights in the program's layout serve them.
+_CONFIG_ONLY_FAMILIES = ("lfm2_moe",)
 _GEMMA_FAMILIES = ("gemma", "gemma2")
 
 
@@ -69,12 +75,16 @@ def hf_config_to_llama(hf: Dict[str, Any], *, dtype=jnp.bfloat16) -> LlamaConfig
     alternating per-layer sliding windows, attention/final logit
     softcapping, an explicit query scale, and sandwich post-norms), and
     ``phi3`` (fused qkv / gate_up projections split at conversion, longrope
-    per-dim frequency scaling). Anything else is rejected loudly."""
+    per-dim frequency scaling). ``lfm2_moe`` (:func:`_lfm2_moe_config`) is a
+    layer-type list of gated short convolutions and attention, dense layers
+    before sigmoid-routed expert layers. Anything else is rejected loudly."""
     family = hf.get("model_type") or "llama"
     if family not in _SUPPORTED_FAMILIES:
         raise ValueError(
             f"unsupported model_type={family!r} (supported: {', '.join(_SUPPORTED_FAMILIES)})"
         )
+    if family == "lfm2_moe":
+        return _lfm2_moe_config(hf, dtype)
     rope = hf.get("rope_scaling") or {}
     kw: Dict[str, Any] = {}
     if rope:
@@ -219,6 +229,58 @@ def hf_config_to_llama(hf: Dict[str, Any], *, dtype=jnp.bfloat16) -> LlamaConfig
     )
 
 
+def _lfm2_moe_config(hf: Dict[str, Any], dtype) -> LlamaConfig:
+    """LFM2-MoE's published keys: ``layer_types`` (conv | full_attention, one
+    per layer), ``conv_L_cache`` taps, ``num_dense_layers`` leading dense
+    layers of ``intermediate_size`` before expert layers of
+    ``moe_intermediate_size``, the sigmoid router's switches, per-head q/k
+    RMSNorm, ``rope_parameters``. No biases anywhere (``conv_bias`` true is
+    refused: the operator has none here)."""
+    from kakveda_tpu.models.llama import LAYER_KINDS
+
+    n_layers = int(hf["num_hidden_layers"])
+    layer_types = tuple(hf["layer_types"])
+    if len(layer_types) != n_layers:
+        raise ValueError(
+            f"lfm2_moe: layer_types names {len(layer_types)} layers, num_hidden_layers is {n_layers}"
+        )
+    unknown = sorted(set(layer_types) - set(LAYER_KINDS))
+    if unknown:
+        raise ValueError(f"lfm2_moe: unknown layer type(s) {unknown} (known: {', '.join(LAYER_KINDS)})")
+    if hf.get("conv_bias"):
+        raise ValueError("lfm2_moe: conv_bias=true is not supported")
+    rope = hf.get("rope_parameters") or {}
+    if (rope.get("rope_type") or "default") != "default":
+        raise ValueError(f"lfm2_moe: unsupported rope_type {rope.get('rope_type')!r}")
+    n_heads = int(hf["num_attention_heads"])
+    vocab = int(hf["vocab_size"])
+    padded = -(-vocab // _VOCAB_MULTIPLE) * _VOCAB_MULTIPLE
+    return LlamaConfig(
+        vocab_size=padded,
+        effective_vocab=vocab if padded != vocab else None,
+        d_model=int(hf["hidden_size"]),
+        n_layers=n_layers,
+        n_heads=n_heads,
+        n_kv_heads=int(hf.get("num_key_value_heads", n_heads)),
+        d_ff=int(hf["moe_intermediate_size"]),
+        d_ff_dense=int(hf["intermediate_size"]),
+        n_dense_layers=int(hf.get("num_dense_layers", 0)),
+        max_seq_len=int(hf.get("max_position_embeddings", 2048)),
+        rope_theta=float(rope.get("rope_theta", hf.get("rope_theta", 1000000.0))),
+        norm_eps=float(hf.get("norm_eps", 1e-5)),
+        dtype=dtype,
+        qk_norm=True,
+        layer_types=layer_types,
+        conv_l_cache=int(hf["conv_L_cache"]),
+        n_experts=int(hf["num_experts"]),
+        n_experts_per_tok=int(hf["num_experts_per_tok"]),
+        router_score="sigmoid",
+        router_bias=bool(hf.get("use_expert_bias", False)),
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+    )
+
+
 # ---------------------------------------------------------------------------
 # tensor streaming
 # ---------------------------------------------------------------------------
@@ -317,6 +379,11 @@ def load_hf_checkpoint(
     """
     with open(os.path.join(path, "config.json")) as f:
         hf_cfg = json.load(f)
+    if hf_cfg.get("model_type") in _CONFIG_ONLY_FAMILIES:
+        raise ValueError(
+            f"model_type={hf_cfg['model_type']!r}: the config maps (hf_config_to_llama) but "
+            "this family's checkpoint tensor names are not mapped yet"
+        )
     cfg = hf_config_to_llama(hf_cfg, dtype=compute_dtype or param_dtype)
     # Gemma applies RMSNorm gain as (1 + w) with zero-init weights; storing
     # the materialized 1+w keeps every forward path convention-free. The

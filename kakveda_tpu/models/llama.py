@@ -4,9 +4,13 @@ Replaces the reference's HTTP hop to an external Ollama daemon
 (reference: services/dashboard/app.py:1182-1258) with a transformer that
 lives on the same TPU mesh as the GFKB index, so the scenario runner,
 playground and LLM failure-classifier share the pod. One forward serves
-eight HF families — Llama, Mistral, Qwen2/3, Gemma/Gemma-2, Phi-3,
-Mixtral — every family delta a flag on :class:`LlamaConfig`
-(models/hf_convert.py maps the checkpoints).
+nine HF families — Llama, Mistral, Qwen2/3, Gemma/Gemma-2, Phi-3,
+Mixtral, LFM2-MoE — every family delta a flag on :class:`LlamaConfig`
+(models/hf_convert.py maps the configs and, for the first eight, the
+checkpoints). A layer is attention or, by ``LlamaConfig.layer_types``, a
+gated short convolution (:func:`conv_operator`) whose per-sequence state is
+a cache of its own kind beside K/V; its FFN is dense or routed experts
+(models/moe.py).
 
 Design is TPU-first, pure functional JAX (no framework classes):
 
@@ -41,6 +45,14 @@ from jax.sharding import Mesh, PartitionSpec as P
 Params = Dict[str, Any]
 
 _NEG_INF = -1e30
+
+LAYER_KINDS = ("full_attention", "conv")
+
+
+class UnsupportedLayerError(ValueError):
+    """A path that cannot run a layer kind the config names (a conv layer
+    in the pipeline, in training, under speculation or prefix reuse): it
+    refuses the config rather than take a silently wrong path."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -123,6 +135,43 @@ class LlamaConfig:
     # Load-balancing aux-loss coefficient for MoE fine-tunes (HF Mixtral's
     # router_aux_loss_coef); 0 disables the aux term in lm_loss.
     router_aux_coef: float = 0.0
+    # The router's published switches (models/moe.py:router_topk). Mixtral:
+    # softmax over all experts, top-k, renormalised. LFM2: sigmoid scores,
+    # selection by score + a per-expert bias (``use_expert_bias``: the layer
+    # carries ``expert_bias`` [E], used to SELECT only), weights from the
+    # unbiased scores, renormalised when ``norm_topk_prob``, times
+    # ``routed_scaling_factor``.
+    router_score: str = "softmax"  # "softmax" | "sigmoid"
+    router_bias: bool = False
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # ``n_dense_layers`` leading layers keep a dense SwiGLU of width
+    # ``d_ff_dense`` before the expert layers of width ``d_ff`` (LFM2's
+    # num_dense_layers / intermediate_size beside moe_intermediate_size).
+    n_dense_layers: int = 0
+    d_ff_dense: int = 0
+    # Layer-type list (LFM2 ``layer_types``): one of LAYER_KINDS per layer;
+    # () = every layer attention. A "conv" layer's operator is the gated
+    # short convolution (``conv_operator``) with a depthwise causal filter of
+    # ``conv_l_cache`` taps; its per-sequence state is the last
+    # ``conv_l_cache - 1`` rows of the gated input, not K/V.
+    layer_types: tuple = ()
+    conv_l_cache: int = 3
+
+    def layer_kind(self, li: int) -> str:
+        return self.layer_types[li] if self.layer_types else "full_attention"
+
+    def layers_of(self, kind: str) -> Tuple[int, ...]:
+        """Indices of the layers of one kind, in order: a layer's place in
+        this tuple is its index into that kind's cache list."""
+        return tuple(i for i in range(self.n_layers) if self.layer_kind(i) == kind)
+
+    def layer_is_moe(self, li: int) -> bool:
+        return bool(self.n_experts) and li >= self.n_dense_layers
+
+    @property
+    def has_conv(self) -> bool:
+        return "conv" in self.layer_types
 
     @property
     def head_dim(self) -> int:
@@ -183,30 +232,38 @@ def init_params(rng: jax.Array, cfg: LlamaConfig, dtype=jnp.float32) -> Params:
         k = jax.random.split(keys[i], 7)
         layer = {
             "attn_norm": jnp.ones((cfg.d_model,), jnp.float32),
-            "wq": dense(k[0], cfg.d_model, (cfg.d_model, cfg.n_heads * hd)),
-            "wk": dense(k[1], cfg.d_model, (cfg.d_model, cfg.n_kv_heads * hd)),
-            "wv": dense(k[2], cfg.d_model, (cfg.d_model, cfg.n_kv_heads * hd)),
-            "wo": dense(k[3], cfg.n_heads * hd, (cfg.n_heads * hd, cfg.d_model)),
             "mlp_norm": jnp.ones((cfg.d_model,), jnp.float32),
         }
-        if cfg.n_experts:
+        if cfg.layer_kind(i) == "conv":
+            layer["conv_in"] = dense(k[0], cfg.d_model, (cfg.d_model, 3 * cfg.d_model))
+            layer["conv_w"] = dense(k[1], cfg.conv_l_cache, (cfg.conv_l_cache, cfg.d_model))
+            layer["conv_out"] = dense(k[3], cfg.d_model, (cfg.d_model, cfg.d_model))
+        else:
+            layer["wq"] = dense(k[0], cfg.d_model, (cfg.d_model, cfg.n_heads * hd))
+            layer["wk"] = dense(k[1], cfg.d_model, (cfg.d_model, cfg.n_kv_heads * hd))
+            layer["wv"] = dense(k[2], cfg.d_model, (cfg.d_model, cfg.n_kv_heads * hd))
+            layer["wo"] = dense(k[3], cfg.n_heads * hd, (cfg.n_heads * hd, cfg.d_model))
+        if cfg.layer_is_moe(i):
             ke = jax.random.split(k[4], 3)
             layer["router"] = dense(k[5], cfg.d_model, (cfg.d_model, cfg.n_experts))
             layer["we_gate"] = dense(ke[0], cfg.d_model, (cfg.n_experts, cfg.d_model, cfg.d_ff))
             layer["we_up"] = dense(ke[1], cfg.d_model, (cfg.n_experts, cfg.d_model, cfg.d_ff))
             layer["we_down"] = dense(ke[2], cfg.d_ff, (cfg.n_experts, cfg.d_ff, cfg.d_model))
+            if cfg.router_bias:
+                layer["expert_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
         else:
-            layer["w_gate"] = dense(k[4], cfg.d_model, (cfg.d_model, cfg.d_ff))
-            layer["w_up"] = dense(k[5], cfg.d_model, (cfg.d_model, cfg.d_ff))
-            layer["w_down"] = dense(k[6], cfg.d_ff, (cfg.d_ff, cfg.d_model))
-        if cfg.attn_bias:
+            ff = cfg.d_ff_dense if cfg.n_experts and cfg.d_ff_dense else cfg.d_ff
+            layer["w_gate"] = dense(k[4], cfg.d_model, (cfg.d_model, ff))
+            layer["w_up"] = dense(k[5], cfg.d_model, (cfg.d_model, ff))
+            layer["w_down"] = dense(k[6], ff, (ff, cfg.d_model))
+        if cfg.attn_bias and "wq" in layer:
             layer["bq"] = jnp.zeros((cfg.n_heads * hd,), jnp.float32)
             layer["bk"] = jnp.zeros((cfg.n_kv_heads * hd,), jnp.float32)
             layer["bv"] = jnp.zeros((cfg.n_kv_heads * hd,), jnp.float32)
         if cfg.post_norms:
             layer["post_attn_norm"] = jnp.ones((cfg.d_model,), jnp.float32)
             layer["post_ffw_norm"] = jnp.ones((cfg.d_model,), jnp.float32)
-        if cfg.qk_norm:
+        if cfg.qk_norm and "wq" in layer:
             layer["q_norm"] = jnp.ones((hd,), jnp.float32)
             layer["k_norm"] = jnp.ones((hd,), jnp.float32)
         layers.append(layer)
@@ -220,38 +277,43 @@ def init_params(rng: jax.Array, cfg: LlamaConfig, dtype=jnp.float32) -> Params:
 
 def param_specs(cfg: LlamaConfig) -> Params:
     """PartitionSpec tree: Megatron TP layout over the ``tp`` axis."""
-    layer = {
-        "attn_norm": P(),
-        "wq": P(None, "tp"),
-        "wk": P(None, "tp"),
-        "wv": P(None, "tp"),
-        "wo": P("tp", None),
-        "mlp_norm": P(),
-    }
-    if cfg.n_experts:
-        # Expert parallelism over ``ep`` on the stacked-expert axis,
-        # composing with TP over the ffn width; the router is tiny and
-        # replicated.
-        layer.update(
-            {
-                "router": P(),
-                "we_gate": P("ep", None, "tp"),
-                "we_up": P("ep", None, "tp"),
-                "we_down": P("ep", "tp", None),
-            }
-        )
-    else:
-        layer.update({"w_gate": P(None, "tp"), "w_up": P(None, "tp"), "w_down": P("tp", None)})
-    if cfg.attn_bias:
-        # Column-parallel biases follow their projection's out axis.
-        layer.update({"bq": P("tp"), "bk": P("tp"), "bv": P("tp")})
-    if cfg.post_norms:
-        layer.update({"post_attn_norm": P(), "post_ffw_norm": P()})
-    if cfg.qk_norm:
-        layer.update({"q_norm": P(), "k_norm": P()})
+
+    def layer_specs(li: int) -> Params:
+        layer = {"attn_norm": P(), "mlp_norm": P()}
+        if cfg.layer_kind(li) == "conv":
+            # Replicated: the depthwise filter and the gates act per channel,
+            # and a column-split in-projection would cut across B | C | x.
+            layer.update({"conv_in": P(), "conv_w": P(), "conv_out": P()})
+        else:
+            layer.update({"wq": P(None, "tp"), "wk": P(None, "tp"), "wv": P(None, "tp"), "wo": P("tp", None)})
+            if cfg.attn_bias:
+                # Column-parallel biases follow their projection's out axis.
+                layer.update({"bq": P("tp"), "bk": P("tp"), "bv": P("tp")})
+            if cfg.qk_norm:
+                layer.update({"q_norm": P(), "k_norm": P()})
+        if cfg.layer_is_moe(li):
+            # Expert parallelism over ``ep`` on the stacked-expert axis,
+            # composing with TP over the ffn width; the router is tiny and
+            # replicated.
+            layer.update(
+                {
+                    "router": P(),
+                    "we_gate": P("ep", None, "tp"),
+                    "we_up": P("ep", None, "tp"),
+                    "we_down": P("ep", "tp", None),
+                }
+            )
+            if cfg.router_bias:
+                layer["expert_bias"] = P()
+        else:
+            layer.update({"w_gate": P(None, "tp"), "w_up": P(None, "tp"), "w_down": P("tp", None)})
+        if cfg.post_norms:
+            layer.update({"post_attn_norm": P(), "post_ffw_norm": P()})
+        return layer
+
     return {
         "embed": P("tp", None),  # vocab-sharded table
-        "layers": [dict(layer) for _ in range(cfg.n_layers)],
+        "layers": [layer_specs(li) for li in range(cfg.n_layers)],
         "final_norm": P(),
         "lm_head": P(None, "tp"),
     }
@@ -634,18 +696,59 @@ def embed_tokens(params: Params, cfg: LlamaConfig, tokens: jax.Array) -> jax.Arr
 
 
 def mlp_block(
-    x: jax.Array, layer: Params, cfg: LlamaConfig, return_aux: bool = False
+    x: jax.Array, layer: Params, cfg: LlamaConfig, return_aux: bool = False,
+    token_mask: Optional[jax.Array] = None,
 ):
     """Dense SwiGLU or sparse-MoE MLP, keyed on the layer's params
     (MoE layers carry a ``router``; models/moe.py). With ``return_aux``
-    returns ``(out, aux)`` — aux is the layer's load-balancing loss
-    (0 for dense layers)."""
+    returns ``(out, aux, counts)`` — aux is the layer's load-balancing loss
+    (0 for dense layers), counts the pairs each expert got (None for dense
+    layers). ``token_mask`` [B, S] keeps tokens that stand for nothing (a
+    serving pool's idle slots) out of the experts' dispatch."""
     if "router" in layer:
         from kakveda_tpu.models.moe import moe_mlp
 
-        return moe_mlp(x, layer, cfg, return_aux=return_aux)
+        return moe_mlp(x, layer, cfg, return_aux=return_aux, token_mask=token_mask)
     out = _mlp_block(x, layer, cfg.act_fn)
-    return (out, jnp.zeros((), jnp.float32)) if return_aux else out
+    return (out, jnp.zeros((), jnp.float32), None) if return_aux else out
+
+
+def init_conv_state(cfg: LlamaConfig, batch: int) -> jax.Array:
+    """A conv layer's per-sequence state: the last ``conv_l_cache - 1`` rows
+    of its gated input ``u``; zeros stand for "before the sequence"."""
+    return jnp.zeros((batch, cfg.conv_l_cache - 1, cfg.d_model), cfg.dtype)
+
+
+def conv_operator(
+    h: jax.Array, layer: Params, state: Optional[jax.Array] = None, valid: Optional[jax.Array] = None
+) -> Tuple[jax.Array, jax.Array]:
+    """LFM2's gated short convolution, THE one body every forward path
+    calls with a view of that layer's state:
+
+        [B, C, x~] = split3(h W_in);  u = B * x~
+        v_t = sum_j w[j] * u_{t-(L-1)+j}   (depthwise, causal, L taps)
+        out = (C * v) W_out
+
+    ``h`` [N, S, D] (already normed); ``state`` [N, L-1, D], the rows of
+    ``u`` before ``h``'s first position (None = the sequence starts here);
+    ``valid`` [N, S] — False marks pad positions, whose ``u`` is zeroed so a
+    left-padded prompt convolves exactly as the unpadded one. Returns
+    (out [N, S, D], the new state: the last L-1 rows of ``u``). The taps are
+    summed in float32."""
+    dt = h.dtype
+    s = h.shape[1]
+    gate_b, gate_c, xt = jnp.split(h @ wmat(layer["conv_in"], dt), 3, axis=-1)
+    u = gate_b * xt
+    if valid is not None:
+        u = jnp.where(valid[..., None], u, jnp.zeros((), dt))
+    w = layer["conv_w"].astype(jnp.float32)  # [L, D], w[L-1] on the current position
+    taps = w.shape[0]
+    if state is None:
+        state = jnp.zeros((h.shape[0], taps - 1, h.shape[2]), dt)
+    ext = jnp.concatenate([state.astype(dt), u], axis=1)  # [N, L-1+S, D]
+    v = sum(ext[:, j:j + s].astype(jnp.float32) * w[j] for j in range(taps))
+    out = (gate_c * v.astype(dt)) @ wmat(layer["conv_out"], dt)
+    return out, ext[:, s:]
 
 
 def forward(
@@ -677,12 +780,15 @@ def forward(
     aux = jnp.zeros((), jnp.float32)
     for li, layer in enumerate(params["layers"]):
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        attn = _attention_block(h, layer, cfg, cos, sin, mesh, cp_axis, li)
+        if cfg.layer_kind(li) == "conv":
+            attn, _ = conv_operator(h, layer)
+        else:
+            attn = _attention_block(h, layer, cfg, cos, sin, mesh, cp_axis, li)
         if "post_attn_norm" in layer:  # Gemma-2 sandwich norm
             attn = rms_norm(attn, layer["post_attn_norm"], cfg.norm_eps)
         x = x + attn
         h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        m, a = mlp_block(h, layer, cfg, return_aux=True)
+        m, a, _ = mlp_block(h, layer, cfg, return_aux=True)
         if "post_ffw_norm" in layer:
             m = rms_norm(m, layer["post_ffw_norm"], cfg.norm_eps)
         x = x + m
@@ -716,19 +822,27 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: Optional[int] = None) -> P
     ml = max_len or cfg.max_seq_len
     hd = cfg.head_dim
     shape = (batch, cfg.n_kv_heads, ml, hd)
+    # A cache per layer type: the K/V lists hold the attention layers only
+    # (layer li's entry is at its place in ``cfg.layers_of("full_attention")``),
+    # and a config with conv layers gets ``conv``, one [B, L-1, D] state per
+    # conv layer (int8 KV stays attention's alone).
+    n_attn = len(cfg.layers_of("full_attention"))
+    cache = {"pos": jnp.zeros((), jnp.int32)}
     if cfg.kv_quant == "int8":
-        return {
-            "pos": jnp.zeros((), jnp.int32),
-            "k": [jnp.zeros(shape, jnp.int8) for _ in range(cfg.n_layers)],
-            "v": [jnp.zeros(shape, jnp.int8) for _ in range(cfg.n_layers)],
-            "ks": [jnp.zeros(shape[:3], jnp.float32) for _ in range(cfg.n_layers)],
-            "vs": [jnp.zeros(shape[:3], jnp.float32) for _ in range(cfg.n_layers)],
-        }
-    return {
-        "pos": jnp.zeros((), jnp.int32),
-        "k": [jnp.zeros(shape, cfg.dtype) for _ in range(cfg.n_layers)],
-        "v": [jnp.zeros(shape, cfg.dtype) for _ in range(cfg.n_layers)],
-    }
+        cache.update(
+            k=[jnp.zeros(shape, jnp.int8) for _ in range(n_attn)],
+            v=[jnp.zeros(shape, jnp.int8) for _ in range(n_attn)],
+            ks=[jnp.zeros(shape[:3], jnp.float32) for _ in range(n_attn)],
+            vs=[jnp.zeros(shape[:3], jnp.float32) for _ in range(n_attn)],
+        )
+    else:
+        cache.update(
+            k=[jnp.zeros(shape, cfg.dtype) for _ in range(n_attn)],
+            v=[jnp.zeros(shape, cfg.dtype) for _ in range(n_attn)],
+        )
+    if cfg.has_conv:
+        cache["conv"] = [init_conv_state(cfg, batch) for _ in cfg.layers_of("conv")]
+    return cache
 
 
 def _kv_quant_rows(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -795,53 +909,61 @@ def decode_step(
     new_v: list = []
     new_ks: list = []
     new_vs: list = []
-    for li, layer in enumerate(params["layers"]):
+    new_conv: list = []
+    # pad slots (left-padded batching, bucketed admits) stay out of a conv state
+    tok_valid = None if kv_valid is None else jax.lax.dynamic_slice_in_dim(kv_valid, pos0, s, axis=1)
+    for layer_i, layer in enumerate(params["layers"]):
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         dt = h.dtype
-        q, k, v = qkv_proj(h, layer, cfg, dt)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-
-        # Head-major cache writes: [B, S, KV, D] -> [B, KV, S, D] slab.
-        k_rows = k.transpose(0, 2, 1, 3)
-        v_rows = v.transpose(0, 2, 1, 3)
-        ks_all = vs_all = None
-        if kq:
-            k_i8, k_sc = _kv_quant_rows(k_rows)
-            v_i8, v_sc = _kv_quant_rows(v_rows)
-            k_all = jax.lax.dynamic_update_slice(cache["k"][li], k_i8, (0, 0, pos0, 0))
-            v_all = jax.lax.dynamic_update_slice(cache["v"][li], v_i8, (0, 0, pos0, 0))
-            ks_all = jax.lax.dynamic_update_slice(cache["ks"][li], k_sc, (0, 0, pos0))
-            vs_all = jax.lax.dynamic_update_slice(cache["vs"][li], v_sc, (0, 0, pos0))
-            new_ks.append(ks_all)
-            new_vs.append(vs_all)
+        if cfg.layer_kind(layer_i) == "conv":
+            attn, state = conv_operator(h, layer, cache["conv"][len(new_conv)], tok_valid)
+            new_conv.append(state)
         else:
-            k_all = jax.lax.dynamic_update_slice(
-                cache["k"][li], k_rows.astype(cfg.dtype), (0, 0, pos0, 0)
-            )
-            v_all = jax.lax.dynamic_update_slice(
-                cache["v"][li], v_rows.astype(cfg.dtype), (0, 0, pos0, 0)
-            )
-        new_k.append(k_all)
-        new_v.append(v_all)
+            li = len(new_k)  # this attention layer's place in the K/V lists
+            q, k, v = qkv_proj(h, layer, cfg, dt)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
 
-        # Fused cached attention: Pallas flash on TPU, grouped XLA einsum
-        # elsewhere — either way K/V are read once, not n_rep times, and
-        # the causal mask (q_pos >= slot) also excludes unwritten slots.
-        # int8 caches pass raw tiles + scales: the flash kernel streams
-        # int8 from HBM and dequantizes in VMEM (the bandwidth win).
-        attn = gqa_cache_attention(
-            q, k_all, v_all, pos0, kv_valid,
-            window=cfg.layer_window(li), softcap=cfg.attn_softcap,
-            k_scale=ks_all, v_scale=vs_all,
-        )
-        attn = attn.reshape(b, s, cfg.n_heads * hd) @ wmat(layer["wo"], dt)
+            # Head-major cache writes: [B, S, KV, D] -> [B, KV, S, D] slab.
+            k_rows = k.transpose(0, 2, 1, 3)
+            v_rows = v.transpose(0, 2, 1, 3)
+            ks_all = vs_all = None
+            if kq:
+                k_i8, k_sc = _kv_quant_rows(k_rows)
+                v_i8, v_sc = _kv_quant_rows(v_rows)
+                k_all = jax.lax.dynamic_update_slice(cache["k"][li], k_i8, (0, 0, pos0, 0))
+                v_all = jax.lax.dynamic_update_slice(cache["v"][li], v_i8, (0, 0, pos0, 0))
+                ks_all = jax.lax.dynamic_update_slice(cache["ks"][li], k_sc, (0, 0, pos0))
+                vs_all = jax.lax.dynamic_update_slice(cache["vs"][li], v_sc, (0, 0, pos0))
+                new_ks.append(ks_all)
+                new_vs.append(vs_all)
+            else:
+                k_all = jax.lax.dynamic_update_slice(
+                    cache["k"][li], k_rows.astype(cfg.dtype), (0, 0, pos0, 0)
+                )
+                v_all = jax.lax.dynamic_update_slice(
+                    cache["v"][li], v_rows.astype(cfg.dtype), (0, 0, pos0, 0)
+                )
+            new_k.append(k_all)
+            new_v.append(v_all)
+
+            # Fused cached attention: Pallas flash on TPU, grouped XLA einsum
+            # elsewhere — either way K/V are read once, not n_rep times, and
+            # the causal mask (q_pos >= slot) also excludes unwritten slots.
+            # int8 caches pass raw tiles + scales: the flash kernel streams
+            # int8 from HBM and dequantizes in VMEM (the bandwidth win).
+            attn = gqa_cache_attention(
+                q, k_all, v_all, pos0, kv_valid,
+                window=cfg.layer_window(layer_i), softcap=cfg.attn_softcap,
+                k_scale=ks_all, v_scale=vs_all,
+            )
+            attn = attn.reshape(b, s, cfg.n_heads * hd) @ wmat(layer["wo"], dt)
         if "post_attn_norm" in layer:  # Gemma-2 sandwich norm
             attn = rms_norm(attn, layer["post_attn_norm"], cfg.norm_eps)
         x = x + attn
 
         h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        m = mlp_block(h, layer, cfg)
+        m = mlp_block(h, layer, cfg, token_mask=tok_valid)
         if "post_ffw_norm" in layer:
             m = rms_norm(m, layer["post_ffw_norm"], cfg.norm_eps)
         x = x + m
@@ -855,4 +977,6 @@ def decode_step(
     if kq:
         new_cache["ks"] = new_ks
         new_cache["vs"] = new_vs
+    if cfg.has_conv:
+        new_cache["conv"] = new_conv
     return logits, new_cache

@@ -46,6 +46,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from kakveda_tpu.models.llama import (
     LlamaConfig,
     Params,
+    UnsupportedLayerError,
     _attention_block,
     _rope_freqs,
     embed_tokens,
@@ -60,8 +61,12 @@ def split_stages(params: Params, cfg: LlamaConfig, n_stages: int) -> Params:
     ``[n_stages, layers_per_stage, …]`` (leading axis shards over ``pp``)."""
     if cfg.n_layers % n_stages:
         raise ValueError(f"{cfg.n_layers} layers do not split into {n_stages} stages")
+    _refuse_mixed_layers(cfg)
     per = cfg.n_layers // n_stages
     layers = params["layers"]
+    if any(jax.tree.structure(layer) != jax.tree.structure(layers[0]) for layer in layers):
+        # dense layers before the expert layers: two trees, nothing to stack
+        raise UnsupportedLayerError("pipeline parallelism cannot stack layers whose weights differ in kind")
     stacked = jax.tree.map(
         lambda *leaves: jnp.stack(leaves).reshape((n_stages, per) + leaves[0].shape),
         *layers,
@@ -72,6 +77,15 @@ def split_stages(params: Params, cfg: LlamaConfig, n_stages: int) -> Params:
         "final_norm": params["final_norm"],
         "lm_head": params["lm_head"],
     }
+
+
+def _refuse_mixed_layers(cfg: LlamaConfig) -> None:
+    """The stages stack their layers into one array per weight and scan over
+    them: every layer has to be the same kind with the same tree. A config
+    with conv layers is refused (served on the tp/ep paths instead) — never
+    run on a wrong path."""
+    if any(cfg.layer_kind(i) != "full_attention" for i in range(cfg.n_layers)):
+        raise UnsupportedLayerError("pipeline parallelism cannot stack a config with conv layers")
 
 
 def pp_param_specs(cfg: LlamaConfig) -> Params:
@@ -121,6 +135,7 @@ def pp_forward(
 
     ``B`` must divide into ``n_micro`` microbatches; bubble fraction is
     (S−1)/(n_micro+S−1)."""
+    _refuse_mixed_layers(cfg)
     n_stages = mesh.shape[pp_axis]
     b, s = tokens.shape
     if b % n_micro:

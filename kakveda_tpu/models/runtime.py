@@ -325,7 +325,11 @@ class MultiModelRuntime:
             itemsize = 1.0 + 4.0 / cfg.head_dim
         else:
             itemsize = float(np.dtype(cfg.dtype).itemsize)
-        return int(slots * window * cfg.n_layers * 2 * cfg.n_kv_heads * cfg.head_dim * itemsize)
+        # a cache per layer type: K/V for the attention layers, L-1 rows of
+        # d_model for each conv layer
+        kv = window * len(cfg.layers_of("full_attention")) * 2 * cfg.n_kv_heads * cfg.head_dim * itemsize
+        conv = len(cfg.layers_of("conv")) * (cfg.conv_l_cache - 1) * cfg.d_model * np.dtype(cfg.dtype).itemsize
+        return int(slots * (kv + conv))
 
     def _evict_lru(self, keep: str) -> bool:
         """Drop the least-recently-used loaded model (never ``keep``);

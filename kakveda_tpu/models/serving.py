@@ -79,6 +79,7 @@ from kakveda_tpu.core import trace as _trace
 from kakveda_tpu.models.llama import (
     LlamaConfig,
     Params,
+    UnsupportedLayerError,
     decode_step,
     init_cache,
     mask_pad_vocab,
@@ -115,31 +116,46 @@ class DeadlineExceededError(RuntimeError):
         self.tokens: List[int] = list(tokens or [])
 
 
+def _cache_lists(cache: Params) -> Dict[str, list]:
+    """A cache's per-layer lists, everything but the scalar ``pos``: a cache
+    per layer type — K/V slabs (+ int8 scales) of the attention layers,
+    ``conv`` states of the conv layers. Every entry is batch-leading, so one
+    rule scatters a [1, ...] scratch entry into a slot."""
+    return {key: val for key, val in cache.items() if key != "pos"}
+
+
+def _scatter_slot(cache: Params, scratch: Params, slot) -> Params:
+    """Write a single-sequence ``scratch`` cache into batch slot ``slot`` of
+    ``cache``, entry by entry: the prompt's K/V rows, and its conv states —
+    whatever the slot held before is gone."""
+    out = {"pos": cache["pos"]}
+    for key, entries in _cache_lists(cache).items():
+        out[key] = [
+            jax.lax.dynamic_update_slice(ce, se, (slot,) + (0,) * (ce.ndim - 1))
+            for ce, se in zip(entries, scratch[key])
+        ]
+    return out
+
+
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2,))
 def _admit_jit(params, cfg: LlamaConfig, cache, last, prompt, slot, kv_valid, pos_offset):
     """Prefill ``prompt`` [1, P] into batch slot ``slot`` of ``cache``.
 
     The single-sequence prefill runs with its own [1, ...] scratch cache
-    (so its attention sees only this prompt), then its K/V rows scatter
-    into the batch cache at ``slot``. `last` [B, V] gets the slot's
-    next-token logits.
+    (so its attention sees only this prompt and a conv layer starts from
+    "before the sequence"), then its K/V rows and conv states scatter into
+    the batch cache at ``slot``: the conv state is the real prompt's last
+    rows, the bucket's pad positions masked out of it (``decode_step``).
+    `last` [B, V] gets the slot's next-token logits.
     """
-    b = last.shape[0]
-    p = prompt.shape[1]
-    scratch = init_cache(cfg, batch=1, max_len=cache["k"][0].shape[2])
+    scratch = init_cache(cfg, batch=1, max_len=kv_valid.shape[1])
     logits, scratch = decode_step(
         params, cfg, prompt, scratch,
         kv_valid=kv_valid[slot][None],
         pos_offset=pos_offset[slot][None],
         last_only=True,
     )
-    out = {"pos": cache["pos"]}
-    for key in ("k", "v") + (("ks", "vs") if cfg.kv_quant == "int8" else ()):
-        zeros = (0,) * (cache[key][0].ndim - 1)
-        out[key] = [
-            jax.lax.dynamic_update_slice(ck, sk, (slot, *zeros))
-            for ck, sk in zip(cache[key], scratch[key])
-        ]
+    out = _scatter_slot(cache, scratch, slot)
     nl = mask_pad_vocab(logits[:, -1, :], cfg)
     last = jax.lax.dynamic_update_slice(last, nl, (slot, 0))
     # cache["pos"] is managed per-slot on host (slot positions differ);
@@ -147,7 +163,7 @@ def _admit_jit(params, cfg: LlamaConfig, cache, last, prompt, slot, kv_valid, po
     return out, last
 
 
-def _forward_wide(params, cfg: LlamaConfig, cache_k, cache_v, cache_ks, cache_vs, tokens, slot_pos, kv_valid, pos_offset):
+def _forward_wide(params, cfg: LlamaConfig, cache, tokens, slot_pos, kv_valid, pos_offset):
     """THE serving-chunk forward body, S-wide with PER-SLOT positions:
     token i of slot b writes cache row ``slot_pos[b]+i`` and attends rows
     ``col <= slot_pos[b]+i`` (within kv_valid, and the sliding-window band
@@ -158,13 +174,21 @@ def _forward_wide(params, cfg: LlamaConfig, cache_k, cache_v, cache_ks, cache_vs
     (keeping the flash / int8-streaming dispatch), S>1 passes the full
     [B, S, L] mask (XLA path; S <= k+1 keeps its scratch tiny).
 
-    Returns (logits [B, S, V] vocab-masked f32, new_k, new_v, new_ks, new_vs).
+    ``cache`` is the pool's per-layer lists (:func:`_cache_lists`): an
+    attention layer scatters into its K/V slab, a conv layer advances its
+    slot's state through ``llama.conv_operator``. A slot with no valid row
+    is idle: its token stays out of the experts' dispatch.
+
+    Returns (logits [B, S, V] vocab-masked f32, the new lists, expert counts
+    int32 [expert layers, E]: the (token, choice) pairs each expert got —
+    [0, 1] for a stack without expert layers).
     """
     from kakveda_tpu.models.attention import gqa_cache_attention
     from kakveda_tpu.models.llama import (
         _kv_quant_rows,
         _rope_freqs,
         apply_rope,
+        conv_operator,
         embed_tokens,
         mlp_block,
         qkv_proj,
@@ -175,8 +199,10 @@ def _forward_wide(params, cfg: LlamaConfig, cache_k, cache_v, cache_ks, cache_vs
 
     b, s = tokens.shape
     hd = cfg.head_dim
-    max_len = cache_k[0].shape[2]
+    max_len = kv_valid.shape[1]
     kq = cfg.kv_quant == "int8"
+    cache_k, cache_v = cache["k"], cache["v"]
+    cache_ks, cache_vs = cache.get("ks", []), cache.get("vs", [])
 
     positions = slot_pos[:, None] + jnp.arange(s)[None, :] - pos_offset[:, None]
     cos, sin = _rope_freqs(cfg, positions)
@@ -188,64 +214,73 @@ def _forward_wide(params, cfg: LlamaConfig, cache_k, cache_v, cache_ks, cache_vs
     win_mask = base_mask
     if cfg.sliding_window:
         win_mask = base_mask & (col > qpos - cfg.sliding_window)
+    # only a stack with expert layers asks which slots are live
+    live = jnp.broadcast_to(jnp.any(kv_valid, axis=1)[:, None], (b, s)) if cfg.n_experts else None
 
     rows = jnp.arange(b)[:, None]  # [B, 1]
     wcols = slot_pos[:, None] + jnp.arange(s)[None, :]  # [B, S] write indices
-    new_k, new_v, new_ks, new_vs = [], [], [], []
-    for li in range(cfg.n_layers):
-        mask = win_mask if cfg.layer_window(li) else base_mask
-        layer = params["layers"][li]
+    new_k, new_v, new_ks, new_vs, new_conv, counts = [], [], [], [], [], []
+    for layer_i in range(cfg.n_layers):
+        layer = params["layers"][layer_i]
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         dt = h.dtype
-        q, k, v = qkv_proj(h, layer, cfg, dt)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        # Per-slot scatter: row i of slot b lands at cache[b, :, slot_pos[b]+i]
-        # — a real scatter (in-place row writes), not a whole-cache rewrite;
-        # mode="drop" clamps overshoot past the window (discarded host-side).
-        k_rows = k.transpose(0, 2, 1, 3)  # [B, KV, S, D]
-        v_rows = v.transpose(0, 2, 1, 3)
-        ks_all = vs_all = None
-        if kq:
-            # Same per-row quantizer as decode_step, so a slot's cache
-            # bytes are identical to its solo decode — int8 parity is
-            # exact, not approximate-squared.
-            k_i8, k_sc = _kv_quant_rows(k_rows)
-            v_i8, v_sc = _kv_quant_rows(v_rows)
-            k_all = cache_k[li].at[rows, :, wcols].set(k_i8.transpose(0, 2, 1, 3), mode="drop")
-            v_all = cache_v[li].at[rows, :, wcols].set(v_i8.transpose(0, 2, 1, 3), mode="drop")
-            ks_all = cache_ks[li].at[rows, :, wcols].set(k_sc.transpose(0, 2, 1), mode="drop")
-            vs_all = cache_vs[li].at[rows, :, wcols].set(v_sc.transpose(0, 2, 1), mode="drop")
-            new_ks.append(ks_all)
-            new_vs.append(vs_all)
+        if cfg.layer_kind(layer_i) == "conv":
+            attn, state = conv_operator(h, layer, cache["conv"][len(new_conv)])
+            new_conv.append(state)
         else:
-            k_all = cache_k[li].at[rows, :, wcols].set(
-                k_rows.transpose(0, 2, 1, 3).astype(cfg.dtype), mode="drop"
-            )
-            v_all = cache_v[li].at[rows, :, wcols].set(
-                v_rows.transpose(0, 2, 1, 3).astype(cfg.dtype), mode="drop"
-            )
-        new_k.append(k_all)
-        new_v.append(v_all)
-        if s == 1:
-            # [B, L] mask keeps the flash/int8-streaming dispatch;
-            # pos0=max_len makes the kernel's scalar causal mask a no-op.
-            attn = gqa_cache_attention(
-                q, k_all, v_all, jnp.asarray(max_len), mask[:, 0, :],
-                softcap=cfg.attn_softcap, k_scale=ks_all, v_scale=vs_all,
-            )
-        else:
-            attn = gqa_cache_attention(
-                q, k_all, v_all, jnp.asarray(max_len), None,
-                softcap=cfg.attn_softcap, k_scale=ks_all, v_scale=vs_all,
-                full_mask=mask,
-            )
-        attn = attn.reshape(b, s, cfg.n_heads * hd) @ wmat(layer["wo"], dt)
+            li = len(new_k)  # this attention layer's place in the K/V lists
+            mask = win_mask if cfg.layer_window(layer_i) else base_mask
+            q, k, v = qkv_proj(h, layer, cfg, dt)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+            # Per-slot scatter: row i of slot b lands at cache[b, :, slot_pos[b]+i]
+            # — a real scatter (in-place row writes), not a whole-cache rewrite;
+            # mode="drop" clamps overshoot past the window (discarded host-side).
+            k_rows = k.transpose(0, 2, 1, 3)  # [B, KV, S, D]
+            v_rows = v.transpose(0, 2, 1, 3)
+            ks_all = vs_all = None
+            if kq:
+                # Same per-row quantizer as decode_step, so a slot's cache
+                # bytes are identical to its solo decode — int8 parity is
+                # exact, not approximate-squared.
+                k_i8, k_sc = _kv_quant_rows(k_rows)
+                v_i8, v_sc = _kv_quant_rows(v_rows)
+                k_all = cache_k[li].at[rows, :, wcols].set(k_i8.transpose(0, 2, 1, 3), mode="drop")
+                v_all = cache_v[li].at[rows, :, wcols].set(v_i8.transpose(0, 2, 1, 3), mode="drop")
+                ks_all = cache_ks[li].at[rows, :, wcols].set(k_sc.transpose(0, 2, 1), mode="drop")
+                vs_all = cache_vs[li].at[rows, :, wcols].set(v_sc.transpose(0, 2, 1), mode="drop")
+                new_ks.append(ks_all)
+                new_vs.append(vs_all)
+            else:
+                k_all = cache_k[li].at[rows, :, wcols].set(
+                    k_rows.transpose(0, 2, 1, 3).astype(cfg.dtype), mode="drop"
+                )
+                v_all = cache_v[li].at[rows, :, wcols].set(
+                    v_rows.transpose(0, 2, 1, 3).astype(cfg.dtype), mode="drop"
+                )
+            new_k.append(k_all)
+            new_v.append(v_all)
+            if s == 1:
+                # [B, L] mask keeps the flash/int8-streaming dispatch;
+                # pos0=max_len makes the kernel's scalar causal mask a no-op.
+                attn = gqa_cache_attention(
+                    q, k_all, v_all, jnp.asarray(max_len), mask[:, 0, :],
+                    softcap=cfg.attn_softcap, k_scale=ks_all, v_scale=vs_all,
+                )
+            else:
+                attn = gqa_cache_attention(
+                    q, k_all, v_all, jnp.asarray(max_len), None,
+                    softcap=cfg.attn_softcap, k_scale=ks_all, v_scale=vs_all,
+                    full_mask=mask,
+                )
+            attn = attn.reshape(b, s, cfg.n_heads * hd) @ wmat(layer["wo"], dt)
         if "post_attn_norm" in layer:
             attn = rms_norm(attn, layer["post_attn_norm"], cfg.norm_eps)
         x = x + attn
         h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        m = mlp_block(h, layer, cfg)
+        m, _, pairs = mlp_block(h, layer, cfg, token_mask=live, return_aux=True)
+        if pairs is not None:
+            counts.append(pairs)
         if "post_ffw_norm" in layer:
             m = rms_norm(m, layer["post_ffw_norm"], cfg.norm_eps)
         x = x + m
@@ -253,7 +288,12 @@ def _forward_wide(params, cfg: LlamaConfig, cache_k, cache_v, cache_ks, cache_vs
     logits = (x @ wmat(params["lm_head"], cfg.dtype)).astype(jnp.float32)
     logits = softcap_logits(logits, cfg.final_softcap)
     logits = mask_pad_vocab(logits, cfg)
-    return logits, new_k, new_v, new_ks, new_vs
+    new = {"k": new_k, "v": new_v}
+    if kq:
+        new["ks"], new["vs"] = new_ks, new_vs
+    if cfg.has_conv:
+        new["conv"] = new_conv
+    return logits, new, jnp.stack(counts) if counts else jnp.zeros((0, 1), jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("cfg", "n_steps"), donate_argnums=(2,))
@@ -267,34 +307,51 @@ def _step_chunk_jit(params, cfg: LlamaConfig, cache, last, slot_pos, kv_valid, p
     ``temps`` [B] — per-slot sampling temperature; a slot with temp <= 0
     decodes greedily, others sample categorically (one rng split per step,
     shared across slots — rows are independent draws of the same key).
+    The scan carries the cache's per-layer lists, whatever the stack holds:
+    K/V slabs, int8 scales, conv states.
+
+    Returns (cache, last, slot_pos, rng, the chunk's fetch). The fetch is ONE
+    int32 array [B + expert layers, max(n_steps, E + n_steps)]: rows [:B] hold
+    the tokens [B, n_steps]; row B + l holds expert layer l's pair counts over
+    the chunk [E], then its distinct experts touched at each step [n_steps] —
+    so the expert counters ride the fetch the loop already makes
+    (:func:`split_fetch`). Without expert layers it is the tokens alone.
     """
-    kq = cfg.kv_quant == "int8"
 
     def one_step(carry, _):
-        cache_k, cache_v, cache_ks, cache_vs, last, slot_pos, rng = carry
+        lists, last, slot_pos, rng = carry
         rng, sub = jax.random.split(rng)
         sampled = jax.random.categorical(
             sub, last / jnp.maximum(temps, 1e-6)[:, None], axis=-1
         )
         nxt = jnp.where(temps > 0.0, sampled, jnp.argmax(last, axis=-1))  # [B]
-        logits, new_k, new_v, new_ks, new_vs = _forward_wide(
-            params, cfg, cache_k, cache_v, cache_ks, cache_vs,
-            nxt[:, None].astype(jnp.int32), slot_pos, kv_valid, pos_offset,
+        logits, lists, counts = _forward_wide(
+            params, cfg, lists, nxt[:, None].astype(jnp.int32), slot_pos, kv_valid, pos_offset,
         )
-        return (new_k, new_v, new_ks, new_vs, logits[:, -1, :], slot_pos + 1, rng), nxt
+        return (lists, logits[:, -1, :], slot_pos + 1, rng), (nxt, counts)
 
-    init = (
-        cache["k"], cache["v"],
-        cache.get("ks", []), cache.get("vs", []),
-        last, slot_pos, rng,
+    (lists, last, slot_pos, rng), (toks, counts) = jax.lax.scan(
+        one_step, (_cache_lists(cache), last, slot_pos, rng), None, length=n_steps
     )
-    (ck, cv, cks, cvs, last, slot_pos, rng), toks = jax.lax.scan(
-        one_step, init, None, length=n_steps
-    )
-    out = {"pos": cache["pos"], "k": ck, "v": cv}
-    if kq:
-        out["ks"], out["vs"] = cks, cvs
-    return out, last, slot_pos, rng, toks.T  # [B, n_steps]
+    fetch = toks.T.astype(jnp.int32)  # [B, n_steps]
+    if counts.shape[1]:  # [n_steps, expert layers, E]
+        stats = jnp.concatenate(
+            [jnp.sum(counts, axis=0), jnp.sum(counts > 0, axis=2).T.astype(jnp.int32)], axis=1
+        )  # [expert layers, E + n_steps]
+        fetch = jnp.concatenate(
+            [jnp.pad(fetch, ((0, 0), (0, stats.shape[1] - n_steps))), stats], axis=0
+        )
+    return {"pos": cache["pos"], **lists}, last, slot_pos, rng, fetch
+
+
+def split_fetch(fetch: np.ndarray, n_slots: int, n_steps: int, n_experts: int):
+    """A chunk's fetch (``_step_chunk_jit``) on the host: (tokens [B, n_steps],
+    pair counts [expert layers, E], experts touched [expert layers, n_steps]);
+    the last two None for a stack without expert layers."""
+    if fetch.shape[0] == n_slots:
+        return fetch, None, None
+    stats = fetch[n_slots:]
+    return fetch[:n_slots, :n_steps], stats[:, :n_experts], stats[:, n_experts:n_experts + n_steps]
 
 
 @partial(jax.jit, static_argnames=("cfg", "k"), donate_argnums=(2,))
@@ -318,17 +375,12 @@ def _spec_chunk_jit(params, cfg: LlamaConfig, cache, last, slot_pos, kv_valid, p
     Returns (cache, new_last [B,V], new_slot_pos [B], toks [B, k+1],
     counts [B]) — the host emits ``toks[b, :counts[b]]``.
     """
-    kq = cfg.kv_quant == "int8"
     t0 = jnp.argmax(last, axis=-1).astype(jnp.int32)  # [B]
     tokens = jnp.concatenate([t0[:, None], drafts.astype(jnp.int32)], axis=1)  # [B, k+1]
-    logits, new_k, new_v, new_ks, new_vs = _forward_wide(
-        params, cfg, cache["k"], cache["v"],
-        cache.get("ks", []), cache.get("vs", []),
-        tokens, slot_pos, kv_valid, pos_offset,
+    logits, lists, _ = _forward_wide(
+        params, cfg, _cache_lists(cache), tokens, slot_pos, kv_valid, pos_offset,
     )
-    new_cache = {"pos": cache["pos"], "k": new_k, "v": new_v}
-    if kq:
-        new_cache["ks"], new_cache["vs"] = new_ks, new_vs
+    new_cache = {"pos": cache["pos"], **lists}
 
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, k+1]; [b, i] follows tokens[b, :i+1]
     match = (drafts.astype(jnp.int32) == greedy[:, :-1]).astype(jnp.int32)  # [B, k]
@@ -357,16 +409,14 @@ def _admit_prefix_jit(
     values. Attention over not-yet-written rows is causally masked exactly
     as in chunked prefill.
     """
-    b = last.shape[0]
-    max_len = cache["k"][0].shape[2]
     off = pos_offset[slot]
-    scratch = init_cache(cfg, batch=1, max_len=max_len)
+    scratch = init_cache(cfg, batch=1, max_len=kv_valid.shape[1])
     scratch["pos"] = write_pos
-    for key in ("k", "v") + (("ks", "vs") if cfg.kv_quant == "int8" else ()):
-        starts = (0, 0, off, 0) if pfx[key][0].ndim == 4 else (0, 0, off)
+    for key, slabs in pfx.items():
+        starts = (0, 0, off, 0) if slabs[0].ndim == 4 else (0, 0, off)
         scratch[key] = [
             jax.lax.dynamic_update_slice(sk, pk, starts)
-            for sk, pk in zip(scratch[key], pfx[key])
+            for sk, pk in zip(scratch[key], slabs)
         ]
     logits, scratch = decode_step(
         params, cfg, suffix, scratch,
@@ -374,13 +424,7 @@ def _admit_prefix_jit(
         pos_offset=pos_offset[slot][None],
         last_only=True,
     )
-    out = {"pos": cache["pos"]}
-    for key in ("k", "v") + (("ks", "vs") if cfg.kv_quant == "int8" else ()):
-        zeros = (0,) * (cache[key][0].ndim - 1)
-        out[key] = [
-            jax.lax.dynamic_update_slice(ck, sk, (slot, *zeros))
-            for ck, sk in zip(cache[key], scratch[key])
-        ]
+    out = _scatter_slot(cache, scratch, slot)
     nl = mask_pad_vocab(logits[:, -1, :], cfg)
     last = jax.lax.dynamic_update_slice(last, nl, (slot, 0))
     return out, last
@@ -455,6 +499,13 @@ class ContinuousBatcher:
         name: str = "default",
         recorder: Optional[_metrics.FlightRecorder] = None,
     ):
+        if cfg.has_conv and spec_k:
+            # A verify chunk's rejected drafts would stay in a conv state:
+            # it has no rows to leave unread, and no rollback yet.
+            raise UnsupportedLayerError(
+                "speculative decoding cannot run a config with conv layers "
+                "(no rollback of conv state to the accepted position): serve it with spec_k=0"
+            )
         self.params, self.cfg = params, cfg
         self.B, self.max_len = batch_slots, max_len
         self.chunk_steps = chunk_steps
@@ -534,6 +585,23 @@ class ContinuousBatcher:
             "kakveda_serving_slots",
             "Total slots in the continuous-batching pool", ("engine",),
         ).labels(engine=name).set(batch_slots)
+        if cfg.n_experts:
+            # Expert-layer load, from the counts each chunk's fetch carries
+            # (:func:`split_fetch`); a stack without expert layers has no
+            # such children.
+            self._mx["moe_touched"] = reg.histogram(
+                "kakveda_moe_experts_touched",
+                "Distinct experts that got at least one token, per expert "
+                "layer per decode step", ("engine",), buckets=_metrics.MOE_TOUCHED_BUCKETS,
+            ).labels(engine=name)
+            self._mx["moe_skew"] = reg.histogram(
+                "kakveda_moe_load_max_over_mean",
+                "Fullest expert's load over the mean load, worst expert layer, "
+                "over the recent chunks' decoded tokens; one observation a chunk",
+                ("engine",), buckets=_metrics.MOE_SKEW_BUCKETS,
+            ).labels(engine=name)
+        # kakveda: owned-by[serving-loop] — decayed (token, choice) pairs per expert
+        self._moe_load: Optional[np.ndarray] = None
         self._last_k_rec = 0
         # Gate inputs: recent per-chunk wall times for each arm (median —
         # robust to one-off compile spikes), recent per-slot emitted
@@ -576,6 +644,14 @@ class ContinuousBatcher:
         self._fault_fetch = _faults.site("engine.fetch")
         self.eos_id = eos_id
         self.cache = init_cache(cfg, batch=batch_slots, max_len=max_len)
+        cache_gauge = reg.gauge(
+            "kakveda_serving_cache_bytes",
+            "Bytes of the slot pool by kind: kv (the attention layers' K/V "
+            "slabs and their scales), conv (the conv layers' states)",
+            ("engine", "kind"),
+        )
+        for kind, nbytes in self.cache_bytes().items():
+            cache_gauge.labels(engine=name, kind=kind).set(nbytes)
         self.last = jnp.full((batch_slots, cfg.vocab_size), -1e30, jnp.float32)
         # Host-side mirrors of the per-slot bookkeeping: step() would
         # otherwise pay per-slot device syncs (int(dev_arr[slot])) and
@@ -593,6 +669,13 @@ class ContinuousBatcher:
         self._next_id = 0
         self._prefixes: Dict[Tuple[int, ...], _Prefix] = {}
         self.prefix_stats = {"registered": 0, "hits": 0, "hit_tokens_saved": 0}
+
+    def cache_bytes(self) -> Dict[str, int]:
+        """The slot pool's bytes by kind (kv | conv)."""
+        out = {"kv": 0, "conv": 0}
+        for key, entries in _cache_lists(self.cache).items():
+            out["conv" if key == "conv" else "kv"] += sum(int(e.nbytes) for e in entries)
+        return out
 
     @staticmethod
     def bucket_for(prompt_len: int, max_len: int) -> int:
@@ -617,12 +700,18 @@ class ContinuousBatcher:
         prompt costs its FLOPs once per process instead of once per request.
 
         Returns False (no-op) when the prefix is too short to matter, too
-        long for the slot window, or the model's RoPE regime depends on the
+        long for the slot window, the model's RoPE regime depends on the
         final sequence length (Phi-3 longrope: a prefix computed at length
         plen would rotate in a different regime than the full prompt —
-        reuse would be silently wrong, so it is refused).
+        reuse would be silently wrong, so it is refused), or the config has
+        conv layers (reuse would need a snapshot of their state at the
+        prefix's end, which the pool does not keep: refused with a warning).
         """
         ids = tuple(int(t) for t in prefix_ids)
+        if self.cfg.has_conv:
+            log.warning("register_prefix refused: the config has conv layers "
+                        "(no snapshot of conv state at a prefix's end); every prompt prefills whole")
+            return False
         if len(ids) < 8 or len(ids) + 9 >= self.max_len:
             return False
         if getattr(self.cfg, "rope_dim_factors_long", None):
@@ -858,8 +947,11 @@ class ContinuousBatcher:
             return []
         self._fault_fetch.fire()
         toks, snapshot, t_dispatch = handle
-        toks_h = np.asarray(toks)
-        _ledger.note_transfer("d2h", toks_h.nbytes)
+        fetch = np.asarray(toks)
+        _ledger.note_transfer("d2h", fetch.nbytes)
+        toks_h, pairs, touched = split_fetch(fetch, self.B, self.chunk_steps, self.cfg.n_experts)
+        if pairs is not None:
+            self._note_expert_load(pairs, touched)
         # Gate denominator: dispatch→process is the chunk's EFFECTIVE
         # wall — under pipelining the fetch overlapped the next chunk's
         # device work, so this interval is the overlapped cost the spec
@@ -874,6 +966,23 @@ class ContinuousBatcher:
                 continue  # retired by an earlier chunk; these are overshoot tokens
             self._emit(slot, st, toks_h[slot], finished)
         return finished
+
+    def _note_expert_load(self, pairs: np.ndarray, touched: np.ndarray) -> None:
+        """A chunk's expert counts onto the metrics plane. ``touched``
+        [expert layers, steps]: one observation per layer per step. ``pairs``
+        [expert layers, E] goes into a decayed sum (about the last 50 chunks'
+        tokens) whose fullest expert over the mean, in the worst layer, is
+        observed once a chunk: one step's few dozen pairs over E experts read
+        4-6 under perfectly even routing, which says how few they are and
+        nothing of the router. A chunk no live slot took part in observes
+        nothing."""
+        if not pairs.any():
+            return
+        for v in touched.ravel().tolist():
+            self._mx["moe_touched"].observe(v)
+        load = pairs if self._moe_load is None else self._moe_load * 0.98 + pairs
+        self._moe_load = load
+        self._mx["moe_skew"].observe(float((load.max(axis=1) / load.mean(axis=1)).max()))
 
     def _emit(self, slot: int, st: _Slot, tok_row, finished: List[int]) -> None:
         """Accept a chunk's tokens into a slot (EOS / budget / window stops),

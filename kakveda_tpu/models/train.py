@@ -28,6 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from kakveda_tpu.models.llama import (
     LlamaConfig,
     Params,
+    UnsupportedLayerError,
     forward,
     init_params,
     param_specs,
@@ -54,7 +55,12 @@ def lm_loss(
     cp_axis: Optional[str] = None,
 ) -> jax.Array:
     """CE loss; MoE configs with ``router_aux_coef > 0`` add the summed
-    load-balancing aux loss (models/moe.py, HF router_aux_loss_coef)."""
+    load-balancing aux loss (models/moe.py, HF router_aux_loss_coef). A
+    config with conv layers is refused: no training run has been held against
+    a reference's gradients for that operator, and its weights have no
+    sharding rule beyond replication."""
+    if cfg.has_conv:
+        raise UnsupportedLayerError("training does not take a config with conv layers yet")
     if cfg.n_experts and cfg.router_aux_coef > 0.0:
         logits, aux = forward(params, cfg, tokens, mesh=mesh, cp_axis=cp_axis, with_aux=True)
         return lm_loss_from_logits(logits, tokens) + cfg.router_aux_coef * aux
