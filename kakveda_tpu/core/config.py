@@ -1,9 +1,9 @@
-"""File-backed config with polling hot reload.
+"""File-backed config with hot reload on mtime change.
 
 Capability parity with the reference's ConfigStore
-(reference: services/shared/config.py:18-58): YAML file, mtime-change or
-poll-interval triggered reload, per-service instances with no shared mutable
-state. Adds typed accessors for the knobs every subsystem reads
+(reference: services/shared/config.py:18-58): YAML file, reload when the
+file's mtime changes, per-service instances with no shared mutable state.
+Adds typed accessors for the knobs every subsystem reads
 (reference: config/config.yaml:1-20).
 """
 
@@ -12,7 +12,7 @@ from __future__ import annotations
 import os
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Any, Dict, Mapping, Optional
+from typing import Any, Dict, Mapping, Optional, Tuple
 
 import yaml
 
@@ -40,11 +40,17 @@ class HotReloadConfig:
     poll_seconds: int
 
 
-class ConfigStore:
-    """YAML config with mtime + poll-based hot reload.
+def _section_of(data: Mapping[str, Any], name: str) -> Mapping[str, Any]:
+    return data.get(name) or DEFAULT_CONFIG.get(name) or {}
 
-    ``get()`` is cheap enough to call on every request; it stats the file and
-    re-reads only when the mtime changed or the poll interval elapsed.
+
+class ConfigStore:
+    """YAML config, hot-reloaded when the file's mtime changes.
+
+    ``get()`` makes one ``stat`` call and re-parses only when the mtime
+    differs from the one it last parsed at, so an edit takes effect on the
+    very next read. ``hot_reload.poll_seconds`` is parsed and never
+    consulted: no read is served from a cache older than the file.
     """
 
     def __init__(self, config_path: Optional[str | Path] = None):
@@ -67,15 +73,16 @@ class ConfigStore:
     def get(self) -> Dict[str, Any]:
         """Current config; re-parses only on first use or mtime change.
 
-        Hot reload works by statting the file per call (cheap) — the mtime
-        check is what detects edits, so there is no parse-every-poll churn.
+        One ``stat`` syscall a call (a missing or unreadable file reads as
+        mtime ``None``, i.e. the defaults); the mtime check is what detects
+        edits, so there is no parse-every-poll churn.
         """
         try:
-            mtime = self._path.stat().st_mtime if self._path.exists() else None
+            mtime = os.stat(self._path).st_mtime
         except OSError:
             mtime = None
 
-        if not self._loaded or (self.hot_reload().enabled and mtime != self._last_mtime):
+        if not self._loaded or (mtime != self._last_mtime and self.hot_reload().enabled):
             self._cache = self._read()
             self._last_mtime = mtime
             self._loaded = True
@@ -92,11 +99,19 @@ class ConfigStore:
     # --- typed accessors -------------------------------------------------
 
     def _section(self, name: str) -> Mapping[str, Any]:
-        return self.get().get(name) or DEFAULT_CONFIG.get(name) or {}
+        return _section_of(self.get(), name)
+
+    def verdict_inputs(self) -> Tuple[float, str]:
+        """(similarity threshold, default action) from ONE ``get()``: what a
+        warn batch judges by, for one ``stat`` of the file."""
+        data = self.get()
+        return (
+            float(_section_of(data, "failure_matching").get("similarity_threshold", 0.8)),
+            str(_section_of(data, "warning_policy").get("default_action", "warn")),
+        )
 
     def similarity_threshold(self) -> float:
-        sect = self._section("failure_matching")
-        return float(sect.get("similarity_threshold", 0.8))
+        return self.verdict_inputs()[0]
 
     def match_top_k(self) -> int:
         sect = self._section("failure_matching")
@@ -107,8 +122,7 @@ class ConfigStore:
         return int(sect.get("embedding_dim", 2048))
 
     def default_action(self) -> str:
-        sect = self._section("warning_policy")
-        return str(sect.get("default_action", "warn"))
+        return self.verdict_inputs()[1]
 
     def severity_weights(self) -> Dict[str, float]:
         sect = self._section("health_score")

@@ -2506,6 +2506,13 @@ class GFKB:
         with self._lock:
             return [self._pattern_view(st) for st in self._pattern_state.values()]
 
+    def pattern_id(self, name: str) -> Optional[str]:
+        """Id of the pattern named ``name``, or None: one dict read under the
+        lock, where ``list_patterns()`` copies every pattern's failure ids."""
+        with self._lock:
+            st = self._pattern_state.get(name)
+            return st["pattern_id"] if st is not None else None
+
     def upsert_pattern(
         self,
         *,

@@ -86,20 +86,14 @@ class WarningPolicy:
                 degraded = True
         self._m_batch.observe(time.perf_counter() - t0)
         with profiling.annotate("warn.patterns"):
-            # list_patterns() copies every pattern's failure ids (262,144 of
-            # them in the benchmark's deployment): the copy and its release
-            # both belong to this phase, so keep only the id a verdict can
-            # carry and let the copy go here.
-            citation_pattern_id = next(
-                (p.pattern_id for p in self.gfkb.list_patterns()
-                 if p.name == _CITATION_PATTERN_NAME),
-                None,
-            )
+            # The one pattern id a verdict can carry, read from the live
+            # state every batch: a pattern upserted since the last batch is
+            # seen by this one.
+            citation_pattern_id = self.gfkb.pattern_id(_CITATION_PATTERN_NAME)
         with profiling.annotate("warn.policy"):
-            # The config is stat-ed for hot reload on every read: syscalls,
-            # so they belong under the phase that uses what they return.
-            threshold = self.config.similarity_threshold()
-            default_action = self.config.default_action()
+            # One read of the config: its stat for hot reload is a syscall,
+            # so it belongs under the phase that uses what it returns.
+            threshold, default_action = self.config.verdict_inputs()
             out: List[WarningResponse] = []
             for matches in all_matches:
                 best = matches[0] if matches else None

@@ -322,6 +322,34 @@ def test_each_batch_leaves_one_cycle_and_its_parts_sum_to_at_most_it():
     assert _phase(f"{name}.batcher.wake")["count"] == 12
 
 
+def test_each_batch_leaves_one_patterns_and_one_policy_phase(tmp_path):
+    """The benchmark reads both names (`warn_patterns_ms`, `warn_policy_ms`,
+    `warn_cycle_unspanned_pct`): the verdict tail of every batch observes
+    each exactly once, however little is left inside."""
+    from kakveda_tpu.core.schemas import WarningRequest
+    from kakveda_tpu.platform import Platform
+
+    name = "pipe-tail"
+    plat = Platform(data_dir=tmp_path / "data", capacity=64, dim=1024)
+    reqs = [WarningRequest(app_id="app-A", prompt=f"Summarize report {i} with citations.",
+                           tools=[], env={"os": "linux"}) for i in range(10)]
+    tail = ("warn.patterns", "warn.policy")
+    before = [_phase(p)["count"] for p in tail]
+
+    async def go():
+        mb = MicroBatcher(plat.warn_batch, max_batch=2, deadline_s=0.001, name=name)
+        mb.start()
+        try:
+            return await asyncio.gather(*await _submit(mb, *reqs))
+        finally:
+            await mb.stop()
+
+    assert len(asyncio.run(asyncio.wait_for(go(), 120))) == len(reqs)
+    batches = _batches(name)
+    assert batches >= 5
+    assert [_phase(p)["count"] - b for p, b in zip(tail, before)] == [batches, batches]
+
+
 def test_latching_a_lost_device_twice_is_one_transition():
     """Two batches in flight may both discover the loss."""
     h = DeviceHealth(probe_interval=3600, probe_fn=lambda: None)
