@@ -87,174 +87,6 @@ def _const_str(node: ast.AST) -> Optional[str]:
 
 
 # ---------------------------------------------------------------------------
-# forward-flag-parity
-# ---------------------------------------------------------------------------
-
-_PARITY_FILES = (
-    "kakveda_tpu/models/llama.py",
-    "kakveda_tpu/models/attention.py",
-    "kakveda_tpu/models/moe.py",
-    "kakveda_tpu/models/serving.py",
-    "kakveda_tpu/models/pipeline.py",
-)
-_PARITY_ROOTS = ("forward", "decode_step", "_forward_wide", "pp_forward")
-# Shape/arch parameters every path reads incidentally — not family flags,
-# excluded so the contract stays about behavior-bearing flags.
-_PARITY_IGNORE = frozenset({
-    "vocab_size", "d_model", "n_layers", "n_heads", "n_kv_heads", "d_ff",
-    "max_seq_len", "norm_eps", "dtype", "head_dim_opt",
-})
-# Params-presence flags: family deltas keyed on layer-dict membership
-# ("post_attn_norm" in layer) rather than a cfg read — tracked with the
-# same parity contract.
-_PARITY_LAYER_KEYS = frozenset({
-    "bq", "bk", "bv", "q_norm", "k_norm",
-    "post_attn_norm", "post_ffw_norm", "router",
-})
-# (root, flag) pairs exempt BY DESIGN — documented in docs/static-analysis.md:
-# kv_quant shapes the KV cache, which the full-sequence paths don't have;
-# effective_vocab masking happens at the sampler for the offline paths
-# (generate._last_logits / _admit_jit) but in-program for _forward_wide.
-_PARITY_EXEMPT: Set[Tuple[str, str]] = {
-    ("forward", "kv_quant"),
-    ("pp_forward", "kv_quant"),
-    ("forward", "effective_vocab"),
-    ("decode_step", "effective_vocab"),
-    ("pp_forward", "effective_vocab"),
-}
-
-
-class _FuncInfo:
-    __slots__ = ("reads", "keys", "calls", "rel", "line")
-
-    def __init__(self, rel: str, line: int):
-        self.reads: Set[str] = set()
-        self.keys: Set[str] = set()
-        self.calls: Set[str] = set()
-        self.rel = rel
-        self.line = line
-
-
-def _scan_parity_function(node, rel: str, receivers: Set[str]) -> _FuncInfo:
-    info = _FuncInfo(rel, node.lineno)
-    for n in ast.walk(node):
-        if isinstance(n, ast.Attribute) and isinstance(n.value, ast.Name):
-            if n.value.id in receivers:
-                info.reads.add(n.attr)
-        elif isinstance(n, ast.Call):
-            if isinstance(n.func, ast.Name):
-                info.calls.add(n.func.id)
-            elif isinstance(n.func, ast.Attribute):
-                v = n.func.value
-                if isinstance(v, ast.Name) and v.id in receivers:
-                    info.calls.add(n.func.attr)  # cfg.layer_window(li)
-        elif isinstance(n, ast.Compare) and len(n.ops) == 1:
-            if isinstance(n.ops[0], (ast.In, ast.NotIn)):
-                k = _const_str(n.left)
-                if (
-                    k in _PARITY_LAYER_KEYS
-                    and isinstance(n.comparators[0], ast.Name)
-                    and n.comparators[0].id == "layer"
-                ):
-                    info.keys.add(k)
-        elif isinstance(n, ast.Subscript):
-            if isinstance(n.value, ast.Name) and n.value.id == "layer":
-                k = _const_str(n.slice)
-                if k in _PARITY_LAYER_KEYS:
-                    info.keys.add(k)
-    return info
-
-
-@register
-class ForwardFlagParity(Rule):
-    id = "forward-flag-parity"
-    invariant = (
-        "every LlamaConfig feature flag read by llama.forward must also be "
-        "read (transitively) by decode_step, serving._forward_wide and "
-        "pipeline.pp_forward — the 'grep all four before adding a flag' "
-        "rule, automated"
-    )
-    scope = None  # tree rule: spans models/llama|serving|pipeline
-
-    def check_tree(self, ctx: TreeContext) -> List[Finding]:
-        funcs: Dict[str, _FuncInfo] = {}
-        fields: Optional[Set[str]] = None
-        for rel in _PARITY_FILES:
-            fc = ctx.by_rel.get(rel)
-            if fc is None or fc.tree is None:
-                continue
-            for node in fc.tree.body:
-                if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-                    funcs.setdefault(
-                        node.name, _scan_parity_function(node, rel, {"cfg"})
-                    )
-                elif isinstance(node, ast.ClassDef) and node.name == "LlamaConfig":
-                    fields = {
-                        stmt.target.id
-                        for stmt in node.body
-                        if isinstance(stmt, ast.AnnAssign)
-                        and isinstance(stmt.target, ast.Name)
-                    }
-                    for m in node.body:
-                        if isinstance(m, (ast.FunctionDef, ast.AsyncFunctionDef)):
-                            # Config methods/properties (layer_window) read
-                            # flags through ``self``.
-                            funcs.setdefault(
-                                m.name,
-                                _scan_parity_function(m, rel, {"cfg", "self"}),
-                            )
-
-        roots = [r for r in _PARITY_ROOTS if r in funcs]
-        if len(roots) < 2:
-            return []  # nothing to compare (partial fixture tree)
-
-        def closure(root: str) -> Tuple[Set[str], Set[str]]:
-            reads: Set[str] = set()
-            keys: Set[str] = set()
-            seen: Set[str] = set()
-            stack = [root]
-            while stack:
-                name = stack.pop()
-                if name in seen or name not in funcs:
-                    continue
-                seen.add(name)
-                info = funcs[name]
-                reads |= info.reads
-                keys |= info.keys
-                stack.extend(info.calls)
-            if fields is not None:
-                reads &= fields
-            return reads - _PARITY_IGNORE, keys
-
-        per_root = {r: closure(r) for r in roots}
-        union_flags = set().union(*(f for f, _ in per_root.values()))
-        union_keys = set().union(*(k for _, k in per_root.values()))
-
-        out: List[Finding] = []
-        for root in roots:
-            flags, keys = per_root[root]
-            for flag in sorted(union_flags - flags):
-                if (root, flag) in _PARITY_EXEMPT:
-                    continue
-                others = sorted(r for r in roots if flag in per_root[r][0])
-                out.append(Finding(
-                    self.id, funcs[root].rel, funcs[root].line,
-                    f"forward path `{root}` never reads `cfg.{flag}` "
-                    f"(read by {', '.join(others)}); every forward path "
-                    "must honor every model-family flag",
-                ))
-            for key in sorted(union_keys - keys):
-                others = sorted(r for r in roots if key in per_root[r][1])
-                out.append(Finding(
-                    self.id, funcs[root].rel, funcs[root].line,
-                    f"forward path `{root}` never checks layer key "
-                    f"{key!r} (checked by {', '.join(others)}); every "
-                    "forward path must honor every params-keyed family flag",
-                ))
-        return out
-
-
-# ---------------------------------------------------------------------------
 # single-writer
 # ---------------------------------------------------------------------------
 

@@ -618,27 +618,24 @@ def ring_attention_local(
 # ---------------------------------------------------------------------------
 
 
-def _attention_block(
-    x: jax.Array,
-    layer: Params,
+def _sequence_attention(
+    q: jax.Array,
+    kv: Params,
+    entry: Optional[Params],
+    window: int,
+    softcap: float,
+    *,
     cfg: LlamaConfig,
-    cos: jax.Array,
-    sin: jax.Array,
-    mesh: Optional[Mesh],
-    cp_axis: Optional[str],
-    li: int = 0,
-) -> jax.Array:
-    b, s, _ = x.shape
-    hd = cfg.head_dim
-    dt = x.dtype
-    window = cfg.layer_window(li)
-
-    q, k, v = qkv_proj(x, layer, cfg, dt)
-
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-
+    mesh: Optional[Mesh] = None,
+    cp_axis: Optional[str] = None,
+) -> Tuple[jax.Array, Optional[Params]]:
+    """What ``forward`` and the pipeline stage hand :func:`transformer_block`:
+    no cache, attention over the whole sequence — grouped XLA, or the ring
+    over ``cp_axis`` when the mesh splits the sequence."""
+    k, v = kv["k"], kv["v"]
     if mesh is not None and cp_axis is not None and mesh.shape[cp_axis] > 1:
+        # the ring rotates sequence-major [B, S, KV, D] chunks
+        k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
         n_cp = mesh.shape[cp_axis]
         tp = "tp" if "tp" in mesh.axis_names else None
         tp_size = mesh.shape[tp] if tp else 1
@@ -654,7 +651,7 @@ def _attention_block(
                 axis_name=cp_axis,
                 n_chunks=n_cp,
                 window=window,
-                softcap=cfg.attn_softcap,
+                softcap=softcap,
             ),
             mesh=mesh,
             in_specs=(spec, spec, spec),
@@ -666,12 +663,8 @@ def _attention_block(
         # GQA repeat, differentiable XLA path (training runs through here).
         from kakveda_tpu.models.attention import _gqa_xla
 
-        attn = _gqa_xla(
-            q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), 0, None,
-            window=window, softcap=cfg.attn_softcap,
-        )
-
-    return attn.reshape(b, s, cfg.n_heads * hd) @ wmat(layer["wo"], dt)
+        attn = _gqa_xla(q, k, v, 0, None, window=window, softcap=softcap)
+    return attn, entry
 
 
 def _act(x: jax.Array, act_fn: str) -> jax.Array:
@@ -751,6 +744,105 @@ def conv_operator(
     return out, ext[:, s:]
 
 
+def transformer_block(
+    x: jax.Array,
+    layer: Params,
+    cfg: LlamaConfig,
+    li: int,
+    cos: jax.Array,
+    sin: jax.Array,
+    attend,
+    entry: Optional[Params] = None,
+    *,
+    conv_valid: Optional[jax.Array] = None,
+    token_mask: Optional[jax.Array] = None,
+    return_aux: bool = False,
+):
+    """THE transformer block, the one body ``forward``, ``decode_step``, the
+    serving chunk (serving._forward_wide) and the pipeline stage all run:
+    norm -> conv operator or attention -> optional sandwich norm -> residual
+    -> norm -> FFN -> optional sandwich norm -> residual. Every model-family
+    flag is read HERE (or in ``qkv_proj`` / ``mlp_block`` below it), so a new
+    flag or layer kind is one edit and no path can forget it.
+
+    A path owns only where a layer's state lives. ``entry`` is layer ``li``'s
+    own buffers of the path's cache — ``{"k", "v"}`` (+ ``"ks"``, ``"vs"``
+    under int8 K/V) or ``{"conv"}`` — None for a path without one. For an
+    attention layer the path's ``attend(q, kv, entry, window, softcap)`` gets
+    the rotated queries [B, S, H, D] and the new rows ``kv`` head-major
+    [B, KV, S, D], already int8 + scales under the same keys when the cache
+    is quantized; it writes them where its cache wants them and returns
+    (attention [B, S, H, D], the new entry).
+
+    ``conv_valid`` [B, S] keeps pad positions out of a conv state,
+    ``token_mask`` [B, S] keeps tokens out of the experts' dispatch. Returns
+    (x, the new entry, aux, pairs); the last two are ``mlp_block``'s under
+    ``return_aux``, else None."""
+    h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+    dt = h.dtype
+    if cfg.layer_kind(li) == "conv":
+        mix, state = conv_operator(h, layer, None if entry is None else entry["conv"], conv_valid)
+        entry = {"conv": state}
+    else:
+        b, s, _ = h.shape
+        q, k, v = qkv_proj(h, layer, cfg, dt)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        # head-major [B, KV, S, D], at the dtype a cache holds
+        kv = {"k": k.transpose(0, 2, 1, 3).astype(cfg.dtype), "v": v.transpose(0, 2, 1, 3).astype(cfg.dtype)}
+        if entry is not None and cfg.kv_quant == "int8":
+            # One per-row quantizer before any path's write: a slot's cache
+            # bytes equal its solo decode's, so int8 parity is exact.
+            (k8, k_sc), (v8, v_sc) = _kv_quant_rows(kv["k"]), _kv_quant_rows(kv["v"])
+            kv = {"k": k8, "v": v8, "ks": k_sc, "vs": v_sc}
+        attn, entry = attend(q, kv, entry, cfg.layer_window(li), cfg.attn_softcap)
+        mix = attn.reshape(b, s, cfg.n_heads * cfg.head_dim) @ wmat(layer["wo"], dt)
+    if "post_attn_norm" in layer:  # Gemma-2 sandwich norm
+        mix = rms_norm(mix, layer["post_attn_norm"], cfg.norm_eps)
+    x = x + mix
+
+    h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+    out = mlp_block(h, layer, cfg, return_aux=return_aux, token_mask=token_mask)
+    m, aux, pairs = out if return_aux else (out, None, None)
+    if "post_ffw_norm" in layer:
+        m = rms_norm(m, layer["post_ffw_norm"], cfg.norm_eps)
+    return x + m, entry, aux, pairs
+
+
+def run_layers(
+    params: Params, cfg: LlamaConfig, x, cos, sin, attend, cache: Optional[Params] = None, **block_kw
+):
+    """Every layer's :func:`transformer_block` in turn, and the bookkeeping
+    of a cache per layer type, written once: layer ``li``'s entry is at its
+    place in ``cfg.layers_of(kind)`` of each of its kind's lists (K/V slabs
+    and int8 scales for attention, ``conv`` for conv). ``cache`` is those
+    lists (a ``pos`` beside them is ignored), or None for a path that keeps
+    no state. Returns (x, the new lists in the same order — None without a
+    cache, [(aux, pairs)] per layer)."""
+    new = None if cache is None else {key: [] for key in cache if key != "pos"}
+    place = {li: i for kind in LAYER_KINDS for i, li in enumerate(cfg.layers_of(kind))}
+    stats = []
+    for li, layer in enumerate(params["layers"]):
+        entry = None
+        if cache is not None:
+            keys = ("conv",) if cfg.layer_kind(li) == "conv" else tuple(key for key in new if key != "conv")
+            entry = {key: cache[key][place[li]] for key in keys}
+        x, entry, aux, pairs = transformer_block(x, layer, cfg, li, cos, sin, attend, entry, **block_kw)
+        stats.append((aux, pairs))
+        if new is not None:
+            for key, val in entry.items():
+                new[key].append(val)
+    return x, new, stats
+
+
+def lm_logits(params: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
+    """The tail every path ends with: final norm, lm head, f32 logits,
+    Gemma-2's final softcap."""
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    logits = (x @ wmat(params["lm_head"], cfg.dtype)).astype(jnp.float32)
+    return softcap_logits(logits, cfg.final_softcap)
+
+
 def forward(
     params: Params,
     cfg: LlamaConfig,
@@ -777,26 +869,10 @@ def forward(
     cos, sin = _rope_freqs(cfg, positions)
 
     x = embed_tokens(params, cfg, tokens)
-    aux = jnp.zeros((), jnp.float32)
-    for li, layer in enumerate(params["layers"]):
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        if cfg.layer_kind(li) == "conv":
-            attn, _ = conv_operator(h, layer)
-        else:
-            attn = _attention_block(h, layer, cfg, cos, sin, mesh, cp_axis, li)
-        if "post_attn_norm" in layer:  # Gemma-2 sandwich norm
-            attn = rms_norm(attn, layer["post_attn_norm"], cfg.norm_eps)
-        x = x + attn
-        h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        m, a, _ = mlp_block(h, layer, cfg, return_aux=True)
-        if "post_ffw_norm" in layer:
-            m = rms_norm(m, layer["post_ffw_norm"], cfg.norm_eps)
-        x = x + m
-        aux = aux + a
-
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ wmat(params["lm_head"], cfg.dtype)).astype(jnp.float32)
-    logits = softcap_logits(logits, cfg.final_softcap)
+    attend = partial(_sequence_attention, cfg=cfg, mesh=mesh, cp_axis=cp_axis)
+    x, _, stats = run_layers(params, cfg, x, cos, sin, attend, return_aux=True)
+    aux = sum((a for a, _ in stats), jnp.zeros((), jnp.float32))
+    logits = lm_logits(params, cfg, x)
     return (logits, aux) if with_aux else logits
 
 
@@ -901,82 +977,34 @@ def decode_step(
     if pos_offset is not None:
         positions = positions - pos_offset[:, None]
     cos, sin = _rope_freqs(cfg, positions, seq_total)
-    hd = cfg.head_dim
 
     x = embed_tokens(params, cfg, tokens)
-    kq = cfg.kv_quant == "int8"
-    new_k: list = []
-    new_v: list = []
-    new_ks: list = []
-    new_vs: list = []
-    new_conv: list = []
-    # pad slots (left-padded batching, bucketed admits) stay out of a conv state
+    # pad slots (left-padded batching, bucketed admits) stay out of a conv
+    # state and out of the experts' dispatch
     tok_valid = None if kv_valid is None else jax.lax.dynamic_slice_in_dim(kv_valid, pos0, s, axis=1)
-    for layer_i, layer in enumerate(params["layers"]):
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        dt = h.dtype
-        if cfg.layer_kind(layer_i) == "conv":
-            attn, state = conv_operator(h, layer, cache["conv"][len(new_conv)], tok_valid)
-            new_conv.append(state)
-        else:
-            li = len(new_k)  # this attention layer's place in the K/V lists
-            q, k, v = qkv_proj(h, layer, cfg, dt)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
 
-            # Head-major cache writes: [B, S, KV, D] -> [B, KV, S, D] slab.
-            k_rows = k.transpose(0, 2, 1, 3)
-            v_rows = v.transpose(0, 2, 1, 3)
-            ks_all = vs_all = None
-            if kq:
-                k_i8, k_sc = _kv_quant_rows(k_rows)
-                v_i8, v_sc = _kv_quant_rows(v_rows)
-                k_all = jax.lax.dynamic_update_slice(cache["k"][li], k_i8, (0, 0, pos0, 0))
-                v_all = jax.lax.dynamic_update_slice(cache["v"][li], v_i8, (0, 0, pos0, 0))
-                ks_all = jax.lax.dynamic_update_slice(cache["ks"][li], k_sc, (0, 0, pos0))
-                vs_all = jax.lax.dynamic_update_slice(cache["vs"][li], v_sc, (0, 0, pos0))
-                new_ks.append(ks_all)
-                new_vs.append(vs_all)
-            else:
-                k_all = jax.lax.dynamic_update_slice(
-                    cache["k"][li], k_rows.astype(cfg.dtype), (0, 0, pos0, 0)
-                )
-                v_all = jax.lax.dynamic_update_slice(
-                    cache["v"][li], v_rows.astype(cfg.dtype), (0, 0, pos0, 0)
-                )
-            new_k.append(k_all)
-            new_v.append(v_all)
+    def attend(q, kv, entry, window, softcap):
+        # One scalar write position for the whole batch: each buffer is
+        # dynamic-update-sliced on its own, which XLA turns into in-place
+        # row writes (init_cache).
+        new = {
+            key: jax.lax.dynamic_update_slice(entry[key], rows, (0, 0, pos0) + (0,) * (rows.ndim - 3))
+            for key, rows in kv.items()
+        }
+        # Fused cached attention: Pallas flash on TPU, grouped XLA einsum
+        # elsewhere — either way K/V are read once, not n_rep times, and
+        # the causal mask (q_pos >= slot) also excludes unwritten slots.
+        # int8 caches pass raw tiles + scales: the flash kernel streams
+        # int8 from HBM and dequantizes in VMEM (the bandwidth win).
+        attn = gqa_cache_attention(
+            q, new["k"], new["v"], pos0, kv_valid,
+            window=window, softcap=softcap, k_scale=new.get("ks"), v_scale=new.get("vs"),
+        )
+        return attn, new
 
-            # Fused cached attention: Pallas flash on TPU, grouped XLA einsum
-            # elsewhere — either way K/V are read once, not n_rep times, and
-            # the causal mask (q_pos >= slot) also excludes unwritten slots.
-            # int8 caches pass raw tiles + scales: the flash kernel streams
-            # int8 from HBM and dequantizes in VMEM (the bandwidth win).
-            attn = gqa_cache_attention(
-                q, k_all, v_all, pos0, kv_valid,
-                window=cfg.layer_window(layer_i), softcap=cfg.attn_softcap,
-                k_scale=ks_all, v_scale=vs_all,
-            )
-            attn = attn.reshape(b, s, cfg.n_heads * hd) @ wmat(layer["wo"], dt)
-        if "post_attn_norm" in layer:  # Gemma-2 sandwich norm
-            attn = rms_norm(attn, layer["post_attn_norm"], cfg.norm_eps)
-        x = x + attn
-
-        h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        m = mlp_block(h, layer, cfg, token_mask=tok_valid)
-        if "post_ffw_norm" in layer:
-            m = rms_norm(m, layer["post_ffw_norm"], cfg.norm_eps)
-        x = x + m
-
+    x, lists, _ = run_layers(
+        params, cfg, x, cos, sin, attend, cache, conv_valid=tok_valid, token_mask=tok_valid
+    )
     if last_only:
         x = x[:, -1:, :]
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ wmat(params["lm_head"], cfg.dtype)).astype(jnp.float32)
-    logits = softcap_logits(logits, cfg.final_softcap)
-    new_cache = {"pos": pos0 + s, "k": new_k, "v": new_v}
-    if kq:
-        new_cache["ks"] = new_ks
-        new_cache["vs"] = new_vs
-    if cfg.has_conv:
-        new_cache["conv"] = new_conv
-    return logits, new_cache
+    return lm_logits(params, cfg, x), {"pos": pos0 + s, **lists}

@@ -19,9 +19,10 @@ Design (TPU-first, shard_map-manual):
     input queue, later stages from the activation received over the ring
     at the end of the previous tick. The loop is a ``lax.scan`` with static
     length — fully compiled, no host round-trips per tick.
-  * **Within a stage**: ``lax.scan`` over the stacked layer axis running
-    the same attention/MLP blocks as the dense forward (MoE layers
-    included), so pp needs no model-code fork.
+  * **Within a stage**: ``lax.scan`` over the stacked layer axis of
+    ``llama.transformer_block`` — the one block body the dense forward,
+    the cached decode and the serving chunk run (MoE layers included) —
+    so pp has no model code of its own.
   * Embedding / final norm / lm head run replicated outside the shard_map
     region (tiny next to the layer stack).
 
@@ -39,6 +40,8 @@ the same loss as the dense step.
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -47,12 +50,12 @@ from kakveda_tpu.models.llama import (
     LlamaConfig,
     Params,
     UnsupportedLayerError,
-    _attention_block,
     _rope_freqs,
+    _sequence_attention,
     embed_tokens,
-    mlp_block,
+    lm_logits,
     param_specs,
-    rms_norm,
+    transformer_block,
 )
 
 
@@ -104,20 +107,14 @@ def pp_param_specs(cfg: LlamaConfig) -> Params:
 
 
 def _stage_apply(x: jax.Array, stage_layers: Params, cfg: LlamaConfig, cos, sin) -> jax.Array:
-    """Run one stage's stacked layers over activations x [mb, S, D]."""
+    """Run one stage's stacked layers over activations x [mb, S, D]: a scan
+    of the one block body over the stacked axis. Every layer is attention
+    with layer 0's window — what ``_refuse_mixed_layers`` and ``pp_forward``'s
+    refusal of ``alt_window`` guarantee."""
+    attend = partial(_sequence_attention, cfg=cfg)
 
     def layer_step(h, layer):
-        a = rms_norm(h, layer["attn_norm"], cfg.norm_eps)
-        attn = _attention_block(a, layer, cfg, cos, sin, None, None)
-        if "post_attn_norm" in layer:  # Gemma-2 sandwich norm
-            attn = rms_norm(attn, layer["post_attn_norm"], cfg.norm_eps)
-        h = h + attn
-        a = rms_norm(h, layer["mlp_norm"], cfg.norm_eps)
-        m = mlp_block(a, layer, cfg)
-        if "post_ffw_norm" in layer:
-            m = rms_norm(m, layer["post_ffw_norm"], cfg.norm_eps)
-        h = h + m
-        return h, None
+        return transformer_block(h, layer, cfg, 0, cos, sin, attend)[0], None
 
     x, _ = jax.lax.scan(layer_step, x, stage_layers)
     return x
@@ -199,12 +196,7 @@ def pp_forward(
         check_vma=False,
     )(stacked["stages"], x_mb, cos, sin)
 
-    y = y_mb.reshape(b, s, -1)
-    y = rms_norm(y, stacked["final_norm"], cfg.norm_eps)
-    from kakveda_tpu.models.llama import softcap_logits, wmat
-
-    logits = (y @ wmat(stacked["lm_head"], cfg.dtype)).astype(jnp.float32)
-    return softcap_logits(logits, cfg.final_softcap)
+    return lm_logits(stacked, cfg, y_mb.reshape(b, s, -1))
 
 
 def place_stacked(stacked: Params, cfg: LlamaConfig, mesh: Mesh) -> Params:

@@ -164,45 +164,33 @@ def _admit_jit(params, cfg: LlamaConfig, cache, last, prompt, slot, kv_valid, po
 
 
 def _forward_wide(params, cfg: LlamaConfig, cache, tokens, slot_pos, kv_valid, pos_offset):
-    """THE serving-chunk forward body, S-wide with PER-SLOT positions:
-    token i of slot b writes cache row ``slot_pos[b]+i`` and attends rows
+    """THE serving-chunk forward, S-wide with PER-SLOT positions: token i of
+    slot b writes cache row ``slot_pos[b]+i`` and attends rows
     ``col <= slot_pos[b]+i`` (within kv_valid, and the sliding-window band
     when the layer has one). Shared by the plain decode chunk (S=1 inside
-    a scan) and the speculative verify chunk (S=k+1) — ONE body to honor
-    model-family flags, not two. Attention goes through
-    ``gqa_cache_attention``: S=1 masks are expressible as [B, L] kv_valid
-    (keeping the flash / int8-streaming dispatch), S>1 passes the full
-    [B, S, L] mask (XLA path; S <= k+1 keeps its scratch tiny).
+    a scan) and the speculative verify chunk (S=k+1). The block itself is
+    ``llama.transformer_block`` — the one body every path runs, where every
+    model-family flag is read; what is this path's own is below: where a
+    slot's rows land, which mask attention gets, which slots are live.
+    Attention goes through ``gqa_cache_attention``: S=1 masks are
+    expressible as [B, L] kv_valid (keeping the flash / int8-streaming
+    dispatch), S>1 passes the full [B, S, L] mask (XLA path; S <= k+1 keeps
+    its scratch tiny).
 
     ``cache`` is the pool's per-layer lists (:func:`_cache_lists`): an
     attention layer scatters into its K/V slab, a conv layer advances its
-    slot's state through ``llama.conv_operator``. A slot with no valid row
-    is idle: its token stays out of the experts' dispatch.
+    slot's state. A slot with no valid row is idle: its token stays out of
+    the experts' dispatch.
 
     Returns (logits [B, S, V] vocab-masked f32, the new lists, expert counts
     int32 [expert layers, E]: the (token, choice) pairs each expert got —
     [0, 1] for a stack without expert layers).
     """
     from kakveda_tpu.models.attention import gqa_cache_attention
-    from kakveda_tpu.models.llama import (
-        _kv_quant_rows,
-        _rope_freqs,
-        apply_rope,
-        conv_operator,
-        embed_tokens,
-        mlp_block,
-        qkv_proj,
-        rms_norm,
-        softcap_logits,
-        wmat,
-    )
+    from kakveda_tpu.models.llama import _rope_freqs, embed_tokens, lm_logits, run_layers
 
     b, s = tokens.shape
-    hd = cfg.head_dim
     max_len = kv_valid.shape[1]
-    kq = cfg.kv_quant == "int8"
-    cache_k, cache_v = cache["k"], cache["v"]
-    cache_ks, cache_vs = cache.get("ks", []), cache.get("vs", [])
 
     positions = slot_pos[:, None] + jnp.arange(s)[None, :] - pos_offset[:, None]
     cos, sin = _rope_freqs(cfg, positions)
@@ -210,90 +198,39 @@ def _forward_wide(params, cfg: LlamaConfig, cache, tokens, slot_pos, kv_valid, p
 
     col = jnp.arange(max_len)[None, None, :]  # [1, 1, L]
     qpos = (slot_pos[:, None] + jnp.arange(s)[None, :])[:, :, None]  # [B, S, 1]
-    base_mask = kv_valid[:, None, :] & (col <= qpos)  # [B, S, L]
-    win_mask = base_mask
-    if cfg.sliding_window:
-        win_mask = base_mask & (col > qpos - cfg.sliding_window)
+    masks = {0: kv_valid[:, None, :] & (col <= qpos)}  # [B, S, L], by window
     # only a stack with expert layers asks which slots are live
     live = jnp.broadcast_to(jnp.any(kv_valid, axis=1)[:, None], (b, s)) if cfg.n_experts else None
 
     rows = jnp.arange(b)[:, None]  # [B, 1]
     wcols = slot_pos[:, None] + jnp.arange(s)[None, :]  # [B, S] write indices
-    new_k, new_v, new_ks, new_vs, new_conv, counts = [], [], [], [], [], []
-    for layer_i in range(cfg.n_layers):
-        layer = params["layers"][layer_i]
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        dt = h.dtype
-        if cfg.layer_kind(layer_i) == "conv":
-            attn, state = conv_operator(h, layer, cache["conv"][len(new_conv)])
-            new_conv.append(state)
+
+    def attend(q, kv, entry, window, softcap):
+        # Per-slot scatter: row i of slot b lands at cache[b, :, slot_pos[b]+i]
+        # — a real scatter (in-place row writes), not a whole-cache rewrite;
+        # mode="drop" clamps overshoot past the window (discarded host-side).
+        new = {
+            key: entry[key].at[rows, :, wcols].set(jnp.swapaxes(r, 1, 2), mode="drop")
+            for key, r in kv.items()
+        }
+        if window not in masks:
+            masks[window] = masks[0] & (col > qpos - window)
+        if s == 1:
+            # [B, L] mask keeps the flash/int8-streaming dispatch;
+            # pos0=max_len makes the kernel's scalar causal mask a no-op.
+            valid, full = masks[window][:, 0, :], None
         else:
-            li = len(new_k)  # this attention layer's place in the K/V lists
-            mask = win_mask if cfg.layer_window(layer_i) else base_mask
-            q, k, v = qkv_proj(h, layer, cfg, dt)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            # Per-slot scatter: row i of slot b lands at cache[b, :, slot_pos[b]+i]
-            # — a real scatter (in-place row writes), not a whole-cache rewrite;
-            # mode="drop" clamps overshoot past the window (discarded host-side).
-            k_rows = k.transpose(0, 2, 1, 3)  # [B, KV, S, D]
-            v_rows = v.transpose(0, 2, 1, 3)
-            ks_all = vs_all = None
-            if kq:
-                # Same per-row quantizer as decode_step, so a slot's cache
-                # bytes are identical to its solo decode — int8 parity is
-                # exact, not approximate-squared.
-                k_i8, k_sc = _kv_quant_rows(k_rows)
-                v_i8, v_sc = _kv_quant_rows(v_rows)
-                k_all = cache_k[li].at[rows, :, wcols].set(k_i8.transpose(0, 2, 1, 3), mode="drop")
-                v_all = cache_v[li].at[rows, :, wcols].set(v_i8.transpose(0, 2, 1, 3), mode="drop")
-                ks_all = cache_ks[li].at[rows, :, wcols].set(k_sc.transpose(0, 2, 1), mode="drop")
-                vs_all = cache_vs[li].at[rows, :, wcols].set(v_sc.transpose(0, 2, 1), mode="drop")
-                new_ks.append(ks_all)
-                new_vs.append(vs_all)
-            else:
-                k_all = cache_k[li].at[rows, :, wcols].set(
-                    k_rows.transpose(0, 2, 1, 3).astype(cfg.dtype), mode="drop"
-                )
-                v_all = cache_v[li].at[rows, :, wcols].set(
-                    v_rows.transpose(0, 2, 1, 3).astype(cfg.dtype), mode="drop"
-                )
-            new_k.append(k_all)
-            new_v.append(v_all)
-            if s == 1:
-                # [B, L] mask keeps the flash/int8-streaming dispatch;
-                # pos0=max_len makes the kernel's scalar causal mask a no-op.
-                attn = gqa_cache_attention(
-                    q, k_all, v_all, jnp.asarray(max_len), mask[:, 0, :],
-                    softcap=cfg.attn_softcap, k_scale=ks_all, v_scale=vs_all,
-                )
-            else:
-                attn = gqa_cache_attention(
-                    q, k_all, v_all, jnp.asarray(max_len), None,
-                    softcap=cfg.attn_softcap, k_scale=ks_all, v_scale=vs_all,
-                    full_mask=mask,
-                )
-            attn = attn.reshape(b, s, cfg.n_heads * hd) @ wmat(layer["wo"], dt)
-        if "post_attn_norm" in layer:
-            attn = rms_norm(attn, layer["post_attn_norm"], cfg.norm_eps)
-        x = x + attn
-        h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        m, _, pairs = mlp_block(h, layer, cfg, token_mask=live, return_aux=True)
-        if pairs is not None:
-            counts.append(pairs)
-        if "post_ffw_norm" in layer:
-            m = rms_norm(m, layer["post_ffw_norm"], cfg.norm_eps)
-        x = x + m
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ wmat(params["lm_head"], cfg.dtype)).astype(jnp.float32)
-    logits = softcap_logits(logits, cfg.final_softcap)
-    logits = mask_pad_vocab(logits, cfg)
-    new = {"k": new_k, "v": new_v}
-    if kq:
-        new["ks"], new["vs"] = new_ks, new_vs
-    if cfg.has_conv:
-        new["conv"] = new_conv
-    return logits, new, jnp.stack(counts) if counts else jnp.zeros((0, 1), jnp.int32)
+            valid, full = None, masks[window]
+        attn = gqa_cache_attention(
+            q, new["k"], new["v"], jnp.asarray(max_len), valid,
+            softcap=softcap, k_scale=new.get("ks"), v_scale=new.get("vs"), full_mask=full,
+        )
+        return attn, new
+
+    x, lists, stats = run_layers(params, cfg, x, cos, sin, attend, cache, token_mask=live, return_aux=True)
+    logits = mask_pad_vocab(lm_logits(params, cfg, x), cfg)
+    counts = [pairs for _, pairs in stats if pairs is not None]
+    return logits, lists, jnp.stack(counts) if counts else jnp.zeros((0, 1), jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("cfg", "n_steps"), donate_argnums=(2,))

@@ -2,8 +2,7 @@
 """Invariant lint: machine-enforce the CLAUDE.md design contracts.
 
 AST static analysis over the code tree (no imports, no jax — sub-second):
-forward-flag parity across the four forward paths, single-writer
-transition helpers, stats-lock discipline, host-sync hazards in jit
+single-writer transition helpers, stats-lock discipline, host-sync hazards in jit
 bodies, typed-error discipline on service paths, fault-site resolve-once,
 plus the knob-docs / fault-site-catalog parity checks shared with
 ``scripts/check_knobs.py``. Rule catalog: docs/static-analysis.md.
@@ -16,7 +15,7 @@ Usage::
 
 ``--changed`` scans only the files git reports as modified/staged/
 untracked (filtered to the lint's code tree) — a sub-100 ms pre-commit
-loop. Whole-tree rules (knob docs, forward-flag parity, lock-order …)
+loop. Whole-tree rules (knob docs, fault-site catalog, lock-order …)
 need the full corpus and are skipped in that mode: the full-tree run
 stays the tier-1 gate.
 

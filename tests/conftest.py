@@ -30,13 +30,17 @@ def tmp_data_dir(tmp_path):
 
 @pytest.fixture
 def decode_parity():
-    """Cached greedy decode must reproduce the full forward's argmax chain —
-    the serving-path invariant every model family asserts. A fixture (not a
-    conftest import) so it works under any pytest import mode."""
+    """Cached greedy decode must reproduce the full forward's argmax chain,
+    and the slot pool must reproduce the cached decode — the serving-path
+    invariant every model family asserts, over the three paths that hand
+    ``llama.transformer_block`` a state of their own (none, one scalar write
+    position, per-slot positions). A fixture (not a conftest import) so it
+    works under any pytest import mode."""
     import jax.numpy as jnp
 
     from kakveda_tpu.models.generate import generate_tokens
     from kakveda_tpu.models.llama import forward, mask_pad_vocab
+    from kakveda_tpu.models.serving import ContinuousBatcher
 
     def check(params, cfg, prompt, n=8):
         greedy_cached = generate_tokens(params, cfg, prompt, max_new_tokens=n)
@@ -48,5 +52,7 @@ def decode_parity():
             # here and spuriously fail (or hide a masking bug).
             toks.append(int(jnp.argmax(mask_pad_vocab(logits[0, -1], cfg))))
         assert greedy_cached == toks[len(prompt) :]
+        pool = ContinuousBatcher(params, cfg, batch_slots=2, max_len=64, chunk_steps=4)
+        assert pool.run_all([list(prompt)], max_new_tokens=n) == [greedy_cached]
 
     return check

@@ -10,6 +10,8 @@ citation lists) acceptance multiplies tokens/stream. KAKVEDA_SERVE_SPEC=k
 enables it on the engine; sampled slots fall back to plain chunks.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -30,6 +32,25 @@ def _solo(params, cfg, n=12):
     return [
         generate_tokens(params, cfg, p, max_new_tokens=n, max_len=128) for p in PROMPTS
     ]
+
+
+LOOP_PROMPT = [7, 8, 9, 7, 8, 9, 7, 8, 9]
+
+
+@functools.lru_cache(maxsize=None)
+def _period1_model(n=40, run=16):
+    """(params, solo output) of the first seeded tiny model whose greedy
+    continuation of LOOP_PROMPT ends in a constant run of at least ``run``
+    tokens: the period-1 regime speculation is for. Searched, not assumed
+    of one seed: which random model settles into a loop changes with the
+    JAX version's RNG and the CPU's summation order (about one seed in
+    eight does)."""
+    for seed in range(64):
+        params = init_params(jax.random.PRNGKey(seed), CFG)
+        solo = generate_tokens(params, CFG, LOOP_PROMPT, max_new_tokens=n, max_len=128)
+        if solo[-run:] == [solo[-1]] * run:
+            return params, solo
+    raise AssertionError("no seed in range(64) gives a model that settles into a constant run")
 
 
 def test_spec_chunk_parity_multi_slot():
@@ -87,14 +108,12 @@ def test_draft_period1_not_degenerate():
 def test_spec_acceptance_on_repetitive_traffic():
     """A model that settles into an argmax loop must accept drafts:
     emitted tokens per slot-chunk > 1 on average (the spec win exists).
-    This seed's output ends in a period-1 constant run — the exact case
-    the old suffix lookup degenerated to all-PAD drafts on (anchoring at
-    j=n-2 left an empty copy region; periodic extrapolation tiles the
-    run instead), which left this assertion failing at rate == 1.0."""
-    params = init_params(jax.random.PRNGKey(2), CFG)
-    p = [7, 8, 9, 7, 8, 9, 7, 8, 9]
-    solo = generate_tokens(params, CFG, p, max_new_tokens=40, max_len=128)
-    assert solo[-4:] == [solo[-1]] * 4  # the period-1 regime is real
+    The output ends in a period-1 constant run — the exact case the old
+    suffix lookup degenerated to all-PAD drafts on (anchoring at j=n-2 left
+    an empty copy region; periodic extrapolation tiles the run instead),
+    which left this assertion failing at rate == 1.0."""
+    params, solo = _period1_model()
+    p = LOOP_PROMPT
     cb = ContinuousBatcher(params, CFG, batch_slots=1, max_len=128, chunk_steps=4, spec_k=4)
     rid = cb.admit(p, max_new_tokens=40)
     while cb.slots:
@@ -272,11 +291,9 @@ def test_pipelined_spec_cursor_continues_accepted_run(monkeypatch):
     first (the acceptance-preserving half of the pipeline win)."""
     monkeypatch.setenv("KAKVEDA_SERVE_SPEC_CALIB", "0")
     monkeypatch.setenv("KAKVEDA_SERVE_SPEC_BREAKEVEN", "0")
-    params = init_params(jax.random.PRNGKey(2), CFG)
-    p = [7, 8, 9, 7, 8, 9, 7, 8, 9]
-    solo = generate_tokens(params, CFG, p, max_new_tokens=40, max_len=128)
+    params, solo = _period1_model()
     cb = ContinuousBatcher(params, CFG, batch_slots=1, max_len=128, chunk_steps=4, spec_k=4)
-    assert _drain_pipelined_spec(cb, [p], max_new=40) == [solo]
+    assert _drain_pipelined_spec(cb, [LOOP_PROMPT], max_new=40) == [solo]
     s = cb.spec_stats
     assert s["emitted"] / s["slot_chunks"] > 1.3, s
     assert s["accepted"] > 0

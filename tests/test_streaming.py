@@ -121,7 +121,13 @@ def test_generate_stream_cancel_before_first_token(monkeypatch):
     rt = LlamaRuntime(cfg=CFG, seed=0)
     try:
         eng = rt.engine()
-        blocker = eng.submit([5, 6, 7], 40)  # occupies the only slot
+        # The blocker occupies the only slot until the test lets it go: its
+        # first chunk's callback holds the loop (a bounded wait). Its length
+        # alone does not hold it — with the programs already compiled (this
+        # file's earlier tests, one worker) 40 tokens take less than the
+        # second slept below, and the queued stream was admitted after all.
+        release = threading.Event()
+        blocker = eng.submit([5, 6, 7], 40, on_tokens=lambda new, done: release.wait(60))
         cancel_ev = threading.Event()
         got: list = []
 
@@ -136,8 +142,10 @@ def test_generate_stream_cancel_before_first_token(monkeypatch):
         t.join(timeout=30)
         assert not t.is_alive(), "stream consumer still blocked after cancel"
         assert got == []  # never produced a token
+        release.set()
         assert len(blocker.result(timeout=120)) > 0  # slot owner unaffected
     finally:
+        release.set()
         rt.retire()
 
 
