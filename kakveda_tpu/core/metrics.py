@@ -78,6 +78,9 @@ RATE_BUCKETS: Tuple[float, ...] = (
 # to a few hundred experts) and the fullest expert over the mean (1 = even).
 MOE_TOUCHED_BUCKETS: Tuple[float, ...] = (1, 2, 4, 8, 12, 16, 24, 32, 48, 64, 96, 128, 256, 512)
 MOE_SKEW_BUCKETS: Tuple[float, ...] = (1.0, 1.1, 1.25, 1.5, 2.0, 3.0, 4.0, 6.0, 8.0, 16.0, 64.0)
+# Rows of a K/V slab a decode chunk reads (models/serving.py): powers of two,
+# from a rehearsal's 64-row window to a 131,072-row one.
+ATTEND_ROWS_BUCKETS: Tuple[float, ...] = tuple(float(1 << p) for p in range(6, 18))
 
 
 def _fmt(v: float) -> str:
@@ -530,6 +533,10 @@ _CORE_FAMILIES = (
      "Fullest expert's load over the mean load, worst expert layer, over "
      "the recent chunks' decoded tokens; one observation a chunk",
      ("engine",), MOE_SKEW_BUCKETS),
+    ("histogram", "kakveda_serving_attend_rows",
+     "Rows of each K/V slab a decode chunk's attention reads (the slot "
+     "window = the pool holds a long sequence); one observation a "
+     "dispatched chunk", ("engine",), ATTEND_ROWS_BUCKETS),
     ("gauge", "kakveda_serving_cache_bytes",
      "Bytes of the slot pool by kind: kv (the attention layers' K/V slabs "
      "and their scales), conv (the conv layers' states)", ("engine", "kind"), None),

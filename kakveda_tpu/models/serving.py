@@ -27,7 +27,10 @@ independent *slots*:
     lets pre-flight warn batches share the chip — models/generate.py
     `DecodeSession`). Inactive slots decode garbage into their own slot
     positions that admission later overwrites — masked out by per-slot
-    `kv_valid`, never visible to active slots.
+    `kv_valid`, never visible to active slots. The program works on the
+    first `attend_len` rows of every slab, a power of two that covers the
+    pool's live rows (`_grow_valid`): a handful of programs, one per
+    length, and a pool of short sequences stops reading its whole window.
   * **retire**: EOS/length-exhausted slots free on the host between
     chunks; their results return to callers and the slot re-enters the
     free list.
@@ -137,6 +140,28 @@ def _scatter_slot(cache: Params, scratch: Params, slot) -> Params:
     return out
 
 
+def _slab_prefix(lists: Dict[str, list], attend_len: int) -> Dict[str, list]:
+    """The pool a decode chunk works on: rows ``[:attend_len]`` of every K/V
+    slab (and int8 scale), conv states whole. The host picks a length that
+    covers every row a live slot reads or writes in the chunk
+    (``ContinuousBatcher._grow_valid``), so the rows left out are rows the
+    mask rejects anyway — and XLA, which cannot skip a masked row, reads a
+    slab whole at every step it is handed whole."""
+    return {
+        key: entries if key == "conv" else [e[:, :, :attend_len] for e in entries]
+        for key, entries in lists.items()
+    }
+
+
+def _slab_restore(lists: Dict[str, list], heads: Dict[str, list]) -> Dict[str, list]:
+    """Write a chunk's prefixes (:func:`_slab_prefix`) back over the head of
+    the donated slabs, in place; a full-length entry replaces its slab."""
+    return {
+        key: [jax.lax.dynamic_update_slice(e, h, (0,) * e.ndim) for e, h in zip(entries, heads[key])]
+        for key, entries in lists.items()
+    }
+
+
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2,))
 def _admit_jit(params, cfg: LlamaConfig, cache, last, prompt, slot, kv_valid, pos_offset):
     """Prefill ``prompt`` [1, P] into batch slot ``slot`` of ``cache``.
@@ -233,9 +258,13 @@ def _forward_wide(params, cfg: LlamaConfig, cache, tokens, slot_pos, kv_valid, p
     return logits, lists, jnp.stack(counts) if counts else jnp.zeros((0, 1), jnp.int32)
 
 
-@partial(jax.jit, static_argnames=("cfg", "n_steps"), donate_argnums=(2,))
-def _step_chunk_jit(params, cfg: LlamaConfig, cache, last, slot_pos, kv_valid, pos_offset, temps, rng, n_steps: int):
-    """Advance every slot by ``n_steps`` tokens in one program.
+@partial(jax.jit, static_argnames=("cfg", "n_steps", "attend_len"), donate_argnums=(2,))
+def _step_chunk_jit(
+    params, cfg: LlamaConfig, cache, last, slot_pos, kv_valid, pos_offset, temps, rng, n_steps: int, attend_len: int
+):
+    """Advance every slot by ``n_steps`` tokens in one program, over the
+    first ``attend_len`` rows of the pool's slabs (:func:`_slab_prefix`; an
+    idle slot's row past them is dropped like any overshoot).
 
     ``slot_pos`` [B] — per-slot NEXT cache index (prompt length + tokens
     decoded so far). decode_step's scalar `pos` can't express per-slot
@@ -244,8 +273,8 @@ def _step_chunk_jit(params, cfg: LlamaConfig, cache, last, slot_pos, kv_valid, p
     ``temps`` [B] — per-slot sampling temperature; a slot with temp <= 0
     decodes greedily, others sample categorically (one rng split per step,
     shared across slots — rows are independent draws of the same key).
-    The scan carries the cache's per-layer lists, whatever the stack holds:
-    K/V slabs, int8 scales, conv states.
+    The scan carries the prefix's per-layer lists, whatever the stack holds:
+    K/V slabs, int8 scales, conv states; they are written back at the end.
 
     Returns (cache, last, slot_pos, rng, the chunk's fetch). The fetch is ONE
     int32 array [B + expert layers, max(n_steps, E + n_steps)]: rows [:B] hold
@@ -255,6 +284,9 @@ def _step_chunk_jit(params, cfg: LlamaConfig, cache, last, slot_pos, kv_valid, p
     (:func:`split_fetch`). Without expert layers it is the tokens alone.
     """
 
+    pool = _cache_lists(cache)
+    valid = kv_valid[:, :attend_len]
+
     def one_step(carry, _):
         lists, last, slot_pos, rng = carry
         rng, sub = jax.random.split(rng)
@@ -263,12 +295,12 @@ def _step_chunk_jit(params, cfg: LlamaConfig, cache, last, slot_pos, kv_valid, p
         )
         nxt = jnp.where(temps > 0.0, sampled, jnp.argmax(last, axis=-1))  # [B]
         logits, lists, counts = _forward_wide(
-            params, cfg, lists, nxt[:, None].astype(jnp.int32), slot_pos, kv_valid, pos_offset,
+            params, cfg, lists, nxt[:, None].astype(jnp.int32), slot_pos, valid, pos_offset,
         )
         return (lists, logits[:, -1, :], slot_pos + 1, rng), (nxt, counts)
 
-    (lists, last, slot_pos, rng), (toks, counts) = jax.lax.scan(
-        one_step, (_cache_lists(cache), last, slot_pos, rng), None, length=n_steps
+    (heads, last, slot_pos, rng), (toks, counts) = jax.lax.scan(
+        one_step, (_slab_prefix(pool, attend_len), last, slot_pos, rng), None, length=n_steps
     )
     fetch = toks.T.astype(jnp.int32)  # [B, n_steps]
     if counts.shape[1]:  # [n_steps, expert layers, E]
@@ -278,7 +310,7 @@ def _step_chunk_jit(params, cfg: LlamaConfig, cache, last, slot_pos, kv_valid, p
         fetch = jnp.concatenate(
             [jnp.pad(fetch, ((0, 0), (0, stats.shape[1] - n_steps))), stats], axis=0
         )
-    return {"pos": cache["pos"], **lists}, last, slot_pos, rng, fetch
+    return {"pos": cache["pos"], **_slab_restore(pool, heads)}, last, slot_pos, rng, fetch
 
 
 def split_fetch(fetch: np.ndarray, n_slots: int, n_steps: int, n_experts: int):
@@ -291,10 +323,11 @@ def split_fetch(fetch: np.ndarray, n_slots: int, n_steps: int, n_experts: int):
     return fetch[:n_slots, :n_steps], stats[:, :n_experts], stats[:, n_experts:n_experts + n_steps]
 
 
-@partial(jax.jit, static_argnames=("cfg", "k"), donate_argnums=(2,))
-def _spec_chunk_jit(params, cfg: LlamaConfig, cache, last, slot_pos, kv_valid, pos_offset, drafts, k: int):
+@partial(jax.jit, static_argnames=("cfg", "k", "attend_len"), donate_argnums=(2,))
+def _spec_chunk_jit(params, cfg: LlamaConfig, cache, last, slot_pos, kv_valid, pos_offset, drafts, k: int, attend_len: int):
     """Speculative verify chunk: each slot advances 1..k+1 GREEDY tokens in
-    ONE :func:`_forward_wide` pass over k+1 positions.
+    ONE :func:`_forward_wide` pass over k+1 positions, over the first
+    ``attend_len`` rows of the pool's slabs as the plain chunk is.
 
     ``drafts`` [B, k] are host-side prompt-lookup guesses for the tokens
     AFTER the committed next token t0 (= argmax(last), computed in-program
@@ -314,10 +347,11 @@ def _spec_chunk_jit(params, cfg: LlamaConfig, cache, last, slot_pos, kv_valid, p
     """
     t0 = jnp.argmax(last, axis=-1).astype(jnp.int32)  # [B]
     tokens = jnp.concatenate([t0[:, None], drafts.astype(jnp.int32)], axis=1)  # [B, k+1]
-    logits, lists, _ = _forward_wide(
-        params, cfg, _cache_lists(cache), tokens, slot_pos, kv_valid, pos_offset,
+    pool = _cache_lists(cache)
+    logits, heads, _ = _forward_wide(
+        params, cfg, _slab_prefix(pool, attend_len), tokens, slot_pos, kv_valid[:, :attend_len], pos_offset,
     )
-    new_cache = {"pos": cache["pos"], **lists}
+    new_cache = {"pos": cache["pos"], **_slab_restore(pool, heads)}
 
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, k+1]; [b, i] follows tokens[b, :i+1]
     match = (drafts.astype(jnp.int32) == greedy[:, :-1]).astype(jnp.int32)  # [B, k]
@@ -516,6 +550,12 @@ class ContinuousBatcher:
                 "kakveda_serving_spec_k",
                 "Pool verify width of the most recent speculative chunk",
                 ("engine",),
+            ).labels(engine=name),
+            "attend_rows": reg.histogram(
+                "kakveda_serving_attend_rows",
+                "Rows of each K/V slab a decode chunk's attention reads (the "
+                "slot window = the pool holds a long sequence); one observation "
+                "a dispatched chunk", ("engine",), buckets=_metrics.ATTEND_ROWS_BUCKETS,
             ).labels(engine=name),
         }
         reg.gauge(
@@ -850,7 +890,7 @@ class ContinuousBatcher:
         # previously threaded device slot_pos is stale from here on.
         self._spec_pos_dev = None
         t_dispatch = time.perf_counter()
-        self._grow_valid(self.chunk_steps)
+        attend_len = self._grow_valid(self.chunk_steps)
 
         _ledger.note_transfer(
             "h2d",
@@ -860,7 +900,7 @@ class ContinuousBatcher:
         self.cache, self.last, _, self.rng, toks = _step_chunk_jit(
             self.params, self.cfg, self.cache, self.last, jnp.asarray(self._pos_np.copy()),
             jnp.asarray(self._kv_np.copy()), jnp.asarray(self._off_np.copy()),
-            jnp.asarray(self._temp_np.copy()), self.rng, self.chunk_steps,
+            jnp.asarray(self._temp_np.copy()), self.rng, self.chunk_steps, attend_len,
         )
         self._pos_np += self.chunk_steps  # every slot advances in lockstep
         try:
@@ -955,19 +995,33 @@ class ContinuousBatcher:
             self._kv_np[slot] = False
             self._mx["active"].set(len(self.slots))
 
-    def _grow_valid(self, steps: int) -> None:
+    def _grow_valid(self, steps: int) -> int:
         """Grow read-validity on the host mirror (vectorized over slots):
         each active slot may read its next ``steps`` rows as it writes
         them (reads stay bounded per-step by ``col <= slot_pos`` inside
         the chunk program). The left-pad region [0, pos_offset) stays
         invalid. One [B, L] upload per chunk replaces per-slot device
         scatters. ONE definition for both chunk flavors — the invariant
-        must not fork."""
+        must not fork.
+
+        Returns the chunk's ``attend_len``, the prefix of each K/V slab the
+        chunk program works on (:func:`_slab_prefix`): the power of two, from
+        512 and capped at the window, that covers the highest ACTIVE slot's
+        ``pos + steps`` — so no valid row of a live slot lies at or past it.
+        An idle slot's position drifts in lockstep and does not count. At a
+        2,048 window that is three chunk programs at most, each compiled on
+        first use as an admit bucket is; a pool that holds a long sequence
+        works on the whole slab."""
+        from kakveda_tpu.ops.knn import pow2_bucket
+
         ar = np.arange(self.max_len)[None, :]
         active = np.zeros((self.B,), bool)
         active[list(self.slots)] = True
-        limit = (self._pos_np + steps)[:, None]
-        self._kv_np |= active[:, None] & (ar >= self._off_np[:, None]) & (ar < limit)
+        limit = self._pos_np + steps
+        self._kv_np |= active[:, None] & (ar >= self._off_np[:, None]) & (ar < limit[:, None])
+        attend_len = pow2_bucket(int(limit[active].max()), floor=512, cap=self.max_len)
+        self._mx["attend_rows"].observe(attend_len)
+        return attend_len
 
     @staticmethod
     def _draft(hist: List[int], k: int) -> List[int]:
@@ -1120,7 +1174,7 @@ class ContinuousBatcher:
         # but-valid and excluded by each query's own causal bound
         # (col <= qpos), the same argument that makes rejected-draft rows
         # safe.
-        self._grow_valid(self._spec_pending_width + k + 1)
+        attend_len = self._grow_valid(self._spec_pending_width + k + 1)
         slot_pos = (
             self._spec_pos_dev
             if self._spec_pos_dev is not None
@@ -1134,7 +1188,7 @@ class ContinuousBatcher:
         self.cache, self.last, self._spec_pos_dev, toks, counts = _spec_chunk_jit(
             self.params, self.cfg, self.cache, self.last, slot_pos,
             jnp.asarray(self._kv_np.copy()), jnp.asarray(self._off_np.copy()),
-            jnp.asarray(drafts), k,
+            jnp.asarray(drafts), k, attend_len,
         )
         self._spec_pending += 1
         self._spec_pending_width += k + 1
