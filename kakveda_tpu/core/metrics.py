@@ -540,6 +540,9 @@ _CORE_FAMILIES = (
     ("gauge", "kakveda_serving_cache_bytes",
      "Bytes of the slot pool by kind: kv (the attention layers' K/V slabs "
      "and their scales), conv (the conv layers' states)", ("engine", "kind"), None),
+    ("gauge", "kakveda_serving_fused_qkv_layers",
+     "Attention layers the pool serves with one q|k|v projection weight "
+     "(llama.fuse_qkv)", ("engine",), None),
     ("counter", "kakveda_compile_total",
      "XLA backend compiles attributed per jit entry point "
      "(KAKVEDA_LEDGER=1)", ("fn",), None),

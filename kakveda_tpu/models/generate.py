@@ -29,9 +29,11 @@ from kakveda_tpu.models.llama import (
     LlamaConfig,
     Params,
     decode_step,
+    fuse_qkv,
     init_cache,
     init_params,
     mask_pad_vocab,
+    unfuse_qkv,
 )
 from kakveda_tpu.models.runtime import GenerateResult
 from kakveda_tpu.models.tokenizer import ByteTokenizer
@@ -542,6 +544,8 @@ class LlamaRuntime:
             self.params = quantize_params_int8(self.params)
         elif quant not in (None, "none"):
             raise ValueError(f"unknown quant mode {quant!r} (int8|none)")
+        # Every program this runtime serves reads one q|k|v weight a layer.
+        self.params = fuse_qkv(self.params)
         self.quant = quant
         self.model_label = model_label or f"llama-{self.cfg.n_layers}L-{self.cfg.d_model}d"
         import threading
@@ -628,7 +632,9 @@ class LlamaRuntime:
         import orbax.checkpoint as ocp
 
         ckptr = ocp.StandardCheckpointer()
-        self.params = ckptr.restore(path, self.params)
+        # A checkpoint holds the tree training writes, three q/k/v leaves a
+        # layer: restore into that structure, then fuse as __init__ does.
+        self.params = fuse_qkv(ckptr.restore(path, unfuse_qkv(self.params, self.cfg)))
         with self._engine_lock:
             if self._engine is not None:
                 # The engine captured the old param tree at construction;

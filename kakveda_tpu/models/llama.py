@@ -368,16 +368,78 @@ def wmat(w, dt) -> jax.Array:
         return w["q"].astype(dt) * w["s"].astype(dt)[..., None, :]
     return w.astype(dt)
 
+
+QKV_KEYS = ("wq", "wk", "wv")
+
+
+def _one_device(w) -> bool:
+    sharding = getattr(jax.tree.leaves(w)[0], "sharding", None)
+    return sharding is None or len(sharding.device_set) == 1
+
+
+def fuse_qkv(params: Params) -> Params:
+    """The tree with each attention layer's ``wq``, ``wk``, ``wv`` held as
+    one weight ``wqkv`` [d_model, (H + 2·KV)·hd], q | k | v columns in that
+    order, which :func:`qkv_proj` reads with one dot. On the TPU three
+    separate weights each change layout at every call of a program that
+    reads them, and the dot that reads ``wq`` waits for a copy of its own
+    normed input; one weight does neither. Each column is the same
+    contraction, so the logits are the same.
+
+    Serving only: ``LlamaRuntime`` fuses where it takes its params, and the
+    tree it is given keeps its three leaves (training, conversion, sharding
+    and quantization all produce and read those). An int8 weight-only pair
+    fuses as a pair, its per-column scales concatenated alike. A layer whose
+    weights span more than one device (``shard_params``' column TP) keeps
+    its three: a fused weight's columns would have to be interleaved per
+    shard. Conv layers have no such keys. Built layer by layer into new
+    dicts; the three originals go when the caller lets go of its tree."""
+    layers = []
+    for layer in params["layers"]:
+        if all(k in layer for k in QKV_KEYS) and _one_device(layer["wq"]):
+            parts = [layer[k] for k in QKV_KEYS]
+            layer = {k: v for k, v in layer.items() if k not in QKV_KEYS}
+            layer["wqkv"] = jax.tree.map(lambda *a: jnp.concatenate(a, axis=-1), *parts)
+        layers.append(layer)
+    return {**params, "layers": layers}
+
+
+def unfuse_qkv(params: Params, cfg: LlamaConfig) -> Params:
+    """:func:`fuse_qkv` undone: ``wq``, ``wk``, ``wv`` cut out of ``wqkv``
+    (the tree a checkpoint was written from)."""
+    nq, nkv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+
+    def cut(a):
+        return jnp.split(a, [nq, nq + nkv], axis=-1)
+
+    layers = []
+    for layer in params["layers"]:
+        if "wqkv" in layer:
+            layer = dict(layer)
+            w = layer.pop("wqkv")
+            if isinstance(w, dict):  # int8 pair
+                layer.update({k: {"q": q, "s": s} for k, q, s in zip(QKV_KEYS, cut(w["q"]), cut(w["s"]))})
+            else:
+                layer.update(zip(QKV_KEYS, cut(w)))
+        layers.append(layer)
+    return {**params, "layers": layers}
+
+
 def qkv_proj(
     h: jax.Array, layer: Params, cfg: LlamaConfig, dt
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """q/k/v projections with optional attention biases (Qwen2-style).
+    """q/k/v projections with optional attention biases (Qwen2-style): one
+    dot where the layer holds ``wqkv`` (:func:`fuse_qkv`), three otherwise.
     h: [B, S, d_model] -> q [B,S,H,hd], k/v [B,S,KV,hd]."""
     b, s, _ = h.shape
     hd = cfg.head_dim
-    q = h @ wmat(layer["wq"], dt)
-    k = h @ wmat(layer["wk"], dt)
-    v = h @ wmat(layer["wv"], dt)
+    if "wqkv" in layer:
+        nq, nkv = cfg.n_heads * hd, cfg.n_kv_heads * hd
+        q, k, v = jnp.split(h @ wmat(layer["wqkv"], dt), [nq, nq + nkv], axis=-1)
+    else:
+        q = h @ wmat(layer["wq"], dt)
+        k = h @ wmat(layer["wk"], dt)
+        v = h @ wmat(layer["wv"], dt)
     if "bq" in layer:
         q = q + layer["bq"].astype(dt)
         k = k + layer["bk"].astype(dt)

@@ -27,7 +27,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-_DENSE_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "we_gate", "we_up", "we_down")
+_DENSE_KEYS = ("wq", "wk", "wv", "wqkv", "wo", "w_gate", "w_up", "w_down", "we_gate", "we_up", "we_down")
 
 
 def quantize_tensor_int8(w: jax.Array) -> Dict[str, jax.Array]:

@@ -629,6 +629,11 @@ class ContinuousBatcher:
         )
         for kind, nbytes in self.cache_bytes().items():
             cache_gauge.labels(engine=name, kind=kind).set(nbytes)
+        reg.gauge(
+            "kakveda_serving_fused_qkv_layers",
+            "Attention layers the pool serves with one q|k|v projection "
+            "weight (llama.fuse_qkv)", ("engine",),
+        ).labels(engine=name).set(sum("wqkv" in layer for layer in params["layers"]))
         self.last = jnp.full((batch_slots, cfg.vocab_size), -1e30, jnp.float32)
         # Host-side mirrors of the per-slot bookkeeping: step() would
         # otherwise pay per-slot device syncs (int(dev_arr[slot])) and

@@ -217,3 +217,56 @@ def test_chunk_program_for_the_chip_keeps_whole_slabs_out_of_its_loop(one_chip, 
     assert not re.search(rf"\[{slots},8,512,128\]\S* slice\(", bodies[512])  # no materialised cut of a slab a step
     temp = {n: c.memory_analysis().temp_size_in_bytes for n, c in compiled.items()}
     assert temp[512] < temp[window]
+
+
+def test_one_qkv_weight_leaves_no_weight_or_activation_copy_in_the_programs_for_the_chip(one_chip, no_compile_cache):
+    """The same widths and pool, the chunk at 512 rows and the admit at
+    bucket 128, compiled for the v5e from three q/k/v weights a layer and
+    from the one ``llama.fuse_qkv`` makes. Three weights arrive in the
+    default layout and the dots want the contraction axis minor, so each
+    program re-lays every one out at every call, and inside the loop the
+    separate ``wq`` dot waits for a copy of its layer's normed activation.
+    One weight needs neither."""
+    import re
+
+    from kakveda_tpu.models.llama import fuse_qkv, init_cache
+    from kakveda_tpu.models.serving import _admit_jit, _step_chunk_jit
+
+    cfg = hf_config_to_llama(
+        {**json.loads((CONFIGS / "judge-mistral-7b.json").read_text()), "num_hidden_layers": 2}, dtype=jnp.bfloat16
+    )
+    slots, window, d = 16, 2048, cfg.d_model
+
+    def shaped(tree, dtype=None):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, dtype or a.dtype, sharding=one_chip), tree)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    three = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    cache = shaped(jax.eval_shape(lambda: init_cache(cfg, batch=slots, max_len=window)))
+    last, valid, per_slot = arg((slots, cfg.vocab_size), jnp.float32), arg((slots, window), jnp.bool_), arg((slots,), jnp.int32)
+    widths = {cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim, (cfg.n_heads + 2 * cfg.n_kv_heads) * cfg.head_dim}
+    weight = rf"bf16\[{d},(?:{'|'.join(map(str, widths))})\]"  # wq, wk / wv, wqkv
+    found = {}
+    for name, tree in (("three", three), ("one", jax.eval_shape(fuse_qkv, three))):
+        params = shaped(tree, jnp.bfloat16)
+        chunk = _step_chunk_jit.lower(
+            params, cfg, cache, last, per_slot, valid, per_slot, arg((slots,), jnp.float32), arg((2,), jnp.uint32), 8, 512
+        ).compile()
+        admit = _admit_jit.lower(params, cfg, cache, last, arg((1, 128), jnp.int32), arg((), jnp.int32), valid, per_slot).compile()
+        text = chunk.as_text()
+        body = re.search(r"body=%([\w.\-]+)", text).group(1)
+        found[name] = {
+            "entry": re.findall(rf"= {weight}\S* copy\(", re.search(r"\nENTRY .*?\n\}", text, re.S).group(0)),
+            "body": re.findall(
+                rf"= \(bf16\[{slots},{d}\]\S*, .*? copy-start\(",
+                re.search(r"\n%" + re.escape(body) + r" \(.*?\n\}", text, re.S).group(0),
+            ),
+            "admit": re.findall(rf"= bf16\[(?:{d},{d}|1024,{d})\]\S* copy\(", admit.as_text()),
+            "temp": chunk.memory_analysis().temp_size_in_bytes,
+        }
+    three, one = found["three"], found["one"]
+    assert three["entry"] and three["body"] and three["admit"]  # the patterns find what is there
+    assert not one["entry"] and not one["body"] and not one["admit"]
+    assert one["temp"] < three["temp"]
