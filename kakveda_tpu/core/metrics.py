@@ -81,6 +81,8 @@ MOE_SKEW_BUCKETS: Tuple[float, ...] = (1.0, 1.1, 1.25, 1.5, 2.0, 3.0, 4.0, 6.0, 
 # Rows of a K/V slab a decode chunk reads (models/serving.py): powers of two,
 # from a rehearsal's 64-row window to a 131,072-row one.
 ATTEND_ROWS_BUCKETS: Tuple[float, ...] = tuple(float(1 << p) for p in range(6, 18))
+# Prompts one admit program prefills (models/serving.py): 1 or 2.
+ADMIT_WIDTH_BUCKETS: Tuple[float, ...] = (1.0, 2.0)
 
 
 def _fmt(v: float) -> str:
@@ -537,6 +539,10 @@ _CORE_FAMILIES = (
      "Rows of each K/V slab a decode chunk's attention reads (the slot "
      "window = the pool holds a long sequence); one observation a "
      "dispatched chunk", ("engine",), ATTEND_ROWS_BUCKETS),
+    ("histogram", "kakveda_serving_admit_width",
+     "Prompts one admit program prefilled (1 to "
+     "ContinuousBatcher.ADMIT_WIDTH); one observation an admit program",
+     ("engine",), ADMIT_WIDTH_BUCKETS),
     ("gauge", "kakveda_serving_cache_bytes",
      "Bytes of the slot pool by kind: kv (the attention layers' K/V slabs "
      "and their scales), conv (the conv layers' states)", ("engine", "kind"), None),

@@ -18,10 +18,11 @@ Under mixed-length traffic that wastes both slots and latency.
 (static shapes — nothing ever retraces) and treats the batch axis as B
 independent *slots*:
 
-  * **admit**: a new prompt prefills into one free slot — a [1, P] prefill
-    whose cache rows are scattered into the batch cache at that slot
-    (`_admit_jit`). Other slots are untouched; admission interleaves with
-    decoding chunks.
+  * **admit**: new prompts prefill into free slots, up to
+    ``ContinuousBatcher.ADMIT_WIDTH`` (two) to one program — a [W, P]
+    prefill whose cache rows are scattered into the batch cache at those
+    slots (`_admit_jit`). Other slots are untouched; admission interleaves
+    with decoding chunks.
   * **step_chunk**: ONE bounded decode program advances every active slot
     by up to `chunk_steps` tokens (same chunked-dispatch scheduling that
     lets pre-flight warn batches share the chip — models/generate.py
@@ -123,20 +124,21 @@ def _cache_lists(cache: Params) -> Dict[str, list]:
     """A cache's per-layer lists, everything but the scalar ``pos``: a cache
     per layer type — K/V slabs (+ int8 scales) of the attention layers,
     ``conv`` states of the conv layers. Every entry is batch-leading, so one
-    rule scatters a [1, ...] scratch entry into a slot."""
+    rule scatters a [W, ...] scratch entry's rows into slots."""
     return {key: val for key, val in cache.items() if key != "pos"}
 
 
-def _scatter_slot(cache: Params, scratch: Params, slot) -> Params:
-    """Write a single-sequence ``scratch`` cache into batch slot ``slot`` of
-    ``cache``, entry by entry: the prompt's K/V rows, and its conv states —
-    whatever the slot held before is gone."""
+def _scatter_slots(cache: Params, scratch: Params, slots) -> Params:
+    """Write row i of a [W, ...] ``scratch`` cache into batch slot
+    ``slots[i]`` of ``cache``, entry by entry: the prompt's K/V rows, and its
+    conv states — whatever the slot held before is gone."""
     out = {"pos": cache["pos"]}
     for key, entries in _cache_lists(cache).items():
-        out[key] = [
-            jax.lax.dynamic_update_slice(ce, se, (slot,) + (0,) * (ce.ndim - 1))
-            for ce, se in zip(entries, scratch[key])
-        ]
+        out[key] = []
+        for ce, se in zip(entries, scratch[key]):
+            for i in range(se.shape[0]):
+                ce = jax.lax.dynamic_update_slice(ce, se[i:i + 1], (slots[i],) + (0,) * (ce.ndim - 1))
+            out[key].append(ce)
     return out
 
 
@@ -163,26 +165,30 @@ def _slab_restore(lists: Dict[str, list], heads: Dict[str, list]) -> Dict[str, l
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2,))
-def _admit_jit(params, cfg: LlamaConfig, cache, last, prompt, slot, kv_valid, pos_offset):
-    """Prefill ``prompt`` [1, P] into batch slot ``slot`` of ``cache``.
+def _admit_jit(params, cfg: LlamaConfig, cache, last, prompts, slots, kv_valid, pos_offset):
+    """Prefill ``prompts`` [W, P] into batch slots ``slots`` [W] of ``cache``.
 
-    The single-sequence prefill runs with its own [1, ...] scratch cache
-    (so its attention sees only this prompt and a conv layer starts from
-    "before the sequence"), then its K/V rows and conv states scatter into
-    the batch cache at ``slot``: the conv state is the real prompt's last
-    rows, the bucket's pad positions masked out of it (``decode_step``).
-    `last` [B, V] gets the slot's next-token logits.
+    The prefill runs with its own [W, ...] scratch cache (so each row's
+    attention sees only its own prompt, the batch axis keeping rows apart,
+    and a conv layer starts from "before the sequence"), then row i's K/V
+    rows and conv states scatter into the batch cache at ``slots[i]``. Every
+    row is left-padded to the same P, so every prompt ends at the scratch's
+    last row: a row's conv state is its own prompt's last rows, its pad
+    positions masked out of it (``decode_step``), as its pad rows are out of
+    its attention and of the experts' dispatch. `last` [B, V] gets each
+    slot's next-token logits.
     """
-    scratch = init_cache(cfg, batch=1, max_len=kv_valid.shape[1])
+    scratch = init_cache(cfg, batch=prompts.shape[0], max_len=kv_valid.shape[1])
     logits, scratch = decode_step(
-        params, cfg, prompt, scratch,
-        kv_valid=kv_valid[slot][None],
-        pos_offset=pos_offset[slot][None],
+        params, cfg, prompts, scratch,
+        kv_valid=kv_valid[slots],
+        pos_offset=pos_offset[slots],
         last_only=True,
     )
-    out = _scatter_slot(cache, scratch, slot)
+    out = _scatter_slots(cache, scratch, slots)
     nl = mask_pad_vocab(logits[:, -1, :], cfg)
-    last = jax.lax.dynamic_update_slice(last, nl, (slot, 0))
+    for i in range(prompts.shape[0]):
+        last = jax.lax.dynamic_update_slice(last, nl[i:i + 1], (slots[i], 0))
     # cache["pos"] is managed per-slot on host (slot positions differ);
     # the batch cache carries pos=0 and step passes explicit positions.
     return out, last
@@ -395,7 +401,7 @@ def _admit_prefix_jit(
         pos_offset=pos_offset[slot][None],
         last_only=True,
     )
-    out = _scatter_slot(cache, scratch, slot)
+    out = _scatter_slots(cache, scratch, slot[None])
     nl = mask_pad_vocab(logits[:, -1, :], cfg)
     last = jax.lax.dynamic_update_slice(last, nl, (slot, 0))
     return out, last
@@ -551,6 +557,12 @@ class ContinuousBatcher:
                 "Pool verify width of the most recent speculative chunk",
                 ("engine",),
             ).labels(engine=name),
+            "admit_width": reg.histogram(
+                "kakveda_serving_admit_width",
+                "Prompts one admit program prefilled (1 to "
+                "ContinuousBatcher.ADMIT_WIDTH); one observation an admit program",
+                ("engine",), buckets=_metrics.ADMIT_WIDTH_BUCKETS,
+            ).labels(engine=name),
             "attend_rows": reg.histogram(
                 "kakveda_serving_attend_rows",
                 "Rows of each K/V slab a decode chunk's attention reads (the "
@@ -651,6 +663,9 @@ class ContinuousBatcher:
         self._next_id = 0
         self._prefixes: Dict[Tuple[int, ...], _Prefix] = {}
         self.prefix_stats = {"registered": 0, "hits": 0, "hit_tokens_saved": 0}
+        # Buckets this pool has admitted into: every width's admit program of
+        # such a bucket has been built (:meth:`_admit_prompts`).
+        self._admit_buckets: set = set()
 
     def cache_bytes(self) -> Dict[str, int]:
         """The slot pool's bytes by kind (kv | conv)."""
@@ -658,6 +673,13 @@ class ContinuousBatcher:
         for key, entries in _cache_lists(self.cache).items():
             out["conv" if key == "conv" else "kv"] += sum(int(e.nbytes) for e in entries)
         return out
+
+    # Prompts one admit program prefills at most. A pair reads the weights
+    # once for two prompts; the cap is 2 because every width is one more
+    # program per bucket, and each costs set-up even when the persistent
+    # compile cache holds it (trace, lower, load): widths 1 and 2 take most
+    # of what a wider admit gives (PERF.md section 6).
+    ADMIT_WIDTH = 2
 
     @staticmethod
     def bucket_for(prompt_len: int, max_len: int) -> int:
@@ -805,7 +827,36 @@ class ContinuousBatcher:
         a handful of compiled prefill programs under mixed-length traffic
         instead of retracing per distinct length; pad slots are masked by
         kv_valid and pos_offset exactly as in generate_tokens_batch."""
-        if not self.free:
+        return self.admit_many([dict(
+            prompt_ids=prompt_ids, max_new_tokens=max_new_tokens,
+            temperature=temperature, on_tokens=on_tokens,
+        )])[0]
+
+    def shared_bucket(self, prompt_lens: List[int], max_new: List[int]) -> Optional[int]:
+        """The bucket one admit program prefills these prompts at: the
+        largest of their own. None where that would cost one of them tokens:
+        a prompt the window cannot hold, or one whose budget fits its own
+        bucket and not the shared one (a slot stops at the window, and a
+        larger bucket starts it later in it)."""
+        if any(p + 1 >= self.max_len for p in prompt_lens):
+            return None
+        own = [self.bucket_for(p, self.max_len) for p in prompt_lens]
+        bucket = max(own)
+        if any(b != bucket and bucket + n + 1 > self.max_len for b, n in zip(own, max_new)):
+            return None
+        return bucket
+
+    def admit_many(self, requests: List[dict]) -> List[int]:
+        """Admit up to ``ADMIT_WIDTH`` requests, each a dict of
+        :meth:`admit`'s keywords; returns their request ids in order.
+
+        The prompts that miss the prefix cache prefill in ONE admit program
+        at :meth:`shared_bucket` (a shorter prompt's left pad grows, its
+        positions stay its own); a prefix hit prefills its suffix alone.
+        Nothing is admitted unless every request can be."""
+        if not 1 <= len(requests) <= self.ADMIT_WIDTH:
+            raise ValueError(f"admit_many takes 1 to {self.ADMIT_WIDTH} requests, got {len(requests)}")
+        if len(self.free) < len(requests):
             raise RuntimeError("no free slot; call step() until one retires")
         if self._spec_pending:
             # Admission rewrites a slot's host mirrors, but an in-flight
@@ -815,60 +866,96 @@ class ContinuousBatcher:
             raise RuntimeError(
                 "admit() with a speculative chunk in flight; process_spec_chunk first"
             )
-        self._spec_pos_dev = None
-        p = len(prompt_ids)
-        if p + 1 >= self.max_len:
+        ids = [list(r["prompt_ids"]) for r in requests]
+        if any(len(p) + 1 >= self.max_len for p in ids):
             raise ValueError("prompt too long for the slot window")
-        bucket = self.bucket_for(p, self.max_len)
-        off = bucket - p
-        slot = self.free.pop()
-        rid = self._next_id
-        self._next_id += 1
-        # Slot validity: the real prompt rows [off, bucket), growing per step.
+        use_prefix = os.environ.get("KAKVEDA_SERVE_PREFIX", "1") != "0"
+        matches = [self._match_prefix(p) if use_prefix else None for p in ids]
+        misses = [i for i, m in enumerate(matches) if m is None]
+        bucket = (
+            self.shared_bucket([len(ids[i]) for i in misses], [requests[i].get("max_new_tokens", 64) for i in misses])
+            if misses else None
+        )
+        if misses and bucket is None:
+            raise ValueError("these prompts cannot share an admit program: admit them one by one")
+        self._spec_pos_dev = None
         ar = np.arange(self.max_len)
-        self._kv_np[slot] = (ar >= off) & (ar < bucket)
-        self._off_np[slot] = off
-        self._pos_np[slot] = bucket
-        self._temp_np[slot] = temperature
+        rids, slots, buckets = [], [], []
+        for i, r in enumerate(requests):
+            # A prefix hit keeps its own bucket; the misses share one.
+            b = bucket if matches[i] is None else self.bucket_for(len(ids[i]), self.max_len)
+            slot = self.free.pop()
+            off = b - len(ids[i])
+            # Slot validity: the real prompt rows [off, b), growing per step.
+            self._kv_np[slot] = (ar >= off) & (ar < b)
+            self._off_np[slot] = off
+            self._pos_np[slot] = b
+            self._temp_np[slot] = r.get("temperature", 0.0)
+            rids.append(self._next_id)
+            self._next_id += 1
+            slots.append(slot)
+            buckets.append(b)
+            if matches[i] is not None:
+                self._admit_prefix(slot, off, ids[i], *matches[i])
+        if misses:
+            self._mx["prefix_miss"].inc(len(misses))
+            self._admit_prompts([slots[i] for i in misses], [ids[i] for i in misses], bucket)
+        for i, r in enumerate(requests):
+            # st.index stays None until the first draft actually needs it
+            # (_anchor builds it lazily): a pool whose gate is OFF — or that
+            # never goes speculative — pays zero index maintenance.
+            self.slots[slots[i]] = _Slot(
+                req_id=rids[i], prompt_len=buckets[i], max_new=r.get("max_new_tokens", 64),
+                on_tokens=r.get("on_tokens"), prompt_ids=ids[i], k=self.spec_k,
+            )
+        self._mx["active"].set(len(self.slots))
+        return rids
+
+    def _admit_prefix(self, slot: int, off: int, prompt_ids: List[int], pe: _Prefix, split: int, sw: int) -> None:
+        """A prefix hit's admit: its suffix alone, over the registered slab."""
+        with self.stats_lock:
+            self.prefix_stats["hits"] += 1
+            self.prefix_stats["hit_tokens_saved"] += split
+        self._mx["prefix_hit"].inc()
         # .copy(): on the CPU backend jnp.asarray can alias the numpy
         # buffer ZERO-COPY, and these mirrors keep mutating while the
         # async program reads them — observed as flaky garbage logits.
-        m = (
-            self._match_prefix(list(prompt_ids))
-            if os.environ.get("KAKVEDA_SERVE_PREFIX", "1") != "0"
-            else None
+        self.cache, self.last = _admit_prefix_jit(
+            self.params, self.cfg, self.cache, self.last,
+            pe.kv, jnp.asarray([prompt_ids[split:]], jnp.int32),
+            jnp.asarray(slot),
+            jnp.asarray(self._kv_np.copy()), jnp.asarray(self._off_np.copy()),
+            jnp.asarray(off + split, jnp.int32),
         )
-        if m is not None:
-            pe, split, sw = m
-            with self.stats_lock:
-                self.prefix_stats["hits"] += 1
-                self.prefix_stats["hit_tokens_saved"] += split
-            self._mx["prefix_hit"].inc()
-            self.cache, self.last = _admit_prefix_jit(
-                self.params, self.cfg, self.cache, self.last,
-                pe.kv, jnp.asarray([list(prompt_ids[split:])], jnp.int32),
-                jnp.asarray(slot),
-                jnp.asarray(self._kv_np.copy()), jnp.asarray(self._off_np.copy()),
-                jnp.asarray(off + split, jnp.int32),
-            )
-        else:
-            self._mx["prefix_miss"].inc()
-            padded = [0] * off + list(prompt_ids)
+
+    def _admit_prompts(self, slots: List[int], prompts: List[List[int]], bucket: int) -> None:
+        """ONE admit program prefills ``prompts`` into ``slots``, each
+        left-padded to ``bucket`` (the slots' mirrors already say so).
+
+        The program set is fixed: widths 1 to ``ADMIT_WIDTH`` for each bucket
+        the traffic uses. The first admit into a bucket, of either width,
+        first runs every other width's program of that bucket into the same
+        slots, which the admit itself then overwrites whole: every admit
+        program of the bucket is built before this returns, so whichever way
+        later requests arrive in pairs, none meets an admit it has to compile."""
+        w = len(prompts)
+        tokens = np.zeros((w, bucket), np.int32)
+        for i, p in enumerate(prompts):
+            tokens[i, bucket - len(p):] = p
+        rows = np.asarray(slots, np.int32)
+        kv, off = jnp.asarray(self._kv_np.copy()), jnp.asarray(self._off_np.copy())
+        picks = []  # the rows of each program run; the admit itself runs last
+        if bucket not in self._admit_buckets:
+            self._admit_buckets.add(bucket)
+            picks = [[min(i, w - 1) for i in range(n)] for n in range(1, self.ADMIT_WIDTH + 1) if n != w]
+        picks.append(list(range(w)))
+        self._mx["admit_width"].observe(w)
+        for pick in picks:
+            # kakveda: allow[retrace-hazard] — the width is 1 to ADMIT_WIDTH, each built at the bucket's first admit
             self.cache, self.last = _admit_jit(
                 self.params, self.cfg, self.cache, self.last,
-                jnp.asarray([padded], jnp.int32), jnp.asarray(slot),
-                jnp.asarray(self._kv_np.copy()), jnp.asarray(self._off_np.copy()),
+                jnp.asarray(tokens[pick]), jnp.asarray(rows[pick]), kv, off,
             )
-        # st.index stays None until the first draft actually needs it
-        # (_anchor builds it lazily): a pool whose gate is OFF — or that
-        # never goes speculative — pays zero index maintenance.
-        self.slots[slot] = _Slot(
-            req_id=rid, prompt_len=bucket, max_new=max_new_tokens, on_tokens=on_tokens,
-            prompt_ids=list(prompt_ids),
-            k=self.spec_k,
-        )
-        self._mx["active"].set(len(self.slots))
-        return rid
 
     def step_async(self):
         """Dispatch one decode chunk WITHOUT fetching its tokens; returns a
@@ -1896,6 +1983,15 @@ class ServingEngine:
             fut.fair_rounds = getattr(fut, "fair_rounds", 0) + 1
         return item
 
+    def _pick_group_locked(self) -> list:
+        """The next admit group: up to ``ContinuousBatcher.ADMIT_WIDTH``
+        waiting items, as many as there are free slots, in the order
+        :meth:`_pick_waiting_locked` gives (so its tenant fairness and
+        max-wait promotion hold pick by pick); an odd one out goes alone.
+        Caller holds ``_submit_lock``."""
+        n = min(ContinuousBatcher.ADMIT_WIDTH, len(self.cb.free), len(self._waiting))
+        return [self._pick_waiting_locked() for _ in range(n)]
+
     def _rebuild(self) -> None:
         """Rebuild the batcher after a loop death: a FRESH ContinuousBatcher
         (cache slabs re-zeroed by init_cache; gate/k/pipeline/adaptive state
@@ -2003,7 +2099,8 @@ class ServingEngine:
         # covers items it moved after this drain.
         self._fail_all(RuntimeError("ServingEngine closed"))
 
-    def _admit_one(self, item) -> None:
+    def _control(self, item) -> None:
+        """A control item (cancel, prefix registration), acted on at once."""
         if item[0] == "cancel":
             _, fut, _ = item
             rid = next((r for r, f in self._pend.items() if f is fut), None)
@@ -2038,60 +2135,82 @@ class ServingEngine:
                 fut.set_result(ok)
             except Exception as e:  # noqa: BLE001 — registration errors belong to the caller
                 self._fail(fut, e)
-            return
-        ids, max_new, temp, on_tokens, t_submit, deadline, fut = item
-        if deadline is not None and time.perf_counter() >= deadline:
-            self._expire_item(fut, [], "expired before admission")
-            return
-        if not fut.set_running_or_notify_cancel():
-            return
+
+    def _admit_group(self, items) -> None:
+        """Admit generation items picked together for free slots, at most
+        ``ContinuousBatcher.ADMIT_WIDTH``: one admit program for them all
+        where they can share one (``ContinuousBatcher.shared_bucket``), else
+        one each. An item expired or cancelled while it waited is dropped
+        here and its partner still admitted; every admitted request gets its
+        own queue-wait, timeline and TTFT wrapper."""
+        live = []
+        for item in items:
+            deadline, fut = item[5], item[-1]
+            if deadline is not None and time.perf_counter() >= deadline:
+                self._expire_item(fut, [], "expired before admission")
+            elif fut.set_running_or_notify_cancel():
+                live.append(item)
+        if len(live) > 1 and self.cb.shared_bucket([len(it[0]) for it in live], [it[1] for it in live]) is None:
+            for item in live:
+                self._admit_items([item])
+        elif live:
+            self._admit_items(live)
+
+    def _admit_items(self, items) -> None:
+        """One ``admit_many`` call for ``items``, whose Futures are running."""
         t_admit = time.perf_counter()
-        self._mx["queue_wait"].observe(t_admit - t_submit)
-        # Feed the admission controller's live queue-wait history — the
-        # input deadline-aware shedding reads (submit rejects a deadline
-        # the observed waits say cannot be met).
-        _admission.get_admission().note_wait("interactive", t_admit - t_submit)
-        # Lifecycle tracking rides the slot's own streaming callback: the
-        # wrapper sees each chunk's accepted tokens on the loop thread
-        # (TTFT + token counts with no extra bookkeeping in the batcher),
-        # then forwards to the caller's callback if any.
-        track = {
-            "submit": t_submit, "admit": t_admit, "first": None, "tokens": 0,
-            "deadline": deadline,
-            "traceparent": getattr(fut, "traceparent", None),
-        }
+        tracks, requests = [], []
         mx_ttft, mx_first = self._mx["ttft"], self._mx["first_chunk"]
+        for ids, max_new, temp, on_tokens, t_submit, deadline, fut in items:
+            self._mx["queue_wait"].observe(t_admit - t_submit)
+            # Feed the admission controller's live queue-wait history — the
+            # input deadline-aware shedding reads (submit rejects a deadline
+            # the observed waits say cannot be met).
+            _admission.get_admission().note_wait("interactive", t_admit - t_submit)
+            # Lifecycle tracking rides the slot's own streaming callback: the
+            # wrapper sees each chunk's accepted tokens on the loop thread
+            # (TTFT + token counts with no extra bookkeeping in the batcher),
+            # then forwards to the caller's callback if any.
+            track = {
+                "submit": t_submit, "admit": t_admit, "first": None, "tokens": 0,
+                "deadline": deadline,
+                "traceparent": getattr(fut, "traceparent", None),
+            }
 
-        def _on_tokens(new, done, _orig=on_tokens, _tr=track):
-            if _tr["first"] is None and new:
-                _tr["first"] = first = time.perf_counter()
-                mx_ttft.observe(first - _tr["submit"])
-                # ttft = queue_wait + prefill + first_chunk. "prefill_s" is
-                # absent while cb.admit itself is still running: tokens it
-                # delivered waited for no chunk.
-                prefill_s = _tr.get("prefill_s")
-                _tr["first_chunk_s"] = (
-                    0.0 if prefill_s is None
-                    else max(0.0, first - _tr["admit"] - prefill_s)
-                )
-                mx_first.observe(_tr["first_chunk_s"])
-            _tr["tokens"] += len(new)
-            if _orig is not None:
-                _orig(new, done)
+            def _on_tokens(new, done, _orig=on_tokens, _tr=track):
+                if _tr["first"] is None and new:
+                    _tr["first"] = first = time.perf_counter()
+                    mx_ttft.observe(first - _tr["submit"])
+                    # ttft = queue_wait + prefill + first_chunk. "prefill_s" is
+                    # absent while cb.admit itself is still running: tokens it
+                    # delivered waited for no chunk.
+                    prefill_s = _tr.get("prefill_s")
+                    _tr["first_chunk_s"] = (
+                        0.0 if prefill_s is None
+                        else max(0.0, first - _tr["admit"] - prefill_s)
+                    )
+                    mx_first.observe(_tr["first_chunk_s"])
+                _tr["tokens"] += len(new)
+                if _orig is not None:
+                    _orig(new, done)
 
+            tracks.append(track)
+            requests.append(dict(prompt_ids=ids, max_new_tokens=max_new, temperature=temp, on_tokens=_on_tokens))
         try:
             with annotate("serve.admit"):
-                rid = self.cb.admit(
-                    ids, max_new_tokens=max_new, temperature=temp, on_tokens=_on_tokens
-                )
+                rids = self.cb.admit_many(requests)
         except Exception as e:  # noqa: BLE001 — admission errors belong to the caller
-            self._m_requests.labels(engine=self.name, outcome="rejected").inc()
-            self._fail(fut, e)
+            for item in items:
+                self._m_requests.labels(engine=self.name, outcome="rejected").inc()
+                self._fail(item[-1], e)
             return
-        track["prefill_s"] = time.perf_counter() - t_admit
-        self._mx["prefill"].observe(track["prefill_s"])
-        self._track[rid] = track
-        self._pend[rid] = fut
+        # One program prefilled them all: its wall is each request's prefill.
+        prefill_s = time.perf_counter() - t_admit
+        for rid, track, item in zip(rids, tracks, items):
+            track["prefill_s"] = prefill_s
+            self._mx["prefill"].observe(prefill_s)
+            self._track[rid] = track
+            self._pend[rid] = item[-1]
 
     def _loop(self) -> None:
         """Supervise the serve loop: on a crash, fail the in-flight futures
@@ -2227,20 +2346,22 @@ class ServingEngine:
                         item = self._q.get(timeout=0.1) if block else self._q.get_nowait()
                         block = False
                         if item[0] in ("cancel", "prefix"):
-                            self._admit_one(item)
+                            self._control(item)
                         else:
                             with self._submit_lock:
                                 self._waiting.append(item)
                 except queue.Empty:
                     pass
+            # Every waiting prompt is admitted this cycle while slots are
+            # free, in groups of up to ADMIT_WIDTH; nothing waits for a partner.
             while self.cb.has_capacity:
                 with self._submit_lock:
-                    if not self._waiting:
-                        break
-                    item = self._pick_waiting_locked()
+                    items = self._pick_group_locked()
+                if not items:
+                    break
                 if pending_spec is not None:
                     drain_spec()
-                self._admit_one(item)
+                self._admit_group(items)
 
         def dispatch(step):
             with annotate("serve.chunk.dispatch"):

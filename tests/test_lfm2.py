@@ -205,7 +205,7 @@ def test_a_padded_admit_equals_the_unpadded_one(served):
         cache = init_cache(cfg, batch=slots, max_len=max_len)
         last = jnp.zeros((slots, cfg.vocab_size), jnp.float32)
         cache, last = _admit_jit(params, cfg, cache, last, jnp.asarray([[0] * off + prompt], jnp.int32),
-                                 jnp.asarray(1), jnp.asarray(kv), jnp.asarray(offs))
+                                 jnp.asarray([1]), jnp.asarray(kv), jnp.asarray(offs))
         return cache, last
 
     (c_pad, l_pad), (c_raw, l_raw) = admit(32), admit(p)

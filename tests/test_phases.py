@@ -219,6 +219,7 @@ def test_the_engine_loop_is_spanned_and_ttft_adds_up():
     label = "engine=phases-test"
     before = {p: _phase(p)["count"] for p in SERVE_PHASES}
     first0 = _hist("kakveda_serving_first_chunk_seconds", label)["count"]
+    width0 = _hist("kakveda_serving_admit_width", label)
     # 2 slots for 5 requests: some wait in the queue, some are admitted at once
     eng = ServingEngine(params, cfg, batch_slots=2, max_len=64, chunk_steps=4, name="phases-test")
     try:
@@ -235,6 +236,9 @@ def test_the_engine_loop_is_spanned_and_ttft_adds_up():
     assert _hist("kakveda_serving_first_chunk_seconds", label)["count"] - first0 == len(prompts)
     moved = {p: _phase(p)["count"] - before[p] for p in SERVE_PHASES}
     assert all(v > 0 for v in moved.values()), moved
-    assert moved["serve.admit"] == len(prompts)
+    # one "serve.admit" an admit program, which prefilled one or two of them
+    width = _hist("kakveda_serving_admit_width", label)
+    assert moved["serve.admit"] == width["count"] - width0["count"]
+    assert width["sum"] - width0["sum"] == len(prompts)
     events = [e for e in eng.recorder.dump() if e["kind"] == "request"]
     assert len(events) == len(prompts) and all("first_chunk_ms" in e for e in events)

@@ -254,7 +254,7 @@ def test_one_qkv_weight_leaves_no_weight_or_activation_copy_in_the_programs_for_
         chunk = _step_chunk_jit.lower(
             params, cfg, cache, last, per_slot, valid, per_slot, arg((slots,), jnp.float32), arg((2,), jnp.uint32), 8, 512
         ).compile()
-        admit = _admit_jit.lower(params, cfg, cache, last, arg((1, 128), jnp.int32), arg((), jnp.int32), valid, per_slot).compile()
+        admit = _admit_jit.lower(params, cfg, cache, last, arg((1, 128), jnp.int32), arg((1,), jnp.int32), valid, per_slot).compile()
         text = chunk.as_text()
         body = re.search(r"body=%([\w.\-]+)", text).group(1)
         found[name] = {
